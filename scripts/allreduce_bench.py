@@ -17,8 +17,7 @@ The headline is the acceptance number: bytes-on-wire reduction of int8 vs
 fp32 at the FIRST model's gradient size (>= 3x required). It is analytic —
 a property of the wire format, not the host — so the payload is meaningful
 even from a CPU run; ms/step carries the measured side and names its
-backend. On a multichip TPU run this is the ``allreduce_bench`` stage of
-``scripts/tpu_watch.sh``.
+backend.
 
 With ``--overlap`` (or ``ALLREDUCE_BENCH_OVERLAP=1``) every mode entry also
 carries an ``"overlap"`` table — ms/step and analytic ring wire bytes per
@@ -37,8 +36,8 @@ each with a MEASURED exposed-comm column: median ms of a dummy-compute +
 allreduce program minus the same compute alone — the wire time the
 scheduler did NOT hide. The mode entry also gets the single-shot baseline
 (``"exposed_comm_ms"``) next to it, plus ``"async_matches_off"`` (gradient
-parity of async vs the single-shot path on the same inputs/key — the
-watcher stage's done-marker) and the payload a ``"recompile_alarms"``
+parity of async vs the single-shot path on the same inputs/key) and the
+payload a ``"recompile_alarms"``
 count (post-warmup jit cache growth across the async benches; 0 expected):
 
     "modes": {"int8": {..., "exposed_comm_ms": ...,
@@ -168,7 +167,7 @@ def bench_mode(
     from jax.sharding import PartitionSpec as P
 
     from simclr_tpu.parallel import compress
-    from simclr_tpu.parallel.mesh import DATA_AXIS, shard_map
+    from simclr_tpu.parallel.mesh import DATA_AXIS
 
     def body(x, step):
         i = jax.lax.axis_index(DATA_AXIS)
@@ -178,7 +177,7 @@ def bench_mode(
         )["g"]
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P())
+        jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
     )
     x = jnp.linspace(-1.0, 1.0, n_elements, dtype=jnp.float32)
     for step in range(WARMUP_ITERS):
@@ -222,7 +221,7 @@ def bench_exposed(
     from jax.sharding import PartitionSpec as P
 
     from simclr_tpu.parallel import compress
-    from simclr_tpu.parallel.mesh import DATA_AXIS, shard_map
+    from simclr_tpu.parallel.mesh import DATA_AXIS
 
     def compute(w, h):
         for _ in range(COMPUTE_MATMULS):
@@ -240,11 +239,13 @@ def bench_exposed(
     def body_compute(w, h, step):
         return compute(w, h).sum()
 
-    fn_both = jax.jit(shard_map(
-        body_both, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P()
+    fn_both = jax.jit(jax.shard_map(
+        body_both, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P(),
+        check_vma=False,
     ))
-    fn_compute = jax.jit(shard_map(
-        body_compute, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P()
+    fn_compute = jax.jit(jax.shard_map(
+        body_compute, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
+        check_vma=False,
     ))
     w = jnp.eye(COMPUTE_DIM, dtype=jnp.float32) * 0.5
     h = jnp.ones((COMPUTE_DIM, COMPUTE_DIM), jnp.float32)
@@ -257,14 +258,13 @@ def bench_exposed(
 
 
 def async_parity(mesh, n_elements: int, mode: str, chunks: int) -> float:
-    """Max relative |async - off| on the same inputs/key — the grad-parity
-    number the watcher's overlap_async done-marker thresholds."""
+    """Max relative |async - off| on the same inputs/key."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from simclr_tpu.parallel import compress
-    from simclr_tpu.parallel.mesh import DATA_AXIS, shard_map
+    from simclr_tpu.parallel.mesh import DATA_AXIS
 
     def body(x):
         i = jax.lax.axis_index(DATA_AXIS)
@@ -276,7 +276,7 @@ def async_parity(mesh, n_elements: int, mode: str, chunks: int) -> float:
         return jnp.max(jnp.abs(asy - off)), jnp.max(jnp.abs(off))
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()))
+        jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()), check_vma=False)
     )
     x = jnp.linspace(-1.0, 1.0, n_elements, dtype=jnp.float32)
     diff, ref = fn(x)
@@ -334,9 +334,9 @@ def main() -> None:
     overlap_on = "--overlap" in sys.argv[1:] or bool(
         os.environ.get("ALLREDUCE_BENCH_OVERLAP")
     )
-    # both spellings reach the async rows: the watcher stage passes the
-    # dedicated --overlap-async flag; `--overlap async` (value form) works
-    # for hand runs next to the bare chunked `--overlap`
+    # both spellings reach the async rows: the dedicated --overlap-async
+    # flag, and `--overlap async` (value form) next to the bare chunked
+    # `--overlap`
     async_on = (
         "--overlap-async" in sys.argv[1:]
         or "async" in sys.argv[1:]

@@ -19,15 +19,13 @@ The headline is the acceptance number: analytic HBM-traffic reduction of
 fused vs xla at the FIRST batch size. It is analytic — a property of the
 memory-access pattern, not the host — so the payload is meaningful even
 from a CPU run (where the Pallas kernel executes in interpret mode);
-ms/batch carries the measured side and names its backend. On a TPU run
-this is the ``augment_bench`` stage of ``scripts/tpu_watch.sh``.
+ms/batch carries the measured side and names its backend.
 
 ``recompile_alarms`` counts post-warmup recompilations of either timed
 callable (jit cache growth after the warmup iterations) — the same silent
-perf killer CompileSentry watches in training; the watcher's done-marker
-requires it to be 0.
+perf killer CompileSentry watches in training; 0 expected.
 
-Robustness contract (same as bench.py / allreduce_bench.py): never exits
+Robustness contract (same as allreduce_bench.py): never exits
 nonzero, never ends on a traceback, emits EXACTLY ONE payload line; a
 wall-clock budget drops unfinished (batch, impl) pairs LOUDLY under
 ``"skipped"``, and SIGTERM emits best-so-far.

@@ -1,4 +1,4 @@
-"""Train+serve co-scheduler e2e smoke (tpu_watch's ``cosched_smoke`` stage).
+"""Train+serve co-scheduler e2e smoke.
 
 Drives ``python -m simclr_tpu.coscheduler`` through its FULL lifecycle on
 CPU — 2 training processes x 2 virtual devices plus the in-process serve
@@ -23,9 +23,9 @@ tier — and judges the whole co-scheduling claim:
      global batch, so the run's per-epoch losses must match an
      uninterrupted same-seed single-process reference within 5e-2.
 
-Contract (bench.py family): exits 0 ALWAYS and prints exactly one JSON
-payload line — the watcher's done-marker greps (swaps, reallocations,
-generation consistency, no error field) are the judge, not the exit code.
+Contract: exits 0 ALWAYS and prints exactly one JSON payload line — its
+fields (swaps, reallocations, generation consistency, no error field) are
+the judge, not the exit code.
 Reuses the scrubbed-env/backstop plumbing from ``multihost_dryrun.py``
 (same directory, so it imports directly). ``COSCHED_SMOKE_TIMEOUT_S``
 overrides the co-scheduler phase's own deadline (default 1500 s — it

@@ -17,27 +17,26 @@ ONE JSON payload line:
      "process_count": 2, "parity": true,
      "multi": {...worker line...}, "single": {...worker line...}}
 
-On a TPU host this is the ``multihost_dryrun`` stage of
-``scripts/tpu_watch.sh``; its done-marker requires ``"process_count": 2``
-and ``"parity": true``. Robustness contract (same as allreduce_bench.py):
+A passing run has ``"process_count": 2`` and ``"parity": true``.
+Robustness contract (same as allreduce_bench.py):
 never exits nonzero, never ends on a traceback, emits EXACTLY ONE payload
 line; failures land in an ``"error"`` field.
 
-``--fleet`` runs the fleet-observability smoke (the ``fleet_smoke``
-watcher stage): a short fault-free 2-process elastic run with
+``--fleet`` runs the fleet-observability smoke: a short fault-free
+2-process elastic run with
 ``telemetry.fleet=true``, whose supervisor-side FleetCollector must expose
 a merged scrape carrying gauges labeled for BOTH hosts plus the
 straggler-skew gauge, and embed the fleet snapshot into
 ``supervisor_summary.json``. The evidence lines from the merged scrape are
-printed verbatim (the stage's done-marker greps them), then ONE payload::
+printed verbatim, then ONE payload::
 
     {"metric": "fleet_smoke", "value": 1.0, "unit": "bool",
      "hosts_seen": ["0", "1"], "skew_ratio": 1.08,
      "summary_embeds_fleet": true, ...}
 
 ``--elastic`` runs the OTHER multi-host proof instead — the elastic
-supervisor's full kill/remesh/grow-back cycle (the ``elastic_dryrun``
-watcher stage): a 2-process CPU pretrain whose process 1 is hard-killed
+supervisor's full kill/remesh/grow-back cycle: a 2-process CPU pretrain
+whose process 1 is hard-killed
 mid-run via ``SIMCLR_FAULT_DIE_PROCESS``, which must remesh down to 1
 process, resume from the last verified checkpoint with the global batch
 preserved, grow back to 2 processes, and finish clean — then an
@@ -88,7 +87,7 @@ _PAYLOAD_EMITTED = False
 
 
 def _emit_payload(payload: dict) -> None:
-    """Print the run's single payload line, exactly once (bench.py contract)."""
+    """Print the run's single payload line, exactly once."""
     global _PAYLOAD_EMITTED
     if _PAYLOAD_EMITTED:
         return
@@ -141,7 +140,7 @@ def _run(cmd: list[str], env: dict, timeout_s: float, label: str) -> dict:
         cmd, env=env, capture_output=True, text=True, timeout=timeout_s,
         cwd=REPO_ROOT,
     )
-    # surface worker stderr for the watcher log, prefixed as commentary
+    # surface worker stderr for the log, prefixed as commentary
     for line in proc.stderr.splitlines()[-20:]:
         print(f"# [{label}] {line}", file=sys.stderr)
     if proc.returncode != 0:
@@ -236,7 +235,7 @@ class _FleetWatch:
 
     Collects the acceptance evidence: at least one gauge labeled for EACH
     host, the straggler-skew gauge (and its last positive value), and a
-    few verbatim sample lines for the watcher log / done-marker greps.
+    few verbatim sample lines for the log.
     """
 
     def __init__(self, run_dir: str):
@@ -287,8 +286,7 @@ class _FleetWatch:
         }
 
     def print_samples(self) -> None:
-        # the evidence lines verbatim: tpu_watch's fleet_smoke done-marker
-        # greps this output for the host="1" label and the skew gauge
+        # the evidence lines verbatim: the host="1" label and the skew gauge
         for key in ("host0", "host1", "skew"):
             if key in self.sample_lines:
                 print(self.sample_lines[key], flush=True)
@@ -449,7 +447,7 @@ def elastic_main() -> None:
     # fleet acceptance: merged scrape carried gauges for BOTH hosts plus
     # the skew gauge, and the run-end summary embeds the fleet snapshot.
     # The embedded snapshot itself is kept OUT of the payload (its per-host
-    # "error" keys would trip the watcher's no-error grep).
+    # "error" keys would read as a failure of this run).
     embeds_fleet = (
         isinstance(summary.pop("fleet", None), dict)
         and _summary_embeds_fleet(elastic_dir)

@@ -10,9 +10,7 @@ exporter is publishing LIVE epoch telemetry, not a dead registry), reads
 (``POST /debug/trace``), and finally SIGTERMs the run — which must land a
 preempt checkpoint and exit through the 0/75 contract.
 
-The full /metrics payload is printed so the collection log keeps the metric
-catalog; scripts/tpu_watch.sh's ``obs_smoke`` done-marker greps it for the
-throughput gauge.
+The full /metrics payload is printed so the log keeps the metric catalog.
 
     python scripts/obs_smoke.py [--timeout 600] [-- override ...]
 """

@@ -2,10 +2,9 @@
 
 Compares forward_mode (two_pass vs concat), fused Pallas NT-Xent, remat,
 epoch-compiled scan, and the superepoch K-sweep (one program per K epochs;
-reports compile time and host syncs per epoch) against the bench.py default,
-all with value-fetch synchronization (see bench.py's measurement-integrity
-note). Prints one JSON line per variant. Not part of the driver bench
-contract — a tuning tool.
+reports compile time and host syncs per epoch) against the default step,
+each timed window closed by fetching the loss. Prints one JSON line per
+variant. A tuning tool, not a benchmark.
 
 Usage: python scripts/perf_explore.py [--steps 100] [--batch 512]
        [--variants two_pass,concat,...]
@@ -81,7 +80,6 @@ def build_state(model, tx, mesh):
 
 
 def time_stepwise(step, state, batches, rng, warmup, steps):
-    # shared sync discipline with bench.py (value-fetch fences)
     dt, loss, _ = time_step_loop(step, state, batches, rng, warmup, steps)
     return dt, loss
 

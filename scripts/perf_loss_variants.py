@@ -31,7 +31,7 @@ from simclr_tpu.ops.ntxent_pallas import ntxent_loss_fused
 
 
 def time_loss(fn, z0, z1, steps):
-    """Time value+grad with value-fetch sync (see bench.py)."""
+    """Time value+grad; the loss fetch closes each timed window."""
     grad_fn = jax.jit(jax.value_and_grad(lambda a, b: fn(a, b, 0.5), argnums=(0, 1)))
     loss, grads = grad_fn(z0, z1)
     float(loss)  # compile + drain

@@ -1,6 +1,6 @@
-"""Superepoch evidence smoke for the real chip (tpu_watch `superepoch` stage).
+"""Superepoch evidence smoke for the real chip.
 
-Proves, ON the accelerator, the three claims the done-marker requires:
+Proves, ON the accelerator, three claims:
 
 1. PARITY — a K-epoch superepoch program reproduces K sequential
    single-epoch programs (same index matrices, same absolute-step RNG

@@ -14,12 +14,6 @@ Entry points (module-level, mirroring the reference CLI):
 """
 
 from simclr_tpu.config import Config, ConfigError, load_config
-from simclr_tpu.utils.platform import ensure_platform
-
-# Re-apply JAX_PLATFORMS before any submodule touches a device: environments
-# that pin a platform in sitecustomize otherwise override the env var (see
-# utils/platform.py). Must run at package import, ahead of lazy backend init.
-ensure_platform()
 
 __version__ = "0.1.0"
 

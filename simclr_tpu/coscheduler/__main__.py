@@ -31,6 +31,9 @@ def main(argv: list[str] | None = None) -> int:
         prog="python -m simclr_tpu.coscheduler",
         description="Continuous train+serve co-scheduler: supervised "
         "pretraining + checkpoint-hot-reloading serve tier on one pod.",
+        epilog="On hardware a chip belongs to one process: run ONE process per host. All of this command's children start on this machine and nothing partitions its chips between them, so without --force-cpu they would each claim every chip; it has only ever run as a CPU dry-run. The serve tier "
+        "also initializes JAX in this parent process before the training "
+        "children start.",
     )
     parser.add_argument(
         "--nprocs", type=int, required=True,
@@ -90,7 +93,9 @@ def main(argv: list[str] | None = None) -> int:
     )
 
     from simclr_tpu.coscheduler.core import CoScheduler
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
+    configure_compilation_cache()
     # serve./cosched. keys configure this process; the training children's
     # strict pretrain config would reject them
     train_overrides = [

@@ -197,9 +197,10 @@ def adjust_saturation(image: jnp.ndarray, factor: jnp.ndarray) -> jnp.ndarray:
     return jnp.clip(gray + factor * (image - gray), 0.0, 1.0)
 
 
-def adjust_hue(image: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
-    """Shift hue by ``delta`` (in turns, torchvision range [-0.5, 0.5])."""
-    r, g, b = image[..., 0], image[..., 1], image[..., 2]
+def _adjust_hue_planes(r, g, b, delta):
+    """:func:`adjust_hue` on separate, same-shaped channel planes; returns
+    the shifted ``(r, g, b)``. The fused kernel (``ops/augment_pallas.py``)
+    calls this on lane-dense planes, so the two paths share one formula."""
     maxc = jnp.maximum(jnp.maximum(r, g), b)
     minc = jnp.minimum(jnp.minimum(r, g), b)
     value = maxc
@@ -224,16 +225,26 @@ def adjust_hue(image: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
     t = value * (1.0 - sat * (1.0 - f))
     i = i.astype(jnp.int32) % 6
 
-    r_out = jnp.select(
-        [i == 0, i == 1, i == 2, i == 3, i == 4, i == 5], [value, q, p, p, t, value]
+    def by_sector(*choices):
+        # a where-chain, not jnp.select: select lowers through an integer
+        # argmax, which Mosaic does not implement
+        out = choices[5]
+        for sector in (4, 3, 2, 1, 0):
+            out = jnp.where(i == sector, choices[sector], out)
+        return out
+
+    r_out = by_sector(value, q, p, p, t, value)
+    g_out = by_sector(t, value, value, q, p, p)
+    b_out = by_sector(p, p, t, value, value, q)
+    return tuple(jnp.clip(c, 0.0, 1.0) for c in (r_out, g_out, b_out))
+
+
+def adjust_hue(image: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
+    """Shift hue by ``delta`` (in turns, torchvision range [-0.5, 0.5])."""
+    return jnp.stack(
+        _adjust_hue_planes(image[..., 0], image[..., 1], image[..., 2], delta),
+        axis=-1,
     )
-    g_out = jnp.select(
-        [i == 0, i == 1, i == 2, i == 3, i == 4, i == 5], [t, value, value, q, p, p]
-    )
-    b_out = jnp.select(
-        [i == 0, i == 1, i == 2, i == 3, i == 4, i == 5], [p, p, t, value, value, q]
-    )
-    return jnp.clip(jnp.stack([r_out, g_out, b_out], axis=-1), 0.0, 1.0)
 
 
 _JITTER_PERMS = np.array(list(itertools.permutations(range(4))), dtype=np.int32)

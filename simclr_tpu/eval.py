@@ -755,9 +755,9 @@ def run_eval(cfg: Config) -> dict:
 def main(argv: list[str] | None = None):
     from simclr_tpu.config import run_multirun, split_multirun_flag
     from simclr_tpu.parallel.multihost import maybe_initialize_multihost
-    from simclr_tpu.utils.platform import ensure_platform
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
-    ensure_platform()
+    configure_compilation_cache()
     maybe_initialize_multihost()
     multirun, args = split_multirun_flag(list(sys.argv[1:] if argv is None else argv))
     if multirun:

@@ -25,9 +25,9 @@ from simclr_tpu.utils.torch_export import save_torch_checkpoint
 
 
 def main(argv: list[str] | None = None) -> list[str]:
-    from simclr_tpu.utils.platform import ensure_platform
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
-    ensure_platform()
+    configure_compilation_cache()
     ap = argparse.ArgumentParser(
         prog="python -m simclr_tpu.export_torch", description=__doc__
     )

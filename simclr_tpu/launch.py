@@ -6,13 +6,17 @@ into each (``/root/reference/launch.py:202-259``). Under SPMD there is one
 process per HOST, so this launcher exists for the two situations where
 something must start those host processes:
 
-  * **Local multi-process testing** (``--nprocs N``): spawns N processes on
-    this machine, each a full JAX distributed participant with its own block
-    of virtual CPU devices — the only way to exercise true multi-PROCESS
-    semantics (``jax.make_array_from_process_local_data``, per-host input
-    sharding, cross-process collectives over the distributed runtime) without
-    a real multi-host slice. An 8-device single-process mesh cannot cover
-    this: it has one address space and one input pipeline.
+  * **Local multi-process testing** (``--nprocs N --devices-per-proc D``):
+    a CPU dry-run. Spawns N processes on this machine, each a full JAX
+    distributed participant with its own block of virtual CPU devices — the
+    only way to exercise true multi-PROCESS semantics
+    (``jax.make_array_from_process_local_data``, per-host input sharding,
+    cross-process collectives over the distributed runtime) without a real
+    multi-host slice. An 8-device single-process mesh cannot cover this: it
+    has one address space and one input pipeline. Without
+    ``--devices-per-proc`` every child would claim all of the host's chips
+    (a chip belongs to one process, and nothing here partitions them): on
+    hardware use the ``--proc-id`` form, one process per host.
   * **Unmanaged multi-host launch** (``--proc-id I --nprocs N --coordinator
     HOST:PORT``): runs the training module in-process on each host of a
     cluster that lacks auto-discovery (no Cloud TPU metadata, no SLURM).
@@ -50,7 +54,9 @@ def _parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     )
     parser.add_argument(
         "--nprocs", type=int, default=1,
-        help="total number of JAX processes (hosts) in the job",
+        help="total number of JAX processes (hosts) in the job; on hardware "
+        "one process per host (--proc-id), spawning all of them locally is "
+        "a CPU dry-run (--devices-per-proc)",
     )
     parser.add_argument(
         "--proc-id", type=int, default=None,

@@ -43,6 +43,7 @@ from simclr_tpu.obs.compile import maybe_sentry
 from simclr_tpu.obs.device import maybe_dump_oom_profile, maybe_monitor
 from simclr_tpu.obs.events import EventLog
 from simclr_tpu.obs.exporter import maybe_start_exporter
+from simclr_tpu.obs.peaks import chip_peak
 from simclr_tpu.obs.telemetry import Telemetry
 from simclr_tpu.ops.lars import get_weight_decay_mask, lars
 from simclr_tpu.parallel.compress import DEFAULT_COMM_CHUNKS, normalize_overlap
@@ -86,6 +87,7 @@ from simclr_tpu.utils.checkpoint import (
     save_checkpoint,
 )
 from simclr_tpu.utils.logging import get_logger, is_logging_host
+from simclr_tpu.utils.platform import compilation_cache_stats, device_identity
 from simclr_tpu.utils.profiling import StepTimer, StepTraceWindow
 from simclr_tpu.utils.schedule import calculate_initial_lr, warmup_cosine_schedule
 
@@ -191,6 +193,8 @@ def run_pretrain(cfg: Config) -> dict:
     # registry + events.jsonl timeline, fed only host floats the loop
     # already fetches — scraping adds zero device syncs
     n_hosts = mesh_host_count(mesh)
+    device = device_identity()
+    peak = chip_peak(device["platform"], device["kind"])
     telemetry = Telemetry(
         arch=str(cfg.experiment.base_cnn),
         per_device_batch=int(cfg.experiment.batches),
@@ -204,6 +208,7 @@ def run_pretrain(cfg: Config) -> dict:
         augment_impl=str(cfg.select("runtime.augment_impl", "xla")),
         comm_overlap=comm_overlap,
         comm_chunks=comm_chunks,
+        peak_flops=peak.bf16_flops_per_s if peak else None,
     )
     events = EventLog(
         save_dir,
@@ -598,9 +603,11 @@ def run_pretrain(cfg: Config) -> dict:
     if is_logging_host():
         os.makedirs(save_dir, exist_ok=True)
         logger.info(
-            "pretrain %s: %d params, mesh %s, global batch %d (%d/device), "
+            "pretrain %s: platform=%s device_kind=%r devices=%d, %d params, "
+            "mesh %s, global batch %d (%d/device), "
             "%d steps/epoch, %d epochs, lr0 %.4f, negatives=%s",
-            cfg.experiment.name, param_count(state.params), dict(mesh.shape),
+            cfg.experiment.name, device["platform"], device["kind"],
+            device["count"], param_count(state.params), dict(mesh.shape),
             global_batch, cfg.experiment.batches, steps_per_epoch, epochs, lr0,
             cfg.select("loss.negatives", "global"),
         )
@@ -645,6 +652,13 @@ def run_pretrain(cfg: Config) -> dict:
             return
         from simclr_tpu.utils.ioutil import atomic_write
 
+        # every write names the devices the numbers came from, and what the
+        # persistent compilation cache did for this process
+        summary = {
+            **summary,
+            "device": device,
+            "compile_cache": compilation_cache_stats(),
+        }
         atomic_write(
             os.path.join(save_dir, "pretrain_results.json"),
             lambda f: json.dump(summary, f, indent=1),
@@ -1100,6 +1114,7 @@ def run_pretrain(cfg: Config) -> dict:
         "save_dir": save_dir,
         "global_batch": global_batch,
         "n_data_shards": n_data,
+        "device": device,
         "lr0": lr0,
         "imgs_per_sec_steady": throughput["imgs_per_sec"],
     }
@@ -1121,9 +1136,9 @@ def main(argv: list[str] | None = None):
     from simclr_tpu.config import run_multirun, split_multirun_flag
     from simclr_tpu.parallel.multihost import maybe_initialize_multihost
     from simclr_tpu.supervisor.guard import EXIT_POISONED, EXIT_PREEMPTED
-    from simclr_tpu.utils.platform import ensure_platform
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
-    ensure_platform()
+    configure_compilation_cache()
     maybe_initialize_multihost()
     multirun, args = split_multirun_flag(list(sys.argv[1:] if argv is None else argv))
     # exit-code contract (docs/FAULT_TOLERANCE.md): 75 = preempted but

@@ -44,9 +44,9 @@ from simclr_tpu.parallel.mesh import (
     MeshSpec,
     create_mesh,
     put_row_sharded,
-    shard_map,
 )
 from simclr_tpu.parallel.multihost import maybe_initialize_multihost
+from simclr_tpu.utils.platform import configure_compilation_cache
 
 # dataset rows per data shard; small enough to run anywhere, large enough
 # that a wrong row block changes the checksum
@@ -89,8 +89,9 @@ def run() -> dict:
         return jnp.sum(out)[None]
 
     fn = jax.jit(
-        shard_map(
-            body, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS)
+        jax.shard_map(
+            body, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS),
+            check_vma=False,
         )
     )
     per_shard = fn(resident)
@@ -114,6 +115,7 @@ def run() -> dict:
 
 
 def main() -> None:
+    configure_compilation_cache()
     result = run()
     if jax.process_index() == 0:
         print(json.dumps(result), flush=True)

@@ -1,15 +1,20 @@
 """ctypes bindings for the native gather library, with lazy self-build.
 
-Build strategy: compile ``gather.cpp`` once with g++ into a per-repo cache
-(``_build/libsimclr_gather.so``) on first use; any failure (no compiler,
-read-only FS) flips to the NumPy fallback permanently for the process.
-ctypes rather than pybind11 because this environment ships no pybind11 and
-the ABI here is two flat C functions over raw pointers.
+Build strategy: compile ``gather.cpp`` with g++ into ``_build/`` on first
+use and record the source's sha256 next to the library. The library is
+rebuilt whenever that record does not match ``gather.cpp`` as it is on disk,
+so only tracked source decides what runs — a ``.so`` left over from another
+checkout or copied in from another machine is never loaded as it is. A failed
+build (no compiler, read-only FS) logs one warning and selects the NumPy path
+for the rest of the process. ctypes rather than pybind11 because this
+environment ships no pybind11 and the ABI here is two flat C functions over
+raw pointers.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,6 +25,7 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "gather.cpp")
 _BUILD_DIR = os.path.join(_DIR, "_build")
 _SO = os.path.join(_BUILD_DIR, "libsimclr_gather.so")
+_SO_SOURCE_HASH = _SO + ".source-sha256"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -28,17 +34,38 @@ _tried = False
 DEFAULT_THREADS = min(8, os.cpu_count() or 1)
 
 
-def _build() -> bool:
+def _source_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_from(source_hash: str) -> bool:
+    """True when ``_SO`` exists and was built from exactly this source."""
+    try:
+        with open(_SO_SOURCE_HASH) as f:
+            return os.path.exists(_SO) and f.read().strip() == source_hash
+    except OSError:
+        return False
+
+
+def _build(source_hash: str) -> None:
+    """Compile ``gather.cpp`` and record the hash it was built from. Raises
+    ``OSError`` / ``subprocess.SubprocessError`` on failure."""
+    from simclr_tpu.utils.ioutil import atomic_write
+
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{_SO}.tmp.{os.getpid()}"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-        _SRC, "-o", _SO,
+        _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    atomic_write(_SO_SOURCE_HASH, lambda f: f.write(source_hash + "\n"))
 
 
 def _load() -> ctypes.CDLL | None:
@@ -47,11 +74,20 @@ def _load() -> ctypes.CDLL | None:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _build():
-            return None
         try:
+            source_hash = _source_hash()
+            if not _built_from(source_hash):
+                _build(source_hash)
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            from simclr_tpu.utils.logging import get_logger
+
+            detail = getattr(e, "stderr", b"") or b""
+            get_logger().warning(
+                "native gather library unavailable (%r %s); host batch "
+                "gathers use the NumPy path for this process",
+                e, detail.decode(errors="replace").strip()[-500:],
+            )
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -22,11 +22,12 @@ loses its performance:
   the moment it happens: alarm counter + ``recompile_alarm`` event +
   the PR 7 rate-limited auto-trace hook.
 
-``watch`` prefers the AOT path (``fn.lower(*args).compile()`` — the only
+``watch`` takes the AOT path (``fn.lower(*args).compile()`` — the only
 way to both time a compile precisely and keep the executable for
-fingerprint/cost analysis) and degrades to plain dispatch with
-call-duration timing when a backend or wrapper lacks ``lower``. Stdlib-only
-at import time; jax is imported lazily inside the signature helper.
+fingerprint/cost analysis); a lowering or compile error propagates. Only a
+wrapped callable that has no ``lower`` at all is dispatched plainly with
+call-duration timing. Stdlib-only at import time; jax is imported lazily
+inside the signature helper.
 """
 
 from __future__ import annotations
@@ -43,17 +44,11 @@ def executable_cost(compiled) -> tuple[float, float]:
     """(flops, bytes_accessed) from an AOT executable's XLA cost analysis.
 
     The extraction ``scripts/perf_attrib.py`` used privately, promoted so
-    live telemetry and the attribution script agree by construction.
-    ``cost_analysis`` may return a per-computation list (older jax) or one
-    dict; missing keys and backends without cost analysis degrade to
-    ``(0.0, 0.0)`` rather than raising.
+    live telemetry and the attribution script agree by construction. A
+    backend whose analysis omits a key reports 0.0 for it.
     """
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
-    except Exception:
-        return 0.0, 0.0
+    ca = compiled.cost_analysis() or {}
+    return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 
 def lowered_fingerprint(lowered) -> str:
@@ -63,11 +58,7 @@ def lowered_fingerprint(lowered) -> str:
     identically, so a changed fingerprint in a ``compile`` event names a
     genuinely different program, not a re-run.
     """
-    try:
-        text = lowered.as_text()
-    except Exception:
-        return ""
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
 
 
 def args_signature(args: tuple) -> tuple:
@@ -130,13 +121,17 @@ class CompileSentry:
         bytes_accessed: float = 0.0,
         steps_per_call: int = 1,
         warm: bool = False,
+        cache_hit: bool | None = None,
     ) -> dict:
         """Book one observed compilation of executable ``name``.
 
         ``steps_per_call`` normalizes cost for epoch-compiled programs (one
         executable runs a whole epoch's scan) so per-step cost gauges and
         the MFU drift compare like with like. ``warm=True`` marks a
-        post-warmup compilation and fires the alarm path.
+        post-warmup compilation and fires the alarm path. ``cache_hit``
+        says whether JAX's persistent compilation cache supplied the
+        executable (``seconds`` is then lowering plus a load, not a
+        compile); ``None`` when the caller cannot tell.
         """
         steps = max(int(steps_per_call), 1)
         record = {
@@ -148,6 +143,8 @@ class CompileSentry:
             "steps_per_call": steps,
             "recompile": bool(warm),
         }
+        known = {} if cache_hit is None else {"cache_hit": bool(cache_hit)}
+        record.update(known)
         with self._lock:
             self.records.append(record)
             self.compiles += 1
@@ -169,6 +166,7 @@ class CompileSentry:
                 flops=record["flops"],
                 bytes_accessed=record["bytes_accessed"],
                 recompile=bool(warm),
+                **known,
             )
         if warm:
             if self.telemetry is not None:
@@ -233,20 +231,10 @@ class WatchedFunction:
         warm = self._calls > 0
         clock = self._sentry._clock
         t0 = clock()
-        compiled = None
-        fingerprint = ""
-        flops = bytes_accessed = 0.0
-        try:
-            lowered = self._fn.lower(*args)
-            fingerprint = lowered_fingerprint(lowered)
-            compiled = lowered.compile()
-            flops, bytes_accessed = executable_cost(compiled)
-        except Exception:
-            compiled = None
-        if compiled is None:
-            # no AOT on this backend/wrapper: dispatch plainly — the first
-            # call at a new signature still IS the compiling call, so its
-            # duration is the (upper-bound) compile time
+        if not hasattr(self._fn, "lower"):
+            # not a jitted function: dispatch plainly — the first call at a
+            # new signature still IS the compiling call, so its duration is
+            # the (upper-bound) compile time
             out = self._fn(*args)
             self._sentry.record_compile(
                 self.name,
@@ -257,14 +245,22 @@ class WatchedFunction:
             self._cache[sig] = self._fn
             self._calls += 1
             return out
+        from simclr_tpu.utils.platform import compilation_cache_stats
+
+        cache_hits = compilation_cache_stats()["hits"]
+        lowered = self._fn.lower(*args)
+        compiled = lowered.compile()
+        cache_hit = compilation_cache_stats()["hits"] > cache_hits
+        flops, bytes_accessed = executable_cost(compiled)
         self._sentry.record_compile(
             self.name,
             seconds=clock() - t0,
-            fingerprint=fingerprint,
+            fingerprint=lowered_fingerprint(lowered),
             flops=flops,
             bytes_accessed=bytes_accessed,
             warm=warm,
             steps_per_call=self._steps_per_call(args),
+            cache_hit=cache_hit,
         )
         self._cache[sig] = compiled
         self._calls += 1

@@ -16,8 +16,7 @@ The last output line is always machine-greppable::
 was actually computed (``REGRESSION`` when it falls below
 ``--threshold``); ``NO_BASELINE``/``NO_DATA`` mean the comparison could
 not happen.  The CLI exits 0 whenever a report was produced — the
-verdict line, not the exit code, carries the judgement (the
-``run_report`` stage in ``scripts/tpu_watch.sh`` greps for it).
+verdict line, not the exit code, carries the judgement.
 
 Deliberately jax-free (stdlib + ``obs.events`` + ``supervisor.heartbeat``,
 both stdlib-only) so it runs on any machine holding the run directory.
@@ -472,7 +471,7 @@ def render_report(report: dict) -> str:
         f"threshold={report['threshold']}"
     )
     # keep this the LAST line and the format stable: tooling greps
-    # '^run_report verdict: ' (scripts/tpu_watch.sh run_report stage)
+    # '^run_report verdict: '
     lines.append(f"run_report verdict: {report['verdict']} ({detail})")
     return "\n".join(lines)
 

@@ -9,9 +9,9 @@ lr, wall-clock epoch durations. Rendering ``/metrics`` reads those floats
 back; no method here ever touches a ``jax.Array``, so a scrape can never
 add a device round-trip to the hot loop.
 
-MFU reuses the analytic FLOP model from ``scripts/roofline_model.py`` (the
-same math that defended the measured 49% MFU as a ceiling fraction): FLOPs
-per device-step divided by measured step time over the v5e bf16 peak.
+MFU reuses the analytic FLOP model from ``scripts/roofline_model.py``: FLOPs
+per device-step divided by measured step time over the chip's bf16 peak
+(``obs/peaks.py``, keyed by ``device_kind``; no peak, no MFU).
 Grad-allreduce wire bytes come from
 :func:`simclr_tpu.parallel.compress.allreduce_wire_bytes` — analytic, per
 device, per step.
@@ -24,11 +24,6 @@ import threading
 import time
 
 from simclr_tpu.obs.metrics import Counter, Gauge, Histogram, Summary
-
-# v5e bf16 peak, mirrored from scripts/roofline_model.py (scripts/ is not a
-# package; the FLOP model itself is file-loaded below so the math has one
-# home, but the peak constant is needed even when scripts/ is absent)
-PEAK_FLOPS = 197e12
 
 # step-time bucket bounds (seconds): 1 ms (CIFAR-small steps on chip) up
 # through minutes (epoch_compile ticks once per epoch)
@@ -86,6 +81,8 @@ class Telemetry:
     ``flops_per_step`` applies to the PRETRAIN step shape (two views +
     NT-Xent + LARS); the supervised entry point passes ``arch=None`` so its
     MFU gauge honestly reads 0 instead of borrowing the wrong model.
+    ``peak_flops`` is the chip's bf16 peak from ``obs/peaks.py``; ``None``
+    (the CPU platform) leaves the MFU and exposed-comm gauges unset.
     """
 
     def __init__(
@@ -103,11 +100,11 @@ class Telemetry:
         augment_impl: str = "xla",
         comm_overlap: str = "off",
         comm_chunks: int = 1,
-        peak_flops: float = PEAK_FLOPS,
+        peak_flops: float | None = None,
     ):
         self.global_batch = int(global_batch)
         self.n_devices = max(int(n_devices), 1)
-        self.peak_flops = float(peak_flops)
+        self.peak_flops = float(peak_flops) if peak_flops else None
         self.flops_per_step = (
             _roofline_flops_per_step(arch, per_device_batch, d, augment_impl)
             if arch else None
@@ -127,8 +124,9 @@ class Telemetry:
             "Per-device training throughput over the last epoch")
         self.mfu = Gauge(
             "simclr_train_mfu",
-            "Model FLOPs utilization vs the bf16 peak, from the roofline "
-            "FLOP model (scripts/roofline_model.py; 0 when no model applies)")
+            "Model FLOPs utilization vs the chip's bf16 peak, from the roofline "
+            "FLOP model (scripts/roofline_model.py; 0 when no model or no "
+            "peak applies)")
         self.loss = Gauge(
             "simclr_train_loss", "Epoch-mean training loss (last epoch)")
         self.lr = Gauge(
@@ -270,7 +268,7 @@ class Telemetry:
         rate = steps * self.global_batch / seconds
         self.imgs_per_sec.set(rate)
         self.imgs_per_sec_per_chip.set(rate / self.n_devices)
-        if self.flops_per_step:
+        if self.flops_per_step and self.peak_flops:
             self.mfu.set(self.flops_per_step / (step_time * self.peak_flops))
             # what the step spent beyond roofline compute: at 100% overlap
             # this tends to 0, and the off->chunked->async deltas attribute

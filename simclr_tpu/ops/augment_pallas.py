@@ -3,8 +3,7 @@
 The XLA augmentation path (``data/augment.py`` vmapped per example) is
 correct but traffic-heavy: each view materializes the per-example bilinear
 weight matrices and several batch-sized float32 temporaries in HBM, and the
-uint8 source rows are re-read per view — measured at 2.2 ms for 1024 images
-(~7% of the step, docs/PERF.md). This kernel reads a tile of resident
+uint8 source rows are re-read per view. This kernel reads a tile of resident
 **uint8** rows into VMEM once and emits BOTH augmented float32 views in a
 single pass: in-VMEM dequant (``to_float`` semantics, uint8 never touches
 HBM as float), the two bilinear crop/resize contractions, horizontal flip,
@@ -21,15 +20,27 @@ tests keep measuring the one true sampler and a knob flip changes the
 schedule, not the draw. The kernel is a pure deterministic function of
 (uint8 tile, per-view parameter rows).
 
-The bilinear weights are rebuilt in-VMEM from the 4 crop-box scalars via
-iota comparisons (equal to ``_axis_resize_weights``' scatter-add form,
-including the clipped ``i0 == i1`` edge where both taps land on one column
-and sum to 1), so the kernel's inputs per view are just
-``(batch, _N_PARAMS)`` floats instead of ``(batch, out, H)+(batch, out, W)``
-weight tensors.
+Layout, chosen for what Mosaic compiles (v5e, jax 0.9 / libtpu 0.0.34):
 
-Runs compiled on TPU; everywhere else (CPU tests) falls back to
-``interpret=True`` automatically, exactly like ``ntxent_pallas``.
+  * images enter and leave as ``(batch, H, W*3)`` — the free reshape of
+    NHWC, lane-dense with the three channels interleaved along the lanes —
+    in blocks of ``tile`` images; a ``fori_loop`` walks the tile one image
+    at a time, so every value in the body is a 2-D ``(32, 96)`` array;
+  * the per-view parameter rows sit in SMEM and are read as scalars
+    (``lax.cond`` / ``lax.switch`` on them run exactly one jitter op per
+    slot instead of computing all four and selecting);
+  * uint8 widens through int32 (Mosaic has no uint8 -> float32 cast);
+  * the bilinear weights are rebuilt from the 4 crop-box scalars via iota
+    comparisons (equal to ``_axis_resize_weights``' scatter-add form,
+    including the clipped ``i0 == i1`` edge where both taps land on one
+    column and sum to 1). The column resize is one ``(W*3, out*3)`` matmul
+    per channel whose result is that channel broadcast over each pixel's
+    three lanes, so the color ops see r, g and b as same-shaped dense planes
+    and no lane is ever sliced or shuffled; the horizontal flip is folded
+    into that matrix by reversing its output index.
+
+Compiled by Mosaic on TPU. ``interpret=True`` is used on the CPU backend
+only, which is how the CPU tests run the kernel.
 """
 
 from __future__ import annotations
@@ -39,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from simclr_tpu.data import augment as _aug
 
@@ -61,17 +73,19 @@ def validate_impl(impl: str) -> str:
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # the Pallas interpreter is for the CPU backend only (tests); every other
+    # backend compiles the kernel or fails
+    return jax.default_backend() == "cpu"
 
 
 def _tile_and_pad(n: int) -> tuple[int, int]:
     """(tile, padded_n) over the batch axis.
 
     Small batches round up to a multiple of 8 so one tile covers everything;
-    large batches tile at 32 rows (≈0.4 MiB of uint8 source + ≈3 MiB of f32
-    working set per view — comfortably inside VMEM with both views live).
-    Padded tail rows carry zero parameter rows (a degenerate but finite
-    crop) and are sliced off after the call.
+    large batches tile at 32 images (96 KiB of uint8 source and 384 KiB of
+    float32 output per view, double-buffered — far inside VMEM). Padded
+    tail rows carry zero parameter rows (a degenerate but finite crop) and
+    are sliced off after the call.
     """
     tile = 32 if n >= 32 else -(-n // 8) * 8
     return tile, -(-n // tile) * tile
@@ -123,115 +137,137 @@ def _view_params(
 
 
 # ---------------------------------------------------------------------------
-# in-kernel ops (batched over the tile axis, all VMEM-resident)
+# in-kernel ops (one image at a time, 2-D lane-dense values)
 # ---------------------------------------------------------------------------
 
-def _axis_weights(origin, size, out_size: int, in_size: int) -> jnp.ndarray:
-    """(tile, out_size, in_size) bilinear weights from per-row box scalars.
-
-    Comparison form of ``augment._axis_resize_weights``' scatter-add: both
-    taps are written via iota equality, so the clipped ``i0 == i1`` border
-    case sums the two taps into one column exactly like ``.at[].add`` does.
-    Index values are small exact integers in float32, so ``==`` is exact.
-    """
-    tn = origin.shape[0]
-    dst = jax.lax.broadcasted_iota(jnp.float32, (tn, out_size), 1)
-    centers = origin[:, None] + (dst + 0.5) * (size[:, None] / out_size) - 0.5
-    centers = jnp.clip(
-        centers, origin[:, None], origin[:, None] + size[:, None] - 1.0
+def _dot(a, b):
+    # float32 passes on the MXU: the bilinear taps and the pixels keep their
+    # mantissa, as they do in the CPU reference
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
+
+
+def _float_iota(shape, dimension: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dimension).astype(
+        jnp.float32
+    )
+
+
+def _bilinear(dst, src, origin, size, out_size: int, in_size: int):
+    """``_axis_resize_weights(origin, size)[dst, src]``, elementwise over
+    same-shaped float index arrays.
+
+    Comparison form of the scatter-add: both taps are written via equality
+    with the source index, so the clipped ``i0 == i1`` border case sums the
+    two taps into one column exactly like ``.at[].add`` does. Index values
+    are small exact integers in float32, so ``==`` is exact.
+    """
+    centers = origin + (dst + 0.5) * (size / out_size) - 0.5
+    centers = jnp.clip(centers, origin, origin + size - 1.0)
     floor = jnp.floor(centers)
     frac = centers - floor
     i0 = jnp.clip(floor, 0.0, in_size - 1.0)
     i1 = jnp.clip(i0 + 1.0, 0.0, in_size - 1.0)
-    src = jax.lax.broadcasted_iota(jnp.float32, (tn, out_size, in_size), 2)
-    return (src == i0[..., None]).astype(jnp.float32) * (
-        1.0 - frac[..., None]
-    ) + (src == i1[..., None]).astype(jnp.float32) * frac[..., None]
+    return jnp.where(src == i0, 1.0 - frac, 0.0) + jnp.where(
+        src == i1, frac, 0.0
+    )
 
 
-def _luma(img: jnp.ndarray) -> jnp.ndarray:
+def _luma(r, g, b):
     w = _aug._GRAY_WEIGHTS  # ITU-R 601, the XLA path's constants
-    return img[..., 0] * w[0] + img[..., 1] * w[1] + img[..., 2] * w[2]
+    return r * w[0] + g * w[1] + b * w[2]
 
 
-def _gray3(img: jnp.ndarray) -> jnp.ndarray:
-    return _luma(img)[..., None] * jnp.ones((3,), jnp.float32)
+def _augment_image(x, p, out_size: int, height: int, width: int):
+    """The crop-to-gray chain for one view of one image.
 
-
-def _brightness(img, f):
-    return jnp.clip(img * f, 0.0, 1.0)
-
-
-def _contrast(img, f):
-    # per-example mean of the grayscale image (augment.adjust_contrast
-    # semantics, batched over the tile axis)
-    mean = _luma(img).mean(axis=(1, 2)).reshape(-1, 1, 1, 1)
-    return jnp.clip(mean + f * (img - mean), 0.0, 1.0)
-
-
-def _saturation(img, f):
-    g = _gray3(img)
-    return jnp.clip(g + f * (img - g), 0.0, 1.0)
-
-
-def _augment_tile(x, p, out_size: int, height: int, width: int):
-    """Both-crop-to-gray chain for one view over one VMEM tile.
-
-    ``x``: (tile, H, W, 3) float32 in [0, 1]; ``p``: (tile, _N_PARAMS).
-    Mirrors ``simclr_augment_single`` stage for stage; the per-example
-    ``lax.switch`` over jitter ops becomes compute-all-and-select, which is
-    what vmap lowers the switch to anyway.
+    ``x``: (H, W*3) float32 in [0, 1], channels interleaved along the lanes;
+    ``p(k)``: scalar parameter ``k`` of this image's row. Mirrors
+    ``simclr_augment_single`` stage for stage and returns (out, out*3).
+    Between the resize and the final store, ``r``/``g``/``b`` are
+    (out, out*3) planes holding each pixel's channel value on all three of
+    its lanes.
     """
-    tn = x.shape[0]
-    w_rows = _axis_weights(p[:, 0], p[:, 2], out_size, height)
-    w_cols = _axis_weights(p[:, 1], p[:, 3], out_size, width)
-    y = jnp.einsum(
-        "toh,thwc->towc", w_rows, x, preferred_element_type=jnp.float32
-    )
-    y = jnp.einsum(
-        "tpw,towc->topc", w_cols, y, preferred_element_type=jnp.float32
-    )
-    flip = p[:, 4].reshape(tn, 1, 1, 1) > 0.5
-    y = jnp.where(flip, jnp.flip(y, axis=2), y)
+    lanes_in, lanes_out = width * 3, out_size * 3
+    dst = _float_iota((out_size, height), 0)
+    src = _float_iota((out_size, height), 1)
+    y = _dot(_bilinear(dst, src, p(0), p(2), out_size, height), x)
 
-    f_b = p[:, 6].reshape(tn, 1, 1, 1)
-    f_c = p[:, 7].reshape(tn, 1, 1, 1)
-    f_s = p[:, 8].reshape(tn, 1, 1, 1)
-    f_h = p[:, 9].reshape(tn, 1, 1)
-    jit = y
-    for slot in range(4):
-        op = p[:, 11 + slot].reshape(tn, 1, 1, 1)
-        jit = jnp.where(
-            op == 0.0,
-            _brightness(jit, f_b),
-            jnp.where(
-                op == 1.0,
-                _contrast(jit, f_c),
-                jnp.where(
-                    op == 2.0,
-                    _saturation(jit, f_s),
-                    _aug.adjust_hue(jit, f_h),
-                ),
-            ),
-        )
-    apply = p[:, 5].reshape(tn, 1, 1, 1) > 0.5
-    y = jnp.where(apply, jit, y)
-    gray = p[:, 10].reshape(tn, 1, 1, 1) > 0.5
-    return jnp.where(gray, _gray3(y), y)
+    lane_in = _float_iota((lanes_in, lanes_out), 0)
+    lane_out = _float_iota((lanes_in, lanes_out), 1)
+    col_in = jnp.floor(lane_in / 3.0)
+    chan_in = lane_in - 3.0 * col_in
+    col_out = jnp.floor(lane_out / 3.0)
+    col_out = jnp.where(p(4) > 0.5, (out_size - 1.0) - col_out, col_out)
+    w_cols = _bilinear(col_out, col_in, p(1), p(3), out_size, width)
+    rgb = tuple(
+        _dot(y, jnp.where(chan_in == channel, w_cols, 0.0))
+        for channel in (0.0, 1.0, 2.0)
+    )
+
+    def brightness(rgb):
+        return tuple(jnp.clip(c * p(6), 0.0, 1.0) for c in rgb)
+
+    def contrast(rgb):
+        # torchvision blends with the mean of the grayscale image; each
+        # pixel's luma sits on three lanes, which leaves the mean unchanged
+        luma = _luma(*rgb)
+        mean = jnp.sum(
+            jnp.sum(luma, axis=1, keepdims=True), axis=0, keepdims=True
+        ) / float(out_size * lanes_out)
+        return tuple(jnp.clip(mean + p(7) * (c - mean), 0.0, 1.0) for c in rgb)
+
+    def saturation(rgb):
+        gray = _luma(*rgb)
+        return tuple(jnp.clip(gray + p(8) * (c - gray), 0.0, 1.0) for c in rgb)
+
+    def hue(rgb):
+        return _aug._adjust_hue_planes(*rgb, p(9))
+
+    # indexed like augment.color_jitter's ops list
+    ops = (brightness, contrast, saturation, hue)
+
+    def jitter(rgb):
+        for slot in range(4):
+            rgb = jax.lax.switch(p(11 + slot).astype(jnp.int32), ops, rgb)
+        return rgb
+
+    def grayscale(rgb):
+        gray = _luma(*rgb)
+        return gray, gray, gray
+
+    rgb = jax.lax.cond(p(5) > 0.5, jitter, lambda rgb: rgb, rgb)
+    r, g, b = jax.lax.cond(p(10) > 0.5, grayscale, lambda rgb: rgb, rgb)
+    lane = _float_iota((out_size, lanes_out), 1)
+    chan_out = lane - 3.0 * jnp.floor(lane / 3.0)
+    return jnp.where(chan_out == 0.0, r, jnp.where(chan_out == 1.0, g, b))
 
 
 def _augment_kernel(*refs, out_size, height, width, scale, n_views):
-    """Grid step: one batch tile. Refs: n_views param blocks, the image
-    block, then n_views output blocks. The source tile is loaded and
+    """Grid step: one batch tile. Refs: n_views SMEM param blocks, the image
+    block, then n_views output blocks. Each source image is loaded and
     dequantized ONCE (``scale`` = 1/255 for uint8 inputs — this is where
     ``to_float`` happens, in VMEM); every view reads the same registers.
     """
+    param_refs = refs[:n_views]
     img_ref = refs[n_views]
-    outs = refs[n_views + 1:]
-    x = img_ref[:].astype(jnp.float32) * scale
-    for v in range(n_views):
-        outs[v][:] = _augment_tile(x, refs[v][:], out_size, height, width)
+    out_refs = refs[n_views + 1:]
+
+    def one_image(t, carry):
+        x = img_ref[t]
+        if x.dtype == jnp.uint8:
+            x = x.astype(jnp.int32)  # Mosaic has no uint8 -> float32 cast
+        x = x.astype(jnp.float32) * scale
+        for param_ref, out_ref in zip(param_refs, out_refs):
+            out_ref[t] = _augment_image(
+                x, lambda k, ref=param_ref: ref[t, k], out_size, height, width
+            )
+        return carry
+
+    jax.lax.fori_loop(0, img_ref.shape[0], one_image, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +287,7 @@ def _fused_views(images, keys_per_view, strength: float, out_size: int):
         _view_params(k, height, width, strength) for k in keys_per_view
     ]
     tn, n_pad = _tile_and_pad(n)
-    imgs = _pad_rows(images, n_pad)
+    imgs = _pad_rows(images.reshape(n, height, width * channels), n_pad)
     params = [_pad_rows(p, n_pad) for p in params]
     n_views = len(params)
     kernel = functools.partial(
@@ -262,26 +298,28 @@ def _fused_views(images, keys_per_view, strength: float, out_size: int):
         scale=scale,
         n_views=n_views,
     )
+    out_lanes = out_size * channels
     views = pl.pallas_call(
         kernel,
         grid=(n_pad // tn,),
-        in_specs=[pl.BlockSpec((tn, _N_PARAMS), lambda i: (i, 0))] * n_views
-        + [pl.BlockSpec((tn, height, width, channels), lambda i: (i, 0, 0, 0))],
-        out_specs=[
+        in_specs=[
             pl.BlockSpec(
-                (tn, out_size, out_size, channels), lambda i: (i, 0, 0, 0)
+                (tn, _N_PARAMS), lambda i: (i, 0), memory_space=pltpu.SMEM
             )
         ]
+        * n_views
+        + [pl.BlockSpec((tn, height, width * channels), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((tn, out_size, out_lanes), lambda i: (i, 0, 0))]
         * n_views,
         out_shape=[
-            jax.ShapeDtypeStruct(
-                (n_pad, out_size, out_size, channels), jnp.float32
-            )
+            jax.ShapeDtypeStruct((n_pad, out_size, out_lanes), jnp.float32)
         ]
         * n_views,
         interpret=_interpret(),
     )(*params, imgs)
-    return tuple(v[:n] for v in views)
+    return tuple(
+        v[:n].reshape(n, out_size, out_size, channels) for v in views
+    )
 
 
 def fused_two_views(

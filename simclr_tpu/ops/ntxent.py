@@ -29,7 +29,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from simclr_tpu.parallel.mesh import axis_size
 
 _NEG_INF = -1e9  # additive mask; safe in float32 logsumexp
 
@@ -108,7 +107,7 @@ def gather_global_candidates(
     """
     n_local = z0.shape[0]
     shard = jax.lax.axis_index(axis_name)
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     n_global = n_local * n_shards
 
     z_local = _l2_normalize(jnp.concatenate([z0, z1], axis=0))  # (2n_local, d)

@@ -28,8 +28,8 @@ Structure:
   * both dims are padded to hardware-aligned tiles; padded candidates are
     masked to -inf, padded anchors are neutralized by zero cotangents.
 
-Runs compiled on TPU; everywhere else (CPU tests) falls back to
-``interpret=True`` automatically.
+Compiled by Mosaic on TPU. ``interpret=True`` is used on the CPU backend
+only, which is how the CPU tests run the kernels.
 """
 
 from __future__ import annotations
@@ -47,7 +47,9 @@ _NEG_INF = -1e9
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # the Pallas interpreter is for the CPU backend only (tests); every other
+    # backend compiles the kernel or fails
+    return jax.default_backend() == "cpu"
 
 
 def _vmem(shape):

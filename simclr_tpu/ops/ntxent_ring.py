@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from simclr_tpu.ops.ntxent import _l2_normalize
-from simclr_tpu.parallel.mesh import axis_size
 
 _NEG_INF = -1e9
 
@@ -46,7 +45,7 @@ def ntxent_loss_ring(
     ``ntxent_loss_sharded_rows`` up to float associativity.
     """
     n_local = z0.shape[0]
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     anchors = _l2_normalize(jnp.concatenate([z0, z1], axis=0))  # (2B, d)
     two_b = 2 * n_local
 

@@ -89,7 +89,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from simclr_tpu.parallel.mesh import axis_size
 
 GRAD_ALLREDUCE_MODES = ("exact", "bf16", "int8")
 
@@ -383,7 +382,7 @@ def _int8_allreduce(
     Returns the fp32 vector of the same length; see the module docstring for
     the scatter / local-accumulate / gather decomposition.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     n_elements = flat.shape[0]
     n_buckets = -(-n_elements // bucket_size)
     n_buckets = -(-n_buckets // n) * n
@@ -438,7 +437,7 @@ def _ring_chunk_allreduce(
     bitwise identical across the axis. Returns the fp32 chunk of the input
     length.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return flat
     n_elements = flat.shape[0]

@@ -75,38 +75,6 @@ def enable_async_collective_flags(env=None, *, platform: str | None = None) -> b
     return True
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; older releases only
-    have ``jax.experimental.shard_map.shard_map(..., check_rep=)`` (same
-    semantics, earlier name). Every shard_map in this package goes through
-    this one wrapper so the version split lives in exactly one place.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` across JAX versions.
-
-    Older releases lack it; there a ``psum`` of the literal 1 constant-folds
-    to the same static Python int, so shapes derived from it stay static.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:
     """Logical mesh shape; -1 axes absorb the remaining devices."""
@@ -139,19 +107,20 @@ def create_mesh(
 ) -> Mesh:
     """Build a 2-D (data, model) mesh over the given (default: all) devices.
 
-    ``mesh_utils.create_device_mesh`` orders devices so that neighboring mesh
-    coordinates are ICI neighbors on TPU (ring-friendly collectives); on CPU
-    test backends it degrades to a plain reshape.
+    On TPU ``mesh_utils.create_device_mesh`` orders devices so that
+    neighboring mesh coordinates are ICI neighbors (ring-friendly
+    collectives), and a shape it cannot lay out is an error. CPU devices
+    have no topology: the virtual test mesh is a plain reshape.
     """
     spec = spec or MeshSpec()
     devices = list(devices if devices is not None else jax.devices())
     data, model = spec.resolve(len(devices))
-    try:
+    if devices[0].platform == "cpu":
+        device_grid = np.asarray(devices).reshape(data, model)
+    else:
         device_grid = mesh_utils.create_device_mesh(
             (data, model), devices=np.asarray(devices)
         )
-    except (ValueError, AssertionError):
-        device_grid = np.asarray(devices).reshape(data, model)
     return Mesh(device_grid, (DATA_AXIS, MODEL_AXIS))
 
 

@@ -103,12 +103,17 @@ def maybe_initialize_multihost() -> bool:
     # TPU-slice metadata only counts when we are actually running on TPU —
     # a CPU-forced dev run on a TPU host must not try to rendezvous.
     # JAX_PLATFORMS is a priority list; its FIRST entry is the default
-    # backend, so 'cpu' or 'cpu,tpu' both mean a CPU run.
+    # backend, so 'cpu' or 'cpu,tpu' both mean a CPU run. A slice of one
+    # host (TPU_WORKER_HOSTNAMES names a single worker) has nobody to
+    # rendezvous with: its process already sees every chip.
     platforms = os.environ.get("JAX_PLATFORMS", "").strip().lower()
     cpu_forced = platforms.split(",")[0].strip() == "cpu"
+    workers = [
+        h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+        if h.strip()
+    ]
     on_tpu_slice = (
-        os.environ.get("TPU_WORKER_HOSTNAMES")
-        or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS")
+        len(workers) > 1 or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS")
     ) and not cpu_forced
     if not env_configured and not on_tpu_slice:
         return False
@@ -151,17 +156,6 @@ def maybe_initialize_multihost() -> bool:
                     "JAX_COORDINATOR_TIMEOUT_S must be a number of seconds, "
                     f"got {timeout_s!r}"
                 ) from None
-    if cpu_forced:
-        # multi-process on the CPU backend (the pod dryrun / 2-process CPU
-        # e2e) needs a cross-process collectives impl, or every collective
-        # dies with "Multiprocess computations aren't implemented on the CPU
-        # backend". Must happen before the CPU client is created; keep any
-        # explicit non-default user choice (e.g. mpi).
-        try:
-            if jax.config.read("jax_cpu_collectives_implementation") == "none":
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, LookupError):
-            pass  # flag renamed/removed in a future jax; rendezvous still works
     try:
         jax.distributed.initialize(**kwargs)
         _initialized = True

@@ -55,7 +55,7 @@ from simclr_tpu.ops.ntxent_pallas import (
 )
 from simclr_tpu.ops.ntxent_ring import ntxent_loss_ring
 from simclr_tpu.parallel import compress
-from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, axis_size, shard_map
+from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from simclr_tpu.parallel.train_state import TrainState
 
 Metrics = dict[str, jnp.ndarray]
@@ -259,7 +259,7 @@ def _global_sample_keys(rng, n_local: int, views: int = 2):
     keys first — matching the ``split(rng, views * n)`` consumption layout.
     Must run inside the data-axis ``shard_map``.
     """
-    n_global = n_local * axis_size(DATA_AXIS)
+    n_global = n_local * jax.lax.axis_size(DATA_AXIS)
     rows = jax.lax.axis_index(DATA_AXIS) * n_local + jnp.arange(
         n_local, dtype=jnp.int32
     )
@@ -482,7 +482,7 @@ def make_pretrain_step(
         comm_overlap=comm_overlap, comm_chunks=comm_chunks,
         augment_impl=augment_impl,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(_REP, _BATCH, _REP),
@@ -610,7 +610,7 @@ def _make_epoch_fn(per_step, mesh, *, n_arrays: int, residency: str = "replicate
         arrays = rest[:n_arrays]
         idx_epoch, base_key, step0 = rest[n_arrays:]
         shard = jax.lax.axis_index(DATA_AXIS)
-        n_local = idx_epoch.shape[1] // axis_size(DATA_AXIS)
+        n_local = idx_epoch.shape[1] // jax.lax.axis_size(DATA_AXIS)
 
         def body(state, xs):
             idx_step, i = xs
@@ -638,7 +638,7 @@ def _make_epoch_fn(per_step, mesh, *, n_arrays: int, residency: str = "replicate
         )
 
     array_spec = _REP if residency == "replicated" else _BATCH
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_epoch,
         mesh=mesh,
         in_specs=(_REP,) + (array_spec,) * n_arrays + (_REP,) * 3,
@@ -659,7 +659,7 @@ def _local_resident_block(a, residency: str):
     trace time, not silently)."""
     if residency == "sharded":
         return a
-    n_shards = axis_size(DATA_AXIS)
+    n_shards = jax.lax.axis_size(DATA_AXIS)
     if a.shape[0] % n_shards:
         raise ValueError(
             f"replicated split of {a.shape[0]} rows does not divide over "
@@ -720,7 +720,7 @@ def _make_superepoch_fn(
             idx_super, base_key, step0 = rest[n_arrays:]
         shard = jax.lax.axis_index(DATA_AXIS)
         steps = idx_super.shape[1]
-        n_local = idx_super.shape[2] // axis_size(DATA_AXIS)
+        n_local = idx_super.shape[2] // jax.lax.axis_size(DATA_AXIS)
 
         def step_body(state, xs):
             idx_step, i = xs
@@ -783,7 +783,7 @@ def _make_superepoch_fn(
     array_spec = _REP if residency == "replicated" else _BATCH
     probe_specs = (_REP, array_spec, _REP) if monitor is not None else ()
     n_tail = 4 if monitor is not None else 3  # idx, [mask,] key, step0
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_super,
         mesh=mesh,
         in_specs=(_REP,) + (array_spec,) * n_arrays + probe_specs
@@ -933,7 +933,7 @@ def make_supervised_step(
         comm_overlap=comm_overlap, comm_chunks=comm_chunks,
         augment_impl=augment_impl,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(_REP, _BATCH, _BATCH, _REP),
@@ -1008,7 +1008,7 @@ def make_supervised_eval_step(model, mesh) -> Callable[..., Metrics]:
         count = jax.lax.psum(valid.sum(), DATA_AXIS)
         return {"sum_loss": sum_loss, "correct": correct, "count": count}
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(_REP, _REP, _BATCH, _BATCH, _BATCH),

@@ -58,7 +58,7 @@ from simclr_tpu.ops.ntxent_pallas import (
 )
 from simclr_tpu.ops.ntxent_ring import ntxent_loss_ring
 from simclr_tpu.parallel import compress
-from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, axis_size, shard_map
+from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from simclr_tpu.parallel.steps import (
     RESIDENCIES,
     _augment_two_views,
@@ -234,7 +234,7 @@ def _make_step_body(
     def step(state: TrainState, images: jax.Array, rng: jax.Array):
         p_specs = tree_pspecs(state.params)
         s_specs = tree_pspecs(state.batch_stats)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_fwd_bwd,
             mesh=mesh,
             in_specs=(p_specs, s_specs, P(DATA_AXIS), P()),
@@ -345,10 +345,10 @@ def make_pretrain_epoch_fn_tp(
     def _local_batch_from_shards(local_rows, idx_step):
         full = _sharded_rows_global_batch(local_rows, idx_step)
         shard = jax.lax.axis_index(DATA_AXIS)
-        n_local = idx_step.shape[0] // axis_size(DATA_AXIS)
+        n_local = idx_step.shape[0] // jax.lax.axis_size(DATA_AXIS)
         return jax.lax.dynamic_slice_in_dim(full, shard * n_local, n_local)
 
-    gather_sharded = shard_map(
+    gather_sharded = jax.shard_map(
         _local_batch_from_shards,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P()),
@@ -426,10 +426,10 @@ def make_pretrain_superepoch_fn_tp(
     def _local_batch_from_shards(local_rows, idx_step):
         full = _sharded_rows_global_batch(local_rows, idx_step)
         shard = jax.lax.axis_index(DATA_AXIS)
-        n_local = idx_step.shape[0] // axis_size(DATA_AXIS)
+        n_local = idx_step.shape[0] // jax.lax.axis_size(DATA_AXIS)
         return jax.lax.dynamic_slice_in_dim(full, shard * n_local, n_local)
 
-    gather_sharded = shard_map(
+    gather_sharded = jax.shard_map(
         _local_batch_from_shards,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P()),
@@ -445,7 +445,7 @@ def make_pretrain_superepoch_fn_tp(
                 _local_resident_block(te_rows, residency), te_labels,
             )
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(

@@ -177,9 +177,9 @@ def run_save_features(cfg: Config) -> list[str]:
 
 def main(argv: list[str] | None = None):
     from simclr_tpu.parallel.multihost import maybe_initialize_multihost
-    from simclr_tpu.utils.platform import ensure_platform
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
-    ensure_platform()
+    configure_compilation_cache()
     maybe_initialize_multihost()
     from simclr_tpu.config import run_multirun, split_multirun_flag
 

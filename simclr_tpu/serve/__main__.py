@@ -13,9 +13,9 @@ import sys
 
 
 def main(argv: list[str] | None = None) -> int:
-    from simclr_tpu.utils.platform import ensure_platform
+    from simclr_tpu.utils.platform import configure_compilation_cache
 
-    ensure_platform()
+    configure_compilation_cache()
     from simclr_tpu.config import load_config
     from simclr_tpu.serve.server import run_server
 

@@ -12,9 +12,8 @@ shapes:
     (1, 2, 4, … ``max_batch``) and sliced back after the forward, so XLA
     compiles one program per bucket instead of one per request size;
   * every bucket is **warmup-compiled at startup** (fenced with
-    ``utils.profiling.synchronize`` — a value fetch, the only reliable
-    completion fence on remote-tunneled runtimes), so no live request ever
-    pays a compile;
+    ``utils.profiling.synchronize``), so no live request ever pays a
+    compile;
   * each engine is single-device BY PLACEMENT: request batches are
     latency-bound and small, so sharding one forward buys nothing — the
     ``device`` argument pins the committed weight copy (and therefore every

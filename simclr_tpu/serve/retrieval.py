@@ -72,7 +72,6 @@ from simclr_tpu.parallel.mesh import (
     DATA_AXIS,
     batch_sharding,
     retrieval_mesh,
-    shard_map,
 )
 from simclr_tpu.serve.engine import make_buckets
 from simclr_tpu.utils.fetch import fetch
@@ -473,7 +472,7 @@ class NeighborIndex:
                 n_operands = 4
 
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 local_merge,
                 mesh=self.mesh,
                 in_specs=(P(),) + (P(DATA_AXIS),) * n_operands,

@@ -25,8 +25,9 @@ Endpoints:
     404 when the corpus is not mutable (no store), 400 bad ids/shapes,
     503 draining.
   * ``GET /healthz`` — 200 once warm and accepting (with per-replica
-    state under ``"replicas"`` and corpus residency under ``"neighbors"``),
-    503 while draining.
+    state under ``"replicas"``, corpus residency under ``"neighbors"``, and
+    the platform, device kind and device count under ``"device"``), 503
+    while draining.
   * ``GET /metrics`` — Prometheus text format (``serve/metrics.py``).
   * ``GET /debug/slow`` — the slowest recent requests with their span
     breakdowns (``obs/trace.py`` ring buffer).
@@ -63,6 +64,7 @@ import numpy as np
 from simclr_tpu.obs.trace import RequestTrace, TraceRecorder, clean_request_id
 from simclr_tpu.serve.batcher import BackpressureError, BatcherClosedError
 from simclr_tpu.utils.logging import get_logger
+from simclr_tpu.utils.platform import compilation_cache_stats, device_identity
 
 logger = get_logger()
 
@@ -167,6 +169,8 @@ class EmbedHandler(BaseHTTPRequestHandler):
                     "checkpoint": getattr(
                         self.server.engine, "checkpoint_path", None
                     ),
+                    "device": device_identity(),
+                    "compile_cache": compilation_cache_stats(),
                 }
                 if self.server.pool is not None:
                     payload["replicas"] = self.server.pool.state()

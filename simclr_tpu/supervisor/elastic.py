@@ -650,6 +650,7 @@ def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m simclr_tpu.supervisor.elastic",
         description="Per-host elastic supervisor: remesh-on-loss + grow-back.",
+        epilog="On hardware a chip belongs to one process: run ONE process per host. All of this command's children start on this machine and nothing partitions its chips between them, so without --force-cpu they would each claim every chip; it has only ever run as a CPU dry-run.",
     )
     parser.add_argument(
         "--nprocs", type=int, required=True,

@@ -201,12 +201,13 @@ def restore_checkpoint(path: str, target=None, *, verify: bool = True):
         # checkpoint written on an 8-device mesh refuses to load in a
         # single-device process (train on a pod, serve/eval on one chip).
         with ocp.PyTreeCheckpointer() as ckptr:
-            meta = ckptr.metadata(path)
-            tree = getattr(meta, "tree", None) or meta
+            tree = ckptr.metadata(path).item_metadata.tree
             restore_args = jax.tree.map(
                 lambda _: ocp.RestoreArgs(restore_type=np.ndarray), tree
             )
-            return ckptr.restore(path, restore_args=restore_args)
+            return ckptr.restore(
+                path, args=ocp.args.PyTreeRestore(restore_args=restore_args)
+            )
     with ocp.StandardCheckpointer() as ckptr:
         return ckptr.restore(path, target)
 

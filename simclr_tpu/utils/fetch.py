@@ -19,8 +19,8 @@ def fetch(x: jax.Array) -> np.ndarray:
     Under multi-host SPMD a sharded output spans chips this process cannot
     address; ``process_allgather`` assembles the full array on every host
     (the arrays fetched here are small: N x 512 floats). Single-process,
-    this is a plain ``np.asarray`` value fetch — which doubles as a true
-    completion fence (see ``utils.profiling.synchronize``).
+    this is a plain ``np.asarray`` value fetch, which also waits for the
+    computation.
     """
     if jax.process_count() > 1:
         from jax.experimental import multihost_utils

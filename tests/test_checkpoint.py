@@ -147,12 +147,13 @@ class TestCrossTopologyRestore:
         code = textwrap.dedent(
             f"""
             import jax
-            jax.config.update("jax_platforms", "cpu")
             import numpy as np
             from simclr_tpu.utils.checkpoint import restore_checkpoint
             assert jax.device_count() == 1, jax.device_count()
             raw = restore_checkpoint({path!r})
-            kernel = np.asarray(raw["params"]["dense"]["kernel"])
+            assert all(type(l) is np.ndarray for l in jax.tree.leaves(raw))
+            assert int(raw["step"]) == 3
+            kernel = raw["params"]["dense"]["kernel"]
             np.testing.assert_array_equal(
                 kernel, np.arange(16, dtype=np.float32).reshape(8, 2)
             )

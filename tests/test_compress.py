@@ -36,7 +36,6 @@ from simclr_tpu.parallel.mesh import (
     MeshSpec,
     batch_sharding,
     create_mesh,
-    shard_map,
 )
 from simclr_tpu.parallel.steps import (
     make_pretrain_epoch_fn,
@@ -57,7 +56,7 @@ def _allreduce_on_mesh(tree, mode, *, bucket_size=DEFAULT_BUCKET_SIZE, seed=0,
     mesh = create_mesh()
     tree = jax.tree.map(jnp.asarray, tree)
 
-    def f(_):
+    def f(_, tree):
         i = jax.lax.axis_index(DATA_AXIS)
         local = jax.tree.map(lambda l: l + 0.01 * i.astype(l.dtype), tree)
         key = jax.random.fold_in(jax.random.key(seed), i)
@@ -68,10 +67,13 @@ def _allreduce_on_mesh(tree, mode, *, bucket_size=DEFAULT_BUCKET_SIZE, seed=0,
         exact = jax.lax.psum(local, DATA_AXIS)
         return jax.tree.map(lambda x: x[None], (out, exact))
 
-    got, exact = shard_map(
-        f, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS),
+    # the tree is an explicit replicated argument under jit: an eager
+    # shard_map closing over committed arrays trips jax's "Unexpected XLA
+    # sharding override" check
+    got, exact = jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(P(DATA_AXIS), P()), out_specs=P(DATA_AXIS),
         check_vma=False,
-    )(jnp.zeros((N_DEV,)))
+    ))(jnp.zeros((N_DEV,)), tree)
     return jax.device_get(got), jax.device_get(exact)
 
 

@@ -19,7 +19,7 @@ The unit/chaos half of the continuous train+serve subsystem:
     config-error exit code.
 
 The full-lifecycle e2e (2-process CPU dryrun with one shrink/grow-back
-cycle) lives in scripts/cosched_smoke.py, staged by scripts/tpu_watch.sh.
+cycle) lives in scripts/cosched_smoke.py.
 """
 
 import json

@@ -926,18 +926,19 @@ class TestLayoutInvariantAugmentKeys:
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, shard_map
+        from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
         from simclr_tpu.parallel.steps import _global_sample_keys
 
         devices = np.array(jax.devices()[:n_shards]).reshape(n_shards, 1)
         mesh = Mesh(devices, (DATA_AXIS, MODEL_AXIS))
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda rng: jax.random.key_data(
                 _global_sample_keys(rng, n_local, views=views)
             ).reshape(views, n_local, -1),
             mesh=mesh,
             in_specs=P(),
             out_specs=P(None, DATA_AXIS),
+            check_vma=False,
         )
         with mesh:
             return np.asarray(jax.jit(fn)(jax.random.key(42)))

@@ -25,7 +25,6 @@ from simclr_tpu.parallel.mesh import (
     put_row_sharded,
     put_tree,
     replicated_sharding,
-    shard_map,
 )
 from simclr_tpu.parallel.steps import (
     _sharded_rows_global_batch,
@@ -230,7 +229,7 @@ def test_epoch_compile_hbm_preconditions():
 
 def _gather_fn(mesh):
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             _sharded_rows_global_batch,
             mesh=mesh,
             in_specs=(P(DATA_AXIS), P()),

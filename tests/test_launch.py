@@ -472,8 +472,7 @@ def test_two_process_chunked_overlap_pretrain(tmp_path):
 def test_multihost_dryrun_script_two_process_parity(tmp_path):
     """scripts/multihost_dryrun.py end to end: one payload line claiming a
     REAL 2-process rendezvous whose chunked-ring checksum bitwise-matches
-    the single-process reference — the claim the tpu_watch stage's done
-    marker greps for."""
+    the single-process reference."""
     import json
 
     env = _launcher_env()

@@ -41,6 +41,93 @@ class TestNativeGather:
         assert gather_rows(src, np.array([], dtype=np.int64)).shape == (0, 4)
 
 
+@pytest.fixture
+def fresh_build_dir(tmp_path, monkeypatch):
+    """Point the loader at an empty build directory and forget what this
+    process already loaded."""
+    from simclr_tpu.native import lib
+
+    so = str(tmp_path / "_build" / "libsimclr_gather.so")
+    monkeypatch.setattr(lib, "_BUILD_DIR", str(tmp_path / "_build"))
+    monkeypatch.setattr(lib, "_SO", so)
+    monkeypatch.setattr(lib, "_SO_SOURCE_HASH", so + ".source-sha256")
+    monkeypatch.setattr(lib, "_tried", False)
+    monkeypatch.setattr(lib, "_lib", None)
+    return lib
+
+
+class TestNativeBuildProvenance:
+    """Only tracked source decides what runs (``gather.cpp``'s sha256 is
+    recorded next to the library it was built from)."""
+
+    def test_build_records_the_source_hash(self, fresh_build_dir):
+        lib = fresh_build_dir
+        assert lib.native_available()
+        with open(lib._SO_SOURCE_HASH) as f:
+            assert f.read().strip() == lib._source_hash()
+
+    @pytest.mark.parametrize("record", [None, "0" * 64])
+    def test_stale_library_is_rebuilt_not_loaded(self, fresh_build_dir, record):
+        """A ``.so`` with no record, or with the record of another source,
+        is replaced. The planted file is not even a library: loading it as
+        it is would fail."""
+        lib = fresh_build_dir
+        import os
+
+        os.makedirs(lib._BUILD_DIR)
+        with open(lib._SO, "wb") as f:
+            f.write(b"left over from another checkout")
+        if record is not None:
+            with open(lib._SO_SOURCE_HASH, "w") as f:
+                f.write(record + "\n")
+        assert lib.native_available()
+        src = np.arange(12, dtype=np.uint8).reshape(4, 3)
+        np.testing.assert_array_equal(
+            lib.gather_rows(src, np.array([2, 0])), src[[2, 0]]
+        )
+        with open(lib._SO_SOURCE_HASH) as f:
+            assert f.read().strip() == lib._source_hash()
+
+    def test_matching_record_skips_the_compiler(self, fresh_build_dir, monkeypatch):
+        lib = fresh_build_dir
+        assert lib.native_available()
+        monkeypatch.setattr(lib, "_tried", False)
+        monkeypatch.setattr(lib, "_lib", None)
+
+        def no_compiler(*args, **kwargs):
+            raise AssertionError("rebuilt a library whose source did not change")
+
+        monkeypatch.setattr(lib.subprocess, "run", no_compiler)
+        assert lib.native_available()
+
+    def test_failed_build_warns_once_and_uses_numpy(self, fresh_build_dir, monkeypatch):
+        import logging
+
+        lib = fresh_build_dir
+
+        def no_compiler(*args, **kwargs):
+            raise FileNotFoundError("g++")
+
+        monkeypatch.setattr(lib.subprocess, "run", no_compiler)
+        records = []
+        handler = logging.Handler()
+        handler.emit = records.append
+        logger = logging.getLogger("simclr_tpu")
+        logger.addHandler(handler)
+        try:
+            assert not lib.native_available()
+            src = np.arange(12, dtype=np.uint8).reshape(4, 3)
+            np.testing.assert_array_equal(
+                lib.gather_rows(src, np.array([3, 1])), src[[3, 1]]
+            )
+            assert not lib.native_available()
+        finally:
+            logger.removeHandler(handler)
+        warnings = [r for r in records if r.levelno == logging.WARNING]
+        assert len(warnings) == 1
+        assert "NumPy path" in warnings[0].getMessage()
+
+
 class TestPrefetcher:
     def test_yields_all_in_order(self):
         items = list(prefetch(iter(range(10))))

@@ -189,10 +189,10 @@ class TestFusedSharded:
     def _sharded(self, loss_fn):
         from jax.sharding import PartitionSpec as P
 
-        from simclr_tpu.parallel.mesh import DATA_AXIS, create_mesh, shard_map
+        from simclr_tpu.parallel.mesh import DATA_AXIS, create_mesh
 
         mesh = create_mesh()
-        f = shard_map(
+        f = jax.shard_map(
             lambda a, b: loss_fn(a, b, DATA_AXIS, 0.5),
             mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)), out_specs=P(),
             check_vma=False,

@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 
 from simclr_tpu.ops.ntxent import ntxent_loss, ntxent_loss_sharded_rows
 from simclr_tpu.ops.ntxent_ring import ntxent_loss_ring
-from simclr_tpu.parallel.mesh import DATA_AXIS, create_mesh, shard_map
+from simclr_tpu.parallel.mesh import DATA_AXIS, create_mesh
 
 
 def _views(n=32, d=16, seed=0):
@@ -22,7 +22,7 @@ def _views(n=32, d=16, seed=0):
 
 def _sharded_loss(loss_fn, z0, z1, temperature=0.5):
     mesh = create_mesh()
-    f = shard_map(
+    f = jax.shard_map(
         lambda a, b: loss_fn(a, b, DATA_AXIS, temperature),
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
@@ -63,7 +63,7 @@ class TestRingGradients:
         def local(a, b):
             return loss_fn(a, b, DATA_AXIS, 0.5)
 
-        f = shard_map(
+        f = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS)), out_specs=P(),
             check_vma=False,

@@ -238,6 +238,46 @@ class TestHistogram:
 
 
 # ---------------------------------------------------------------------------
+# Chip peak table
+# ---------------------------------------------------------------------------
+
+
+class TestChipPeaks:
+    def test_v5e_row_and_source(self):
+        from simclr_tpu.obs.peaks import chip_peak
+
+        peak = chip_peak("tpu", "TPU v5 lite")
+        assert peak.bf16_flops_per_s == 197e12
+        assert peak.hbm_bytes_per_s == 819e9
+        assert "TPU v5e" in peak.source
+
+    def test_cpu_has_no_peak(self):
+        from simclr_tpu.obs.peaks import chip_peak
+
+        assert chip_peak("cpu", "cpu") is None
+
+    def test_unknown_accelerator_is_an_error(self):
+        from simclr_tpu.obs.peaks import chip_peak
+
+        with pytest.raises(KeyError, match="TPU v9"):
+            chip_peak("tpu", "TPU v9")
+
+    def test_scripts_read_the_same_table(self):
+        """roofline_model.py models the v5e from the table's row, not from a
+        constant of its own."""
+        import importlib.util
+
+        from simclr_tpu.obs.peaks import CHIP_PEAKS, V5E
+
+        spec = importlib.util.spec_from_file_location(
+            "roofline", os.path.join(REPO_ROOT, "scripts", "roofline_model.py")
+        )
+        roofline = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(roofline)
+        assert roofline.PEAK is CHIP_PEAKS[V5E]
+
+
+# ---------------------------------------------------------------------------
 # Telemetry registry
 # ---------------------------------------------------------------------------
 
@@ -246,8 +286,11 @@ class TestTelemetry:
     def _make(self, **kw):
         from simclr_tpu.obs.telemetry import Telemetry
 
+        from simclr_tpu.obs.peaks import CHIP_PEAKS, V5E
+
         base = dict(
-            arch="resnet18", per_device_batch=8, global_batch=64, n_devices=8
+            arch="resnet18", per_device_batch=8, global_batch=64, n_devices=8,
+            peak_flops=CHIP_PEAKS[V5E].bf16_flops_per_s,
         )
         base.update(kw)
         return Telemetry(**base)
@@ -284,6 +327,15 @@ class TestTelemetry:
         t.observe_epoch(1, epochs=2, step=2, steps=2, seconds=1.0, loss=1.0, lr=0.1)
         assert t.mfu.value == 0.0
         assert t.imgs_per_sec.value > 0  # throughput still reported
+
+    def test_no_peak_means_no_mfu(self):
+        """The CPU platform has no row in the peak table (chip_peak -> None):
+        throughput is reported, utilization is not."""
+        t = self._make(peak_flops=None)
+        assert t.flops_per_step
+        t.observe_epoch(1, epochs=2, step=2, steps=2, seconds=1.0, loss=1.0, lr=0.1)
+        assert t.mfu.value == 0.0 and t.exposed_comm_ms.value == 0.0
+        assert t.imgs_per_sec.value > 0
 
     def test_unknown_arch_degrades_to_none(self):
         assert self._make(arch="not-a-model").flops_per_step is None
@@ -781,7 +833,7 @@ class TestRunReport:
         assert rc == 0
         out = capsys.readouterr().out
         assert "stalled attempts: 1" in out
-        # the verdict is the LAST line and greppable (tpu_watch contract)
+        # the verdict is the LAST line and greppable
         assert out.strip().splitlines()[-1].startswith("run_report verdict: OK")
         assert json.load(open(out_json))["verdict"] == "OK"
 

@@ -218,16 +218,16 @@ class TestCompileSentry:
         assert args_signature((x, 3)) != args_signature((jnp.ones((5,)), 3))
         assert args_signature((x, 3)) != args_signature((x, 3.0))
 
-    def test_executable_cost_is_best_effort(self):
+    def test_executable_cost_reads_one_dict(self):
         compiled = jax.jit(_double).lower(jnp.ones((16, 16))).compile()
         flops, bytes_accessed = executable_cost(compiled)
-        assert flops >= 0.0 and bytes_accessed >= 0.0
+        assert flops >= 0.0 and bytes_accessed > 0.0
 
-        class _NoCost:
+        class _PartialCost:
             def cost_analysis(self):
-                raise NotImplementedError
+                return {"flops": 7.0}
 
-        assert executable_cost(_NoCost()) == (0.0, 0.0)
+        assert executable_cost(_PartialCost()) == (7.0, 0.0)
 
     def test_watch_counts_compiles_and_caches(self, tmp_path):
         telemetry = _make_telemetry()
@@ -247,6 +247,29 @@ class TestCompileSentry:
         assert compile_events[0]["recompile"] is False
         assert compile_events[0]["fingerprint"]
         assert compile_events[0]["seconds"] > 0
+
+    def test_compile_event_says_when_the_persistent_cache_answered(
+        self, tmp_path, monkeypatch
+    ):
+        """``seconds`` of a cached step is lowering plus a load: the event
+        must say which kind it is (``chip_smoke.py`` reads this on resume)."""
+        from simclr_tpu.utils import platform
+
+        hits = iter([0, 0, 4, 5])  # before/after: a miss, then a hit
+        monkeypatch.setattr(
+            platform, "compilation_cache_stats",
+            lambda: {"dir": None, "hits": next(hits), "misses": 0},
+        )
+        events = EventLog(str(tmp_path))
+        sentry = CompileSentry(events=events)
+        step = sentry.watch(jax.jit(_double), "step")
+        step(jnp.ones((4,)))
+        step(jnp.ones((8,)))
+        compile_events = [e for e in read_events(events_path(str(tmp_path)))
+                          if e["event"] == "compile"]
+        assert [e["cache_hit"] for e in compile_events] == [False, True]
+        # a caller that cannot tell (serve bucket warm-up) says nothing
+        assert "cache_hit" not in sentry.record_compile("bucket", seconds=0.1)
 
     def test_recompile_alarm_on_shape_change(self, tmp_path):
         """The tentpole scenario: a step function recompiling after warmup
@@ -282,8 +305,39 @@ class TestCompileSentry:
         assert sentry.compiles == 1
         assert sentry.recompile_alarms == 0
 
+    @pytest.mark.parametrize("stage", ["lower", "compile"])
+    def test_watch_reraises_lowering_and_compile_errors(self, stage):
+        """A kernel the backend refuses must stop the run, not be retried
+        through plain dispatch with the failure swallowed."""
+
+        class _Lowered:
+            def as_text(self):
+                return "module"
+
+            def compile(self):
+                raise RuntimeError("Mosaic refused the kernel")
+
+        class _Jitted:
+            calls = 0
+
+            def lower(self, *args):
+                if stage == "lower":
+                    raise NotImplementedError("Unimplemented primitive: rev")
+                return _Lowered()
+
+            def __call__(self, *args):
+                self.calls += 1
+
+        fn = _Jitted()
+        sentry = CompileSentry()
+        step = sentry.watch(fn, "step")
+        with pytest.raises((NotImplementedError, RuntimeError)):
+            step(jnp.ones((4,)))
+        assert fn.calls == 0
+        assert sentry.compiles == 0
+
     def test_watch_degrades_without_aot(self):
-        """A callable with no ``lower`` (epoch wrappers, exotic backends)
+        """A callable with no ``lower`` at all (a plain Python wrapper)
         still dispatches and still books its compiles."""
         sentry = CompileSentry()
         step = sentry.watch(lambda x: x * 2.0, "plain")

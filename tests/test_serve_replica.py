@@ -260,8 +260,8 @@ class TestObservability:
 
 @pytest.mark.slow
 class TestAggregateScaling:
-    """The acceptance number, measured by the bench the tpu_watch
-    serve_scale stage runs: N synthetic replicas behind the REAL pool +
+    """The acceptance number, measured by scripts/serve_bench.py: N
+    synthetic replicas behind the REAL pool +
     batcher + HTTP stack must at least double single-replica throughput."""
 
     def test_serve_bench_reports_2x_speedup_at_4_replicas(self):

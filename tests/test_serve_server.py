@@ -139,6 +139,18 @@ class TestEndpoints:
         assert payload["max_batch"] == MAX_BATCH
         assert payload["feature_dim"] == 16
 
+    def test_healthz_names_the_device(self, live):
+        """Whoever reads a number off this server can read where it ran."""
+        import jax
+
+        payload = json.loads(live.request("GET", "/healthz")[1])
+        assert payload["device"] == {
+            "platform": "cpu",
+            "kind": jax.devices()[0].device_kind,
+            "count": jax.device_count(),
+        }
+        assert set(payload["compile_cache"]) == {"dir", "hits", "misses"}
+
     def test_metrics_exposition_parses(self, live):
         live.embed(random_images(2))
         status, body, headers = live.request("GET", "/metrics")

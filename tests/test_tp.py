@@ -24,7 +24,6 @@ from simclr_tpu.parallel.mesh import (
     MeshSpec,
     batch_sharding,
     create_mesh,
-    shard_map,
 )
 from simclr_tpu.parallel.tp import (
     make_pretrain_epoch_fn_tp,
@@ -70,7 +69,7 @@ def test_sharded_head_forward_matches_unsharded():
     def fwd(p, s, x):
         return local.apply({"params": p, "batch_stats": s}, x, train=False)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fwd, mesh=mesh, in_specs=(p_specs, s_specs, P()), out_specs=P(),
         check_vma=False,
     )
@@ -328,7 +327,7 @@ def test_tp_output_psum_operand_is_f32():
     def fwd(p, s, x):
         return local.apply({"params": p, "batch_stats": s}, x, train=False)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fwd, mesh=mesh, in_specs=(p_specs, s_specs, P()), out_specs=P(),
         check_vma=False,
     )

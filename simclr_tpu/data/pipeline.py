@@ -25,6 +25,7 @@ import numpy as np
 
 from simclr_tpu.data.cifar import Dataset
 from simclr_tpu.native.lib import DEFAULT_THREADS, gather_rows2
+from simclr_tpu.obs.trace import recorder
 from simclr_tpu.parallel.mesh import put_global_batch
 
 
@@ -110,24 +111,34 @@ class EpochIterator:
         return np.arange(len(self.dataset))
 
     def batches(self, epoch: int) -> Iterator[dict[str, np.ndarray | jax.Array]]:
-        order = self._order(epoch)
+        # spans (obs/trace.py): this generator runs on the prefetch thread;
+        # `step` is the optimizer step a batch feeds, the number the host
+        # loop's `dispatch` span of the same batch carries
+        spans = recorder()
+        with spans.span("boundary/shuffle", epoch=epoch):
+            order = self._order(epoch)
         n_proc = jax.process_count()
         proc = jax.process_index()
         for step in range(self.steps_per_epoch):
+            ids = {"epoch": epoch, "step": (epoch - 1) * self.steps_per_epoch + step + 1}
             idx = order[step * self.global_batch : (step + 1) * self.global_batch]
             # each host materializes only its contiguous row block
             per_host = len(idx) // n_proc if n_proc > 1 else len(idx)
             local_idx = idx[proc * per_host : (proc + 1) * per_host]
             # native multithreaded row gather (numpy-take fallback inside)
-            images, labels = gather_rows2(
-                self.dataset.images, self.dataset.labels, local_idx,
-                n_threads=self.gather_threads,
-            )
+            with spans.span("gather", n=len(local_idx), **ids):
+                images, labels = gather_rows2(
+                    self.dataset.images, self.dataset.labels, local_idx,
+                    n_threads=self.gather_threads,
+                )
             batch = {"image": images, "label": labels}
             if self.sharding is not None:
-                batch = {
-                    k: self._to_device(v, k) for k, v in batch.items()
-                }
+                with spans.span(
+                    "device_put", n=images.nbytes + labels.nbytes, **ids
+                ):
+                    batch = {
+                        k: self._to_device(v, k) for k, v in batch.items()
+                    }
             yield batch
 
     def _to_device(self, array: np.ndarray, name: str) -> jax.Array:

@@ -23,6 +23,8 @@ import time
 from collections.abc import Iterator
 from typing import Any
 
+from simclr_tpu.obs.trace import recorder
+
 _SENTINEL = object()
 
 # bound on every internal blocking queue op: long enough to stay off the hot
@@ -73,13 +75,19 @@ class Prefetcher:
                 except queue.Full:
                     pass
 
-        self._thread = threading.Thread(target=worker, daemon=True)
+        self._thread = threading.Thread(target=worker, daemon=True, name="prefetch")
         self._thread.start()
 
     def __iter__(self):
         return self
 
     def __next__(self):
+        # the consumer blocked on the queue: how long the host loop waited
+        # for its input
+        with recorder().span("input_wait"):
+            return self._next()
+
+    def _next(self):
         while True:
             try:
                 item = self._q.get(timeout=_POLL_S)

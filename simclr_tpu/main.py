@@ -45,6 +45,7 @@ from simclr_tpu.obs.events import EventLog
 from simclr_tpu.obs.exporter import maybe_start_exporter
 from simclr_tpu.obs.peaks import chip_peak
 from simclr_tpu.obs.telemetry import Telemetry
+from simclr_tpu.obs.trace import recorder, spans_path
 from simclr_tpu.ops.lars import get_weight_decay_mask, lars
 from simclr_tpu.parallel.compress import DEFAULT_COMM_CHUNKS, normalize_overlap
 from simclr_tpu.parallel.mesh import (
@@ -113,6 +114,14 @@ def run_pretrain(cfg: Config) -> dict:
     """Train; returns a summary dict (final loss, steps, save_dir)."""
     check_pretrain_conf(cfg)
     seed = int(cfg.parameter.seed)
+    # host spans (obs/trace.py): always on, in memory until a flush. main()
+    # opened boot/runtime at its entry; a caller that came another way
+    # starts with an empty recorder and the span covers the backend start
+    spans = recorder()
+    boot_span = spans.current()
+    if boot_span is None or boot_span.name != "boot/runtime":
+        spans.reset()
+        boot_span = spans.begin("boot/runtime")
 
     comm_overlap = str(
         normalize_overlap(cfg.select("parallel.comm_overlap", "off"))
@@ -123,17 +132,20 @@ def run_pretrain(cfg: Config) -> dict:
         # backend; no-op off-TPU (parallel/mesh.py)
         enable_async_collective_flags()
     mesh = mesh_from_config(cfg)
+    spans.end(boot_span)
     n_data = mesh.shape[DATA_AXIS]
     global_batch = validate_per_device_batch(int(cfg.experiment.batches), mesh)
 
-    dataset = load_dataset(
-        cfg.experiment.name,
-        "train",
-        data_dir=cfg.select("experiment.data_dir"),
-        synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
-        synthetic_size=cfg.select("experiment.synthetic_size"),
-        synthetic_noise=cfg.select("experiment.synthetic_noise"),
-    )
+    with spans.span("boot/data"):
+        dataset = load_dataset(
+            cfg.experiment.name,
+            "train",
+            data_dir=cfg.select("experiment.data_dir"),
+            synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
+            synthetic_size=cfg.select("experiment.synthetic_size"),
+            synthetic_noise=cfg.select("experiment.synthetic_noise"),
+        )
+    boot_span = spans.begin("boot/state")  # optimizer, model, state: to run_start
 
     # Reference step accounting (drop_last truncation, main.py:76-80)
     steps_per_epoch = len(dataset) // global_batch
@@ -196,16 +208,12 @@ def run_pretrain(cfg: Config) -> dict:
     device = device_identity()
     peak = chip_peak(device["platform"], device["kind"])
     telemetry = Telemetry(
-        arch=str(cfg.experiment.base_cnn),
-        per_device_batch=int(cfg.experiment.batches),
         global_batch=global_batch,
         n_devices=jax.device_count(),
         mesh_hosts=n_hosts,
-        d=int(cfg.parameter.d),
         grad_allreduce=str(cfg.select("parallel.grad_allreduce", "exact")),
         grad_elements=param_count(state.params),
         allreduce_devices=n_data,
-        augment_impl=str(cfg.select("runtime.augment_impl", "xla")),
         comm_overlap=comm_overlap,
         comm_chunks=comm_chunks,
         peak_flops=peak.bf16_flops_per_s if peak else None,
@@ -239,11 +247,15 @@ def run_pretrain(cfg: Config) -> dict:
     sentry = maybe_sentry(
         cfg, telemetry=telemetry, events=events, detector=detector
     )
+    spans.end(boot_span)
     events.emit(
         "run_start", entry="pretrain", epochs=epochs,
         steps_per_epoch=steps_per_epoch, global_batch=global_batch,
         pid=os.getpid(),
     )
+    # resume, step builders, iterator, exporter, and the step's compilation
+    # (boot/compile): ended before the first dispatch
+    build_span = spans.begin("boot/build_step")
     start_epoch = 1
     skip_steps = 0
     # the PRIOR generation's topology record, read BEFORE this run
@@ -742,6 +754,51 @@ def run_pretrain(cfg: Config) -> dict:
     exporter = maybe_start_exporter(
         cfg, telemetry, save_dir, process_index=jax.process_index()
     )
+    spans_file = spans_path(save_dir, jax.process_index())
+    # the open `boundary` span: from the step loop's exit to the next
+    # epoch's first batch in hand
+    boundary_span = None
+
+    def flush_spans() -> None:
+        try:
+            spans.flush(spans_file, process_index=jax.process_index())
+        except OSError as e:  # a full disk must not change how the run ends
+            logger.warning("spans not written to %s: %s", spans_file, e)
+
+    def end_boundary() -> None:
+        nonlocal boundary_span
+        spans.end(boundary_span)
+        boundary_span = None
+
+    def timed_save(path: str) -> None:
+        t_save = time.perf_counter()
+        save_checkpoint(path, state)  # checkpoint/save, /digest, /sidecar
+        telemetry.observe_save(time.perf_counter() - t_save)
+
+    def first_dispatch_next(fn, *args) -> None:
+        """Ends boot/build_step before the run's first dispatch, the step's
+        compilation (the sentry's boot/compile) still inside it."""
+        nonlocal build_span
+        if build_span is None:
+            return
+        if hasattr(fn, "compile"):  # obs/compile.py WatchedFunction
+            fn.compile(*args)
+        spans.end(build_span)
+        build_span = None
+
+    def leave_preempted(path: str, at_epoch: int):
+        """The tail of a preemption, inside its ``preempt`` span: event,
+        last beat, the recorder's flush (inside ``preempt_s``, so timed)."""
+        with spans.span("preempt/event"):
+            events.emit(
+                "preempt", step=cur_step, epoch=at_epoch, checkpoint=path
+            )
+        with spans.span("preempt/beat"):
+            guard.beat_preempted(cur_step, at_epoch)
+        with spans.span("preempt/flush"):
+            flush_spans()
+        raise PreemptedRun(path)
+
     guard.install_signals()
     try:
         epoch = start_epoch
@@ -761,30 +818,37 @@ def run_pretrain(cfg: Config) -> dict:
                 K = epochs_per_compile
                 chunk = list(range(epoch, epoch + K))
                 boundary = chunk[-1]
-                idx_super = jnp.asarray(
-                    np.stack([
-                        epoch_index_matrix(
-                            len(dataset), seed, e, steps_per_epoch, global_batch
-                        )
-                        for e in chunk
-                    ])
-                )
+                with spans.span("boundary/shuffle", epoch=epoch):
+                    idx_super = jnp.asarray(
+                        np.stack([
+                            epoch_index_matrix(
+                                len(dataset), seed, e, steps_per_epoch, global_batch
+                            )
+                            for e in chunk
+                        ])
+                    )
+                probed = [False] * K
+                args = (state, images_all, idx_super, base_key, cur_step)
                 if probe_local is not None:
                     probed = [e % eval_every == 0 or e == epochs for e in chunk]
-                    state, hist = superepoch_fn(
+                    args = (
                         state, images_all, *probe_arrays,
                         idx_super, jnp.asarray(probed), base_key, cur_step,
                     )
-                else:
-                    probed = [False] * K
-                    state, hist = superepoch_fn(
-                        state, images_all, idx_super, base_key, cur_step
-                    )
-                metrics = {"loss": hist["loss"][-1, -1]}
-                timer.tick(hist["loss"])
+                end_boundary()
+                first_dispatch_next(superepoch_fn, *args)
+                with spans.span("epoch", epoch=boundary, step=cur_step + 1):
+                    with spans.span("dispatch"):
+                        state, hist = superepoch_fn(*args)
+                    metrics = {"loss": hist["loss"][-1, -1]}
+                    timer.tick(hist["loss"])
+                boundary_span = spans.begin(
+                    "boundary", epoch=boundary, step=cur_step + K * steps_per_epoch
+                )
                 # the boundary fetch: K epochs of losses (and probe rows)
                 # come back in one transfer of K*steps_per_epoch floats
-                hist = jax.device_get(hist)
+                with spans.span("boundary/loss_fetch"):
+                    hist = jax.device_get(hist)
                 losses = np.asarray(hist["loss"])
                 cur_step += K * steps_per_epoch
                 if detector is not None:
@@ -794,19 +858,22 @@ def run_pretrain(cfg: Config) -> dict:
                     # same boundary-checkpoint contract as below; cur_step is
                     # a multiple of steps_per_epoch so this lands as the
                     # regular boundary checkpoint name
-                    timer.pause(metrics["loss"])
-                    path = os.path.join(
-                        save_dir,
-                        preempt_checkpoint_name(cur_step, steps_per_epoch, stem),
-                    )
-                    t_save = time.perf_counter()
-                    save_checkpoint(path, state)
-                    telemetry.observe_save(time.perf_counter() - t_save)
-                    events.emit(
-                        "preempt", step=cur_step, epoch=boundary, checkpoint=path
-                    )
-                    guard.beat_preempted(cur_step, boundary)
-                    raise PreemptedRun(path)
+                    seen_ns = time.monotonic_ns()
+                    end_boundary()
+                    with spans.span("preempt", epoch=boundary, step=cur_step) as pre:
+                        spans.add(
+                            "preempt/notice", guard.preempt_ns or seen_ns, seen_ns,
+                            parent=pre.id, epoch=boundary, step=cur_step,
+                        )
+                        path = os.path.join(
+                            save_dir,
+                            preempt_checkpoint_name(cur_step, steps_per_epoch, stem),
+                        )
+                        with spans.span("checkpoint"):
+                            with spans.span("checkpoint/drain"):
+                                timer.pause(metrics["loss"])
+                            timed_save(path)
+                        leave_preempted(path, boundary)
                 chunk_losses = [float(losses[j, -1]) for j in range(K)]
                 # checked_loss is the fault-injection seam on the single-epoch
                 # path; route the boundary loss through it so injected NaNs
@@ -817,18 +884,20 @@ def run_pretrain(cfg: Config) -> dict:
                 # per-host telemetry on EVERY host (the fleet skew gauge
                 # divides per-host step times): all inputs are host floats
                 # already in hand, so this adds no device syncs anywhere
-                for j, e in enumerate(chunk):
-                    step_e = epoch_start_step + (j + 1) * steps_per_epoch
-                    telemetry.observe_epoch(
-                        e,
-                        epochs=epochs,
-                        step=step_e,
-                        steps=steps_per_epoch,
-                        seconds=dt / K,
-                        loss=chunk_losses[j],
-                        lr=float(schedule(max(step_e - 1, 0))),
-                    )
-                guard.beat(cur_step, boundary, loss=epoch_loss)
+                with spans.span("boundary/telemetry"):
+                    for j, e in enumerate(chunk):
+                        step_e = epoch_start_step + (j + 1) * steps_per_epoch
+                        telemetry.observe_epoch(
+                            e,
+                            epochs=epochs,
+                            step=step_e,
+                            steps=steps_per_epoch,
+                            seconds=dt / K,
+                            loss=chunk_losses[j],
+                            lr=float(schedule(max(step_e - 1, 0))),
+                        )
+                with spans.span("beat"):
+                    guard.beat(cur_step, boundary, loss=epoch_loss)
                 if any(not math.isfinite(l) for l in chunk_losses):
                     # same rollback as the single-epoch path; under
                     # superepochs every checkpoint lands on a K boundary, so
@@ -858,26 +927,28 @@ def run_pretrain(cfg: Config) -> dict:
                     )
                     continue
                 if is_logging_host():
-                    lr_now = float(schedule(max(cur_step - 1, 0)))
-                    imgs_per_sec = (
-                        (cur_step - (start_epoch - 1) * steps_per_epoch)
-                        * global_batch / max(time.time() - t_start, 1e-9)
-                    )
-                    logger.info(
-                        "Epoch:%d/%d progress:%.3f loss:%.3f, lr:%.7f, "
-                        "imgs/sec:%.0f (superepoch of %d)",
-                        boundary, epochs, boundary / epochs, epoch_loss,
-                        lr_now, imgs_per_sec, K,
-                    )
+                    with spans.span("boundary/log"):
+                        lr_now = float(schedule(max(cur_step - 1, 0)))
+                        imgs_per_sec = (
+                            (cur_step - (start_epoch - 1) * steps_per_epoch)
+                            * global_batch / max(time.time() - t_start, 1e-9)
+                        )
+                        logger.info(
+                            "Epoch:%d/%d progress:%.3f loss:%.3f, lr:%.7f, "
+                            "imgs/sec:%.0f (superepoch of %d)",
+                            boundary, epochs, boundary / epochs, epoch_loss,
+                            lr_now, imgs_per_sec, K,
+                        )
                 # per-epoch rows reconstructed from the stacked metrics:
                 # results/events keep the exact shape K=1 produces
                 for j, e in enumerate(chunk):
                     step_e = epoch_start_step + (j + 1) * steps_per_epoch
                     loss_history.append([e, chunk_losses[j]])
-                    events.emit(
-                        "epoch", epoch=e, step=step_e, loss=chunk_losses[j],
-                        seconds=round(dt / K, 6),
-                    )
+                    with spans.span("boundary/event"):
+                        events.emit(
+                            "epoch", epoch=e, step=step_e, loss=chunk_losses[j],
+                            seconds=round(dt / K, 6),
+                        )
                     if probed[j]:
                         monitor_val_acc = float(hist["monitor/val_acc"][j])
                         telemetry.observe_val_acc(monitor_val_acc)
@@ -894,88 +965,15 @@ def run_pretrain(cfg: Config) -> dict:
                     or boundary == epochs
                 ):
                     path = os.path.join(save_dir, checkpoint_name(boundary, stem))
-                    timer.pause(metrics["loss"])
-                    t_save = time.perf_counter()
-                    save_checkpoint(path, state)
-                    telemetry.observe_save(time.perf_counter() - t_save)
+                    with spans.span("checkpoint"):
+                        with spans.span("checkpoint/drain"):
+                            timer.pause(metrics["loss"])
+                        timed_save(path)
                     events.emit("checkpoint", epoch=boundary, path=path)
                     guard.after_save(boundary, path)
+                    flush_spans()  # the timer is paused for I/O already
                     timer.resume()
-                write_results(
-                    {
-                        "epochs": epochs,
-                        "save_dir": save_dir,
-                        "loss_history": loss_history,
-                        "monitor_history": monitor_history,
-                        "complete": False,
-                    }
-                )
-                epoch += K
-                continue
-            if epoch_compile:
-                idx_e = jnp.asarray(
-                    epoch_index_matrix(
-                        len(dataset), seed, epoch, steps_per_epoch, global_batch
-                    )
-                )
-                state, hist = epoch_fn(state, images_all, idx_e, base_key, cur_step)
-                metrics = {"loss": hist["loss"][-1]}
-                if not superepoch:
-                    # under superepochs this path only runs tail epochs; the
-                    # timer's tick unit is K epochs, so tail epochs stay out
-                    timer.tick(hist["loss"])
-                cur_step += steps_per_epoch
-                if detector is not None:
-                    # one tick per epoch here: the detector's "step" unit is
-                    # whatever the host loop's unit of progress is
-                    detector.tick(cur_step, epoch)
-            else:
-                batches = iterator.batches(epoch)
-                if skip_steps:
-                    # mid-epoch resume: replay the epoch's deterministic
-                    # batch order past the consumed prefix; step RNG folds on
-                    # the absolute cur_step, so the continuation is exact
-                    batches = itertools.islice(batches, skip_steps, None)
-                    skip_steps = 0
-                for batch in prefetch(batches):
-                    tracer.tick(cur_step, pending=metrics["loss"])
-                    step_rng = jax.random.fold_in(base_key, cur_step)
-                    state, metrics = step_fn(state, batch["image"], step_rng)
-                    timer.tick(metrics["loss"])
-                    cur_step += 1
-                    if detector is not None:
-                        # BEFORE the beat: the beat is where fault injection
-                        # wedges, and the watchdog must already be armed to
-                        # catch exactly that class of hang
-                        detector.tick(cur_step, epoch)
-                    guard.beat(cur_step, epoch)
-                    if guard.preempt_requested:
-                        break
-            if detector is not None:
-                # epoch-boundary work (probe, checkpoint I/O, preempt saves)
-                # is not a step: disarm so it can never read as a stall, and
-                # keep its duration out of the step-time window
-                detector.pause()
-            if guard.preempt_requested:
-                # land a resumable checkpoint at this step boundary, then
-                # exit 75 via main() — at an exact epoch boundary this is the
-                # regular boundary checkpoint; mid-epoch it gets "-preempt"
-                timer.pause(metrics["loss"])
-                epoch_loss = float(metrics["loss"])
-                if (
-                    cur_step == epoch * steps_per_epoch
-                    and math.isfinite(epoch_loss)
-                    and (not loss_history or loss_history[-1][0] < epoch)
-                ):
-                    # the preempt landed on a completed epoch (elastic
-                    # grow-back drains SIGTERM at exactly this boundary):
-                    # its loss row and epoch event are in hand — persist
-                    # them, or the resumed run's history skips this epoch
-                    loss_history.append([epoch, epoch_loss])
-                    events.emit(
-                        "epoch", epoch=epoch, step=cur_step, loss=epoch_loss,
-                        seconds=round(time.perf_counter() - epoch_t0, 6),
-                    )
+                with spans.span("boundary/results"):
                     write_results(
                         {
                             "epochs": epochs,
@@ -985,34 +983,144 @@ def run_pretrain(cfg: Config) -> dict:
                             "complete": False,
                         }
                     )
-                path = os.path.join(
-                    save_dir,
-                    preempt_checkpoint_name(cur_step, steps_per_epoch, stem),
+                epoch += K
+                continue
+            if epoch_compile:
+                with spans.span("boundary/shuffle", epoch=epoch):
+                    idx_e = jnp.asarray(
+                        epoch_index_matrix(
+                            len(dataset), seed, epoch, steps_per_epoch, global_batch
+                        )
+                    )
+                end_boundary()
+                first_dispatch_next(
+                    epoch_fn, state, images_all, idx_e, base_key, cur_step
                 )
-                t_save = time.perf_counter()
-                save_checkpoint(path, state)
-                telemetry.observe_save(time.perf_counter() - t_save)
-                events.emit(
-                    "preempt", step=cur_step, epoch=epoch, checkpoint=path
-                )
-                guard.beat_preempted(cur_step, epoch)
-                raise PreemptedRun(path)
+                with spans.span("epoch", epoch=epoch, step=cur_step + 1):
+                    with spans.span("dispatch"):
+                        state, hist = epoch_fn(
+                            state, images_all, idx_e, base_key, cur_step
+                        )
+                    metrics = {"loss": hist["loss"][-1]}
+                    if not superepoch:
+                        # under superepochs this path only runs tail epochs;
+                        # the timer's tick unit is K epochs, so tail epochs
+                        # stay out
+                        timer.tick(hist["loss"])
+                    cur_step += steps_per_epoch
+                    if detector is not None:
+                        # one tick per epoch here: the detector's "step" unit
+                        # is whatever the host loop's unit of progress is
+                        detector.tick(cur_step, epoch)
+            else:
+                batches = iterator.batches(epoch)
+                if skip_steps:
+                    # mid-epoch resume: replay the epoch's deterministic
+                    # batch order past the consumed prefix; step RNG folds on
+                    # the absolute cur_step, so the continuation is exact
+                    batches = itertools.islice(batches, skip_steps, None)
+                    skip_steps = 0
+                batches = prefetch(batches)
+                with spans.span("boundary/first_batch", epoch=epoch):
+                    batch = next(batches, None)
+                end_boundary()
+                if batch is not None:
+                    first_dispatch_next(
+                        step_fn, state, batch["image"],
+                        jax.random.fold_in(base_key, cur_step),
+                    )
+                with spans.span("epoch", epoch=epoch, step=cur_step + 1) as epoch_span:
+                    while batch is not None:
+                        tracer.tick(cur_step, pending=metrics["loss"])
+                        # the key's fold_in is a device program too, and the
+                        # first of the two to meet the run-ahead limit: in
+                        # steady state this span is the wait for a free slot
+                        with spans.span("dispatch"):
+                            step_rng = jax.random.fold_in(base_key, cur_step)
+                            state, metrics = step_fn(state, batch["image"], step_rng)
+                        timer.tick(metrics["loss"])
+                        cur_step += 1
+                        if detector is not None:
+                            # BEFORE the beat: the beat is where fault
+                            # injection wedges, and the watchdog must already
+                            # be armed to catch exactly that class of hang
+                            detector.tick(cur_step, epoch)
+                        with spans.span("beat"):
+                            guard.beat(cur_step, epoch)
+                        if guard.preempt_requested:
+                            break
+                        epoch_span.step = cur_step + 1  # what the next spans serve
+                        batch = next(batches, None)
+            if detector is not None:
+                # epoch-boundary work (probe, checkpoint I/O, preempt saves)
+                # is not a step: disarm so it can never read as a stall, and
+                # keep its duration out of the step-time window
+                detector.pause()
+            if guard.preempt_requested:
+                # land a resumable checkpoint at this step boundary, then
+                # exit 75 via main() — at an exact epoch boundary this is the
+                # regular boundary checkpoint; mid-epoch it gets "-preempt"
+                seen_ns = time.monotonic_ns()
+                with spans.span("preempt", epoch=epoch, step=cur_step) as pre:
+                    spans.add(
+                        "preempt/notice", guard.preempt_ns or seen_ns, seen_ns,
+                        parent=pre.id, epoch=epoch, step=cur_step,
+                    )
+                    with spans.span("checkpoint"):
+                        with spans.span("checkpoint/drain"):
+                            timer.pause(metrics["loss"])
+                            epoch_loss = float(metrics["loss"])
+                        if (
+                            cur_step == epoch * steps_per_epoch
+                            and math.isfinite(epoch_loss)
+                            and (not loss_history or loss_history[-1][0] < epoch)
+                        ):
+                            # the preempt landed on a completed epoch (elastic
+                            # grow-back drains SIGTERM at exactly this
+                            # boundary): its loss row and epoch event are in
+                            # hand — persist them, or the resumed run's
+                            # history skips this epoch
+                            loss_history.append([epoch, epoch_loss])
+                            events.emit(
+                                "epoch", epoch=epoch, step=cur_step,
+                                loss=epoch_loss,
+                                seconds=round(time.perf_counter() - epoch_t0, 6),
+                            )
+                            write_results(
+                                {
+                                    "epochs": epochs,
+                                    "save_dir": save_dir,
+                                    "loss_history": loss_history,
+                                    "monitor_history": monitor_history,
+                                    "complete": False,
+                                }
+                            )
+                        path = os.path.join(
+                            save_dir,
+                            preempt_checkpoint_name(cur_step, steps_per_epoch, stem),
+                        )
+                        timed_save(path)
+                    leave_preempted(path, epoch)
 
-            epoch_loss = guard.checked_loss(cur_step, float(metrics["loss"]))
+            boundary_span = spans.begin("boundary", epoch=epoch, step=cur_step)
+            with spans.span("boundary/loss_fetch"):
+                epoch_loss = guard.checked_loss(cur_step, float(metrics["loss"]))
             # epoch telemetry BEFORE the boundary beat, so the beat's
             # snapshot (and any scrape) reflects the epoch that just
             # finished; every input is a host float already in hand, and
             # every host updates its OWN gauges for the fleet view
-            telemetry.observe_epoch(
-                epoch,
-                epochs=epochs,
-                step=cur_step,
-                steps=cur_step - epoch_start_step,
-                seconds=time.perf_counter() - epoch_t0,
-                loss=epoch_loss,
-                lr=float(schedule(max(cur_step - 1, 0))),
-            )
-            guard.beat(cur_step, epoch, loss=epoch_loss)
+            with spans.span("boundary/telemetry"):
+                telemetry.observe_epoch(
+                    epoch,
+                    epochs=epochs,
+                    step=cur_step,
+                    steps=cur_step - epoch_start_step,
+                    seconds=time.perf_counter() - epoch_t0,
+                    loss=epoch_loss,
+                    lr=float(schedule(max(cur_step - 1, 0))),
+                )
+            with spans.span("beat"):
+                guard.beat(cur_step, epoch, loss=epoch_loss)
             if not math.isfinite(epoch_loss):
                 # roll back to the newest verified checkpoint; a different
                 # RNG stream on the retry — deterministically replaying the
@@ -1040,45 +1148,53 @@ def run_pretrain(cfg: Config) -> dict:
                 continue
             if is_logging_host():
                 # one line per epoch, the reference's rank-0 log (main.py:124-127)
-                lr_now = float(schedule(max(cur_step - 1, 0)))
-                imgs_per_sec = (
-                    (cur_step - (start_epoch - 1) * steps_per_epoch)
-                    * global_batch / max(time.time() - t_start, 1e-9)
-                )
-                logger.info(
-                    "Epoch:%d/%d progress:%.3f loss:%.3f, lr:%.7f, imgs/sec:%.0f",
-                    epoch, epochs, epoch / epochs, epoch_loss, lr_now,
-                    imgs_per_sec,
-                )
+                with spans.span("boundary/log"):
+                    lr_now = float(schedule(max(cur_step - 1, 0)))
+                    imgs_per_sec = (
+                        (cur_step - (start_epoch - 1) * steps_per_epoch)
+                        * global_batch / max(time.time() - t_start, 1e-9)
+                    )
+                    logger.info(
+                        "Epoch:%d/%d progress:%.3f loss:%.3f, lr:%.7f, "
+                        "imgs/sec:%.0f",
+                        epoch, epochs, epoch / epochs, epoch_loss, lr_now,
+                        imgs_per_sec,
+                    )
             loss_history.append([epoch, epoch_loss])
-            events.emit(
-                "epoch", epoch=epoch, step=cur_step, loss=epoch_loss,
-                seconds=round(time.perf_counter() - epoch_t0, 6),
-            )
+            with spans.span("boundary/event"):
+                events.emit(
+                    "epoch", epoch=epoch, step=cur_step, loss=epoch_loss,
+                    seconds=round(time.perf_counter() - epoch_t0, 6),
+                )
             if eval_every > 0 and (epoch % eval_every == 0 or epoch == epochs):
-                timer.pause(metrics["loss"])  # keep probe compute out of imgs/sec
-                monitor_val_acc = run_monitor_probe(epoch)
-                monitor_history.append([epoch, monitor_val_acc])
-                timer.resume()
+                with spans.span("boundary/probe"):
+                    timer.pause(metrics["loss"])  # keep probe compute out of imgs/sec
+                    monitor_val_acc = run_monitor_probe(epoch)
+                    monitor_history.append([epoch, monitor_val_acc])
+                    timer.resume()
             if epoch % save_model_epoch == 0 or epoch == epochs:
                 path = os.path.join(save_dir, checkpoint_name(epoch, stem))
-                timer.pause(metrics["loss"])  # keep save I/O out of the imgs/sec window
-                t_save = time.perf_counter()
-                save_checkpoint(path, state)
-                telemetry.observe_save(time.perf_counter() - t_save)
+                with spans.span("checkpoint"):
+                    with spans.span("checkpoint/drain"):
+                        # keep save I/O out of the imgs/sec window
+                        timer.pause(metrics["loss"])
+                    timed_save(path)
                 events.emit("checkpoint", epoch=epoch, path=path)
                 guard.after_save(epoch, path)
+                flush_spans()  # the timer is paused for I/O already
                 timer.resume()
-            write_results(
-                {
-                    "epochs": epochs,
-                    "save_dir": save_dir,
-                    "loss_history": loss_history,
-                    "monitor_history": monitor_history,
-                    "complete": False,
-                }
-            )
+            with spans.span("boundary/results"):
+                write_results(
+                    {
+                        "epochs": epochs,
+                        "save_dir": save_dir,
+                        "loss_history": loss_history,
+                        "monitor_history": monitor_history,
+                        "complete": False,
+                    }
+                )
             epoch += 1
+        end_boundary()
     except Exception as exc:
         # an allocator RESOURCE_EXHAUSTED leaves its forensic behind —
         # device memory profile + oom event — before the error propagates;
@@ -1092,6 +1208,10 @@ def run_pretrain(cfg: Config) -> dict:
             detector.close()
         if exporter is not None:
             exporter.close()
+        # on a normal end, a preemption, a poisoned run and any exception:
+        # what is still open is written as open, which says where it stopped
+        flush_spans()
+        spans.reset()
 
     tracer.close(pending=metrics["loss"])
     throughput = timer.summary()
@@ -1138,6 +1258,9 @@ def main(argv: list[str] | None = None):
     from simclr_tpu.supervisor.guard import EXIT_POISONED, EXIT_PREEMPTED
     from simclr_tpu.utils.platform import configure_compilation_cache
 
+    # boot/runtime: from here to the backend's start in run_pretrain
+    recorder().reset()
+    recorder().begin("boot/runtime")
     configure_compilation_cache()
     maybe_initialize_multihost()
     multirun, args = split_multirun_flag(list(sys.argv[1:] if argv is None else argv))

@@ -16,11 +16,13 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from simclr_tpu.models.heads import ProjectionHead
 from simclr_tpu.models.resnet import ResNetEncoder
+from simclr_tpu.obs.trace import SCOPE_HEAD
 
 Dtype = Any
 
@@ -62,7 +64,9 @@ class ContrastiveModel(nn.Module):
 
     def __call__(self, x, train: bool = True):
         h = self.encode(x, train=train)
-        return self.g(h, train=train)
+        # the projection head's share of the step's `encoder` scope
+        with jax.named_scope(SCOPE_HEAD):
+            return self.g(h, train=train)
 
 
 class SupervisedModel(nn.Module):

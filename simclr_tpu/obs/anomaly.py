@@ -11,12 +11,25 @@ module answers *when* and records *why*.  The trainers tick a
   ``median + slow_factor * MAD`` (MAD floored at 5% of the median so a
   perfectly steady stream, whose MAD is ~0, never flags ordinary
   jitter).  Emitted into ``events.jsonl`` and counted in ``/metrics``.
+  On an accelerator the host runs ahead of the device: a run begins with
+  a burst of dispatches (11 of ~5 ms on the v5e) before the first one
+  blocks on the device and ticks become step times (~126 ms), the burst
+  comes again after every boundary's sync, and where an epoch is shorter
+  than the burst (6 steps on four chips) no dispatch ever blocks inside
+  the loop. A dispatch is not a step. So, first, until the window has
+  filled once, the first tick that stands out is read as the first
+  dispatch that blocked, and the window is seeded anew from it with no
+  alarm; second, a tick far under the last epoch's mean step time (the
+  telemetry's, which divides the epoch's wall time, sync included, by its
+  steps) is a dispatch: neither sampled nor classified.
 * **stall** — a wedged host loop cannot report its own absence, so a
   daemon watchdog thread is armed at every tick with a deadline of
   ``max(stall_min_s, stall_factor * median)``; if no tick (or
   :meth:`StepAnomalyDetector.pause`) lands in time, the watchdog emits a
   ``stall`` event from its own thread while the loop is still stuck —
-  before the supervisor's heartbeat timeout SIGKILLs the process.
+  before the supervisor's heartbeat timeout SIGKILLs the process.  The
+  event names the host span open on the main thread (``obs/trace.py``),
+  and the span recorder is flushed, so the file shows the call it sat in.
 * **auto_trace** — both anomaly kinds can fire the existing
   ``capture_trace`` machinery (``utils/profiling.py``) so the chip's
   state at the moment of the anomaly is recorded with no operator
@@ -38,6 +51,7 @@ import threading
 import time
 
 from simclr_tpu.obs.events import EventLog
+from simclr_tpu.obs.trace import recorder, spans_path
 
 logger = logging.getLogger("simclr_tpu")
 
@@ -97,6 +111,9 @@ class StepAnomalyDetector:
         # the window must hold at least `warmup` samples or the detector
         # could never leave its grace period
         self._samples = collections.deque(maxlen=max(int(window), self.warmup))
+        # False until the first dispatch that blocked has been seen, or the
+        # window has filled with ticks among which none stood out
+        self._seeded = False
         self._last_tick: float | None = None
         self._step = 0
         self._epoch = 0
@@ -117,49 +134,64 @@ class StepAnomalyDetector:
         mad = statistics.median(abs(x - med) for x in self._samples)
         return med, mad
 
+    def _step_time(self) -> float:
+        """The last epoch's mean step time as the telemetry has it (the
+        epoch's wall time, its sync included, over its steps); 0.0 before
+        the first epoch ends or without a telemetry that knows."""
+        return float(getattr(self.telemetry, "step_time_s", 0.0) or 0.0)
+
     def tick(self, step: int = 0, epoch: int = 0) -> str | None:
         """Record one completed step; returns ``"slow_step"`` when the
         step classified as anomalous, else None."""
         now = self._clock()
         self._step, self._epoch = int(step), int(epoch)
         verdict = None
-        if self._last_tick is not None:
-            dt = now - self._last_tick
+        dt = None if self._last_tick is None else now - self._last_tick
+        # a tick far under the epoch's mean step time is a run-ahead
+        # dispatch, not a step
+        if dt is not None and dt * self.slow_factor >= self._step_time():
             med, mad = self._stats()
             if med is not None:
                 threshold = med + self.slow_factor * max(
                     mad, _MAD_FLOOR_FRAC * med, _MAD_FLOOR_ABS
                 )
-                if dt > threshold:
-                    verdict = SLOW_STEP_EVENT
-                    self.slow_steps += 1
-                    if self.telemetry is not None:
-                        self.telemetry.record_slow_step()
-                    if self.events is not None:
-                        self.events.emit(
-                            SLOW_STEP_EVENT,
-                            step=self._step,
-                            epoch=self._epoch,
-                            seconds=round(dt, 6),
-                            median_s=round(med, 6),
-                            threshold_s=round(threshold, 6),
-                        )
-                    logger.warning(
-                        "slow step %d: %.3fs vs median %.3fs (threshold %.3fs)",
-                        self._step,
-                        dt,
-                        med,
-                        threshold,
-                    )
-                    self._maybe_auto_trace(SLOW_STEP_EVENT, dt)
+                if dt > threshold and self._seeded:
+                    verdict = self._slow_step(dt, med, threshold)
+                elif dt > threshold:
+                    # the run-ahead burst is over: this dispatch blocked on
+                    # the device, and step times start with it
+                    self._samples.clear()
+                    self._seeded = True
             self._samples.append(dt)
+            if len(self._samples) == self._samples.maxlen:
+                self._seeded = True  # a full window, and no tick stood out
         self._last_tick = now
-        med, _ = self._stats()
-        if med is not None:
+        pace = self._stats()[0] or self._step_time()
+        if pace:
             self._watchdog.arm(
-                now + max(self.stall_min_s, self.stall_factor * med)
+                now + max(self.stall_min_s, self.stall_factor * pace)
             )
         return verdict
+
+    def _slow_step(self, dt: float, med: float, threshold: float) -> str:
+        self.slow_steps += 1
+        if self.telemetry is not None:
+            self.telemetry.record_slow_step()
+        if self.events is not None:
+            self.events.emit(
+                SLOW_STEP_EVENT,
+                step=self._step,
+                epoch=self._epoch,
+                seconds=round(dt, 6),
+                median_s=round(med, 6),
+                threshold_s=round(threshold, 6),
+            )
+        logger.warning(
+            "slow step %d: %.3fs vs median %.3fs (threshold %.3fs)",
+            self._step, dt, med, threshold,
+        )
+        self._maybe_auto_trace(SLOW_STEP_EVENT, dt)
+        return SLOW_STEP_EVENT
 
     def pause(self) -> None:
         """Disarm across non-step work (probe, checkpoint, validation):
@@ -175,6 +207,11 @@ class StepAnomalyDetector:
     def _on_stall(self, armed_at: float) -> None:
         silence = self._clock() - armed_at
         self.stalls += 1
+        # the call the host loop sits in: the innermost span open on the main
+        # thread, written out with everything recorded so far
+        spans = recorder()
+        stuck = spans.current(threading.main_thread().ident)
+        in_span = stuck.name if stuck is not None else None
         if self.telemetry is not None:
             self.telemetry.record_stall()
         if self.events is not None:
@@ -183,12 +220,19 @@ class StepAnomalyDetector:
                 step=self._step,
                 epoch=self._epoch,
                 silence_s=round(silence, 3),
+                span=in_span,
             )
+        try:
+            spans.flush(spans_path(self.save_dir))
+        except OSError as exc:
+            logger.warning("spans not written on a stall: %s", exc)
         logger.warning(
-            "stall: no step completed for %.1fs after step %d (epoch %d)",
+            "stall: no step completed for %.1fs after step %d (epoch %d), in "
+            "span %s",
             silence,
             self._step,
             self._epoch,
+            in_span,
         )
         self._maybe_auto_trace(STALL_EVENT, silence)
 

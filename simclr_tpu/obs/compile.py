@@ -21,6 +21,14 @@ loses its performance:
   abstract argument signature, so any post-warmup compilation is observed
   the moment it happens: alarm counter + ``recompile_alarm`` event +
   the PR 7 rate-limited auto-trace hook.
+* **Scope map** — a device trace names each executed op by its
+  instruction (``%fusion.33 = ...``) and, read through
+  ``jax.profiler.ProfileData``, carries no ``op_name``; the compiled
+  program's text does (``metadata={op_name="jit(step)/encoder/..."}``, the
+  ``jax.named_scope`` path). :func:`instruction_scopes` reads it once per
+  compile into ``<save_dir>/step_scopes.json``, which lets a reader
+  (``benchmarks/scopes.py``) set each op of a trace to augment / encoder /
+  head / ntxent / grad_reduce / lars.
 
 ``watch`` takes the AOT path (``fn.lower(*args).compile()`` — the only
 way to both time a compile precisely and keep the executable for
@@ -32,12 +40,99 @@ inside the signature helper.
 
 from __future__ import annotations
 
+import collections
 import hashlib
+import json
+import logging
+import os
+import re
 import threading
 import time
 
+from simclr_tpu.obs.trace import recorder
+from simclr_tpu.utils.ioutil import atomic_write
+
+logger = logging.getLogger("simclr_tpu")
+
 COMPILE_EVENT = "compile"
 RECOMPILE_ALARM_EVENT = "recompile_alarm"
+
+
+# {instruction name: op_name} of the newest watched program, beside events.jsonl
+STEP_SCOPES_NAME = "step_scopes.json"
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([^\s=]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_FUSION_CALLS = re.compile(r" fusion\(.*?calls=%?([^\s,}]+)")
+_OPERAND = re.compile(r"%([^\s,()]+)")
+
+
+def instruction_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: op_name}`` of a compiled program's HLO text, for
+    every instruction a device trace can show: those of the entry computation
+    and of the computations it calls or loops over, not the insides of
+    fusions. A fusion that carries no ``op_name`` itself takes its root
+    instruction's (xprof's rule), or where the root has none, the one most of
+    its instructions carry. An instruction the compiler added without
+    metadata (a prefetch copy and its ``-start``/``-done``, a slice, a
+    reshape) moves what its first operand made, and takes that one's
+    ``op_name``. What still has none (constants, parameters' copies) is left
+    out: a reader counts it as unscoped."""
+    computations: dict[str, list[tuple]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            current = computations.setdefault(m.group(1), []) if m else None
+            continue
+        m = _INSTRUCTION.match(line) if current is not None else None
+        if m is None:
+            continue
+        op_name = _OP_NAME.search(line)
+        calls = _FUSION_CALLS.search(line)
+        operand = None if op_name else _OPERAND.search(line, m.end())
+        current.append((
+            m.group(2), op_name.group(1) if op_name else None,
+            bool(m.group(1)), calls.group(1) if calls else None,
+            operand.group(1) if operand else None,
+        ))
+    fused = {
+        calls for rows in computations.values() for *_, calls, _ in rows if calls
+    }
+
+    def fusion_op_name(computation: str, depth: int = 0) -> str | None:
+        rows = computations.get(computation, ())
+        for _, op_name, is_root, calls, _ in rows:
+            if is_root and not op_name and calls and depth < 4:
+                op_name = fusion_op_name(calls, depth + 1)  # a fusion of fusions
+            if is_root and op_name:
+                return op_name
+        # a root the compiler made (the tuple of a multi-output fusion, a
+        # copy): the op_name most of the fused instructions carry
+        names = collections.Counter(op for _, op, *_ in rows if op)
+        return names.most_common(1)[0][0] if names else None  # ties: the first
+
+    scopes, moved_from = {}, {}
+    for computation, rows in computations.items():
+        if computation in fused:
+            continue
+        for name, op_name, _, calls, operand in rows:
+            if op_name is None and calls:
+                op_name = fusion_op_name(calls)
+            if op_name:
+                scopes[name] = op_name
+            elif operand:
+                moved_from[name] = operand
+    for name in moved_from:
+        source = name
+        for _ in range(8):  # copy-done <- copy-start <- the producer
+            source = moved_from.get(source)
+            if source is None or source in scopes:
+                break
+        if source in scopes:
+            scopes[name] = scopes[source]
+    return scopes
 
 
 def executable_cost(compiled) -> tuple[float, float]:
@@ -101,9 +196,12 @@ class CompileSentry:
         events=None,
         auto_trace=None,
         clock=time.perf_counter,
+        scopes_dir: str | None = None,
     ):
         self.telemetry = telemetry
         self.events = events
+        # where step_scopes.json goes (the run directory); None writes none
+        self.scopes_dir = scopes_dir
         self._auto_trace = auto_trace
         self._clock = clock
         self._lock = threading.Lock()
@@ -185,6 +283,32 @@ class CompileSentry:
                     pass
         return record
 
+    def write_scopes(self, compiled) -> None:
+        """``step_scopes.json`` for this executable, inside the compile's
+        span and seconds (0.28 s to print the ResNet-18 step's 5 MB of HLO
+        on the v5e, 0.08 s to read it; chip run, PR 25). A failure costs the
+        trace reader its scopes and the run nothing."""
+        if self.scopes_dir is None:
+            return
+        try:
+            t0 = time.perf_counter()
+            text = compiled.as_text()
+            t1 = time.perf_counter()
+            scopes = instruction_scopes(text)
+            t2 = time.perf_counter()
+            os.makedirs(self.scopes_dir, exist_ok=True)
+            atomic_write(
+                os.path.join(self.scopes_dir, STEP_SCOPES_NAME),
+                lambda f: json.dump(scopes, f),
+            )
+            logger.info(
+                "%s: %d instructions (text %.2fs, read %.2fs, written %.2fs)",
+                STEP_SCOPES_NAME, len(scopes), t1 - t0, t2 - t1,
+                time.perf_counter() - t2,
+            )
+        except Exception as e:  # a backend without as_text, a full disk
+            logger.warning("%s not written: %r", STEP_SCOPES_NAME, e)
+
     def watch(self, fn, name: str, *, steps_from_args=None):
         """Wrap a jitted callable so its every compilation is observed."""
         return WatchedFunction(fn, name, self, steps_from_args=steps_from_args)
@@ -223,48 +347,63 @@ class WatchedFunction:
             return 1
 
     def __call__(self, *args):
-        sig = args_signature(args)
-        entry = self._cache.get(sig)
-        if entry is not None:
-            self._calls += 1
-            return entry(*args)
-        warm = self._calls > 0
+        entry = self._cache.get(args_signature(args))
+        if entry is None:
+            if not hasattr(self._fn, "lower"):
+                return self._first_plain_call(args)
+            entry = self.compile(*args)
+        self._calls += 1
+        return entry(*args)
+
+    def _first_plain_call(self, args):
+        """Not a jitted function: dispatch plainly — the first call at a new
+        signature still IS the compiling call, so its duration is the
+        (upper-bound) compile time."""
         clock = self._sentry._clock
         t0 = clock()
-        if not hasattr(self._fn, "lower"):
-            # not a jitted function: dispatch plainly — the first call at a
-            # new signature still IS the compiling call, so its duration is
-            # the (upper-bound) compile time
-            out = self._fn(*args)
-            self._sentry.record_compile(
-                self.name,
-                seconds=clock() - t0,
-                warm=warm,
-                steps_per_call=self._steps_per_call(args),
-            )
-            self._cache[sig] = self._fn
-            self._calls += 1
-            return out
-        from simclr_tpu.utils.platform import compilation_cache_stats
-
-        cache_hits = compilation_cache_stats()["hits"]
-        lowered = self._fn.lower(*args)
-        compiled = lowered.compile()
-        cache_hit = compilation_cache_stats()["hits"] > cache_hits
-        flops, bytes_accessed = executable_cost(compiled)
+        out = self._fn(*args)
         self._sentry.record_compile(
             self.name,
             seconds=clock() - t0,
-            fingerprint=lowered_fingerprint(lowered),
-            flops=flops,
-            bytes_accessed=bytes_accessed,
-            warm=warm,
+            warm=self._calls > 0,
             steps_per_call=self._steps_per_call(args),
-            cache_hit=cache_hit,
         )
-        self._cache[sig] = compiled
+        self._cache[args_signature(args)] = self._fn
         self._calls += 1
-        return compiled(*args)
+        return out
+
+    def compile(self, *args):
+        """The executable for these arguments' signature, lowered, compiled
+        and recorded on a miss. The host loop calls this before its first
+        dispatch, so that the compilation is a span of its own
+        (``boot/compile``) and the first ``dispatch`` span is a dispatch."""
+        sig = args_signature(args)
+        entry = self._cache.get(sig)
+        if entry is not None or not hasattr(self._fn, "lower"):
+            return entry
+        from simclr_tpu.utils.platform import compilation_cache_stats
+
+        clock = self._sentry._clock
+        with recorder().span("boot/compile"):
+            t0 = clock()
+            cache_hits = compilation_cache_stats()["hits"]
+            lowered = self._fn.lower(*args)
+            compiled = lowered.compile()
+            cache_hit = compilation_cache_stats()["hits"] > cache_hits
+            flops, bytes_accessed = executable_cost(compiled)
+            self._sentry.write_scopes(compiled)
+            self._sentry.record_compile(
+                self.name,
+                seconds=clock() - t0,
+                fingerprint=lowered_fingerprint(lowered),
+                flops=flops,
+                bytes_accessed=bytes_accessed,
+                warm=self._calls > 0,
+                steps_per_call=self._steps_per_call(args),
+                cache_hit=cache_hit,
+            )
+        self._cache[sig] = compiled
+        return compiled
 
 
 def maybe_sentry(cfg, *, telemetry=None, events=None, detector=None):
@@ -276,6 +415,10 @@ def maybe_sentry(cfg, *, telemetry=None, events=None, detector=None):
     if not bool(cfg.select("telemetry.compile_sentry", True)):
         return None
     auto_trace = detector._maybe_auto_trace if detector is not None else None
+    scopes_dir = None
+    if events is not None and events.enabled:
+        scopes_dir = os.path.dirname(events.path)
     return CompileSentry(
-        telemetry=telemetry, events=events, auto_trace=auto_trace
+        telemetry=telemetry, events=events, auto_trace=auto_trace,
+        scopes_dir=scopes_dir,
     )

@@ -457,13 +457,6 @@ def render_report(report: dict) -> str:
         )
         if serve.get("last_swap_epoch") is not None:
             lines.append(f"  last swap: epoch {serve['last_swap_epoch']}")
-    telemetry = report.get("telemetry") or {}
-    if telemetry.get("exposed_comm_ms") is not None:
-        # step time beyond roofline compute — the wire the scheduler did NOT
-        # hide; compare runs across comm_overlap=off|chunked|async
-        lines.append(
-            f"exposed comm: {float(telemetry['exposed_comm_ms']):.3f} ms/step"
-        )
     detail = (
         f"imgs/s/chip measured={report['measured_imgs_per_sec_per_chip']} "
         f"baseline={report['baseline_imgs_per_sec_per_chip']} "

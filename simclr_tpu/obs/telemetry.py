@@ -9,9 +9,10 @@ lr, wall-clock epoch durations. Rendering ``/metrics`` reads those floats
 back; no method here ever touches a ``jax.Array``, so a scrape can never
 add a device round-trip to the hot loop.
 
-MFU reuses the analytic FLOP model from ``scripts/roofline_model.py``: FLOPs
-per device-step divided by measured step time over the chip's bf16 peak
-(``obs/peaks.py``, keyed by ``device_kind``; no peak, no MFU).
+MFU divides XLA's own count of the step program's FLOPs per device-step
+(the compile sentry's cost analysis, ``observe_xla_cost``) by the measured
+step time over the chip's bf16 peak (``obs/peaks.py``, keyed by
+``device_kind``; no peak or no recorded step program, no MFU).
 Grad-allreduce wire bytes come from
 :func:`simclr_tpu.parallel.compress.allreduce_wire_bytes` — analytic, per
 device, per step.
@@ -19,7 +20,6 @@ device, per step.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 
@@ -33,71 +33,29 @@ STEP_TIME_BUCKETS = (
 )
 
 
-def _roofline_flops_per_step(
-    arch: str, per_device_batch: int, d: int, augment_impl: str = "xla"
-) -> float | None:
-    """Total FLOPs of one per-device train step from the roofline model.
-
-    ``scripts/`` is not a package, so the model is loaded by file path
-    relative to the repo root; an installed-without-scripts tree degrades to
-    ``None`` (MFU gauge stays 0) rather than failing the run.
-
-    ``augment_impl`` selects the augmentation row's byte accounting (the
-    fused Pallas kernel reclaims HBM bandwidth); the step's FLOPs are
-    impl-invariant today, but threading the knob keeps the live MFU and
-    drift gauges attributed to the program actually running.
-    """
-    import importlib.util
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-        "scripts",
-        "roofline_model.py",
-    )
-    try:
-        spec = importlib.util.spec_from_file_location("simclr_roofline", path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return float(
-            sum(
-                op[1]
-                for op in module.model_step(
-                    arch, per_device_batch, d=d, augment_impl=augment_impl
-                )
-            )
-        )
-    except Exception:
-        return None
-
-
 class Telemetry:
     """The run's metric registry; see module docstring. Usage::
 
-        telemetry = Telemetry(arch="resnet18", per_device_batch=512, ...)
+        telemetry = Telemetry(global_batch=512, n_devices=1, ...)
         telemetry.observe_epoch(epoch, loss=..., lr=..., steps=..., seconds=...)
         text = telemetry.render()        # the /metrics payload
         beat = telemetry.snapshot()      # the heartbeat.json enrichment
 
-    ``flops_per_step`` applies to the PRETRAIN step shape (two views +
-    NT-Xent + LARS); the supervised entry point passes ``arch=None`` so its
-    MFU gauge honestly reads 0 instead of borrowing the wrong model.
-    ``peak_flops`` is the chip's bf16 peak from ``obs/peaks.py``; ``None``
-    (the CPU platform) leaves the MFU and exposed-comm gauges unset.
+    ``flops_per_step`` is XLA's FLOP count of the newest watched step
+    program, per device-step (``observe_xla_cost``); until one is recorded
+    the MFU gauge reads 0. ``peak_flops`` is the chip's bf16 peak from
+    ``obs/peaks.py``; ``None`` (the CPU platform) leaves the MFU gauge unset.
     """
 
     def __init__(
         self,
         *,
-        arch: str | None,
-        per_device_batch: int,
         global_batch: int,
         n_devices: int,
         mesh_hosts: int = 1,
-        d: int = 128,
         grad_allreduce: str = "exact",
         grad_elements: int | None = None,
         allreduce_devices: int | None = None,
-        augment_impl: str = "xla",
         comm_overlap: str = "off",
         comm_chunks: int = 1,
         peak_flops: float | None = None,
@@ -105,10 +63,7 @@ class Telemetry:
         self.global_batch = int(global_batch)
         self.n_devices = max(int(n_devices), 1)
         self.peak_flops = float(peak_flops) if peak_flops else None
-        self.flops_per_step = (
-            _roofline_flops_per_step(arch, per_device_batch, d, augment_impl)
-            if arch else None
-        )
+        self.flops_per_step: float | None = None
         self._lock = threading.Lock()
 
         self.step_time = Histogram(
@@ -124,9 +79,9 @@ class Telemetry:
             "Per-device training throughput over the last epoch")
         self.mfu = Gauge(
             "simclr_train_mfu",
-            "Model FLOPs utilization vs the chip's bf16 peak, from the roofline "
-            "FLOP model (scripts/roofline_model.py; 0 when no model or no "
-            "peak applies)")
+            "Model FLOPs utilization vs the chip's bf16 peak, from XLA's FLOP "
+            "count of the compiled step program (0 until one is recorded, or "
+            "when no peak applies)")
         self.loss = Gauge(
             "simclr_train_loss", "Epoch-mean training loss (last epoch)")
         self.lr = Gauge(
@@ -185,16 +140,6 @@ class Telemetry:
             "remesh-down, recovers on grow-back (parallel/mesh.py "
             "mesh_host_count)")
         self.mesh_hosts.set(float(max(int(mesh_hosts), 1)))
-        self.mfu_xla_drift = Gauge(
-            "simclr_train_mfu_roofline_xla_drift",
-            "Fractional drift of the roofline FLOP model feeding the live "
-            "MFU gauge vs XLA's analytic cost for the step executable "
-            "(roofline/xla - 1; 0 until a step cost is recorded)")
-        self.exposed_comm_ms = Gauge(
-            "simclr_train_exposed_comm_ms",
-            "Step wall time in excess of the roofline compute time, in ms — "
-            "the communication the scheduler did NOT hide (0 when no roofline "
-            "model applies; compare across comm_overlap=off|chunked|async)")
         self.grad_allreduce_mode = str(grad_allreduce)
         self.comm_overlap = str(comm_overlap)
         self.comm_chunks = int(comm_chunks)
@@ -223,11 +168,12 @@ class Telemetry:
             self.checkpoint_saves, self.nan_rollbacks,
             self.anomaly_slow_steps, self.anomaly_stalls, self.auto_traces,
             self.scrape_disconnects, self.compiles, self.compile_seconds,
-            self.recompile_alarms, self.mesh_hosts, self.mfu_xla_drift,
-            self.exposed_comm_ms,
+            self.recompile_alarms, self.mesh_hosts,
         )
         self._started = time.time()
-        self._last_step_time = 0.0
+        # mean step time over the last completed epoch: its wall time, the
+        # boundary's sync included, over its steps (the anomaly detector reads it)
+        self.step_time_s = 0.0
 
     def attach_device_monitor(self, monitor) -> None:
         """Render the DeviceMonitor's HBM gauges with every scrape.
@@ -264,19 +210,12 @@ class Telemetry:
         seconds = max(float(seconds), 1e-9)
         step_time = seconds / steps
         self.step_time.observe(step_time)
-        self._last_step_time = step_time
+        self.step_time_s = step_time
         rate = steps * self.global_batch / seconds
         self.imgs_per_sec.set(rate)
         self.imgs_per_sec_per_chip.set(rate / self.n_devices)
         if self.flops_per_step and self.peak_flops:
             self.mfu.set(self.flops_per_step / (step_time * self.peak_flops))
-            # what the step spent beyond roofline compute: at 100% overlap
-            # this tends to 0, and the off->chunked->async deltas attribute
-            # exactly how much of the ring the scheduler hid
-            self.exposed_comm_ms.set(
-                max(0.0, step_time - self.flops_per_step / self.peak_flops)
-                * 1000.0
-            )
 
     def observe_save(self, seconds: float) -> None:
         self.checkpoint_save_seconds.observe(float(seconds))
@@ -314,18 +253,15 @@ class Telemetry:
         self, name: str, *, flops_per_step: float = 0.0,
         bytes_per_step: float = 0.0,
     ) -> None:
-        """Per-executable analytic cost from the compile sentry.
-
-        When the roofline FLOP model applies (pretrain), the drift gauge
-        reconciles it against XLA's own analytic flops for the same step —
-        the continuous version of the scripts/perf_attrib.py survey.
-        """
+        """Per-executable analytic cost from the compile sentry. Every
+        executable the trainers watch is a step program, so the newest one's
+        FLOPs per device-step are what the MFU gauge divides."""
         with self._lock:
             self._xla_costs[str(name)] = (
                 float(flops_per_step), float(bytes_per_step)
             )
-        if self.flops_per_step and flops_per_step > 0:
-            self.mfu_xla_drift.set(self.flops_per_step / flops_per_step - 1.0)
+        if flops_per_step > 0:
+            self.flops_per_step = float(flops_per_step)
 
     # -- read side ----------------------------------------------------------
     def snapshot(self) -> dict:
@@ -338,11 +274,10 @@ class Telemetry:
             "lr": self.lr.value,
             # the FleetCollector's skew ratio divides these across hosts,
             # so the snapshot carries the scalar, not just the histogram
-            "step_time_s": self._last_step_time,
+            "step_time_s": self.step_time_s,
             "imgs_per_sec": self.imgs_per_sec.value,
             "imgs_per_sec_per_chip": self.imgs_per_sec_per_chip.value,
             "mfu": self.mfu.value,
-            "exposed_comm_ms": self.exposed_comm_ms.value,
             "slow_steps": self.anomaly_slow_steps.value,
             "stalls": self.anomaly_stalls.value,
             "auto_traces": self.auto_traces.value,

@@ -18,7 +18,12 @@ renders as tracks:
   * a supervisor track carrying ``run_start``/``child_exit``/``restart``/
     ``remesh 2→1``/``grow_back``/``outcome`` lifecycle markers;
   * a serve track for ``serve_*`` events (e.g. a ``serve_swap`` span when
-    the serving tier swaps weights mid-run).
+    the serving tier swaps weights mid-run);
+  * under each host, one row per thread of the trainer's host spans
+    (``spans.jsonl`` / ``spans.p<i>.jsonl``, ``obs/trace.py``): boot,
+    dispatch, input wait, boundary, checkpoint and preempt spans with their
+    ``step``, ``epoch`` and ``n``, set on the wall clock by the file's
+    clock anchor.
 
 Within one track the ``tid`` is the attempt (supervisor restart ordinal or
 elastic generation), so attempts stack as separate rows under each host.
@@ -39,6 +44,7 @@ import re
 import sys
 
 from simclr_tpu.obs.events import events_path, read_events_counted
+from simclr_tpu.obs.trace import read_spans, spans_path
 from simclr_tpu.supervisor.heartbeat import HEARTBEAT_NAME, read_heartbeat
 
 TRACE_NAME = "timeline_trace.json"
@@ -48,6 +54,8 @@ TRACE_NAME = "timeline_trace.json"
 PID_SUPERVISOR = 1
 PID_SERVE = 2
 PID_HOST_BASE = 10  # host slot i renders as pid 10 + i
+# attempts are tids 1, 2, ...; a host's span threads follow from here
+TID_SPAN_BASE = 1000
 
 # supervisor/lifecycle event kinds (everything the trainers do NOT emit)
 _LIFECYCLE = {
@@ -151,8 +159,17 @@ def build_timeline(run_dir: str) -> dict:
         if beat is not None and _num(beat.get("time")) is not None:
             heartbeats[slot] = beat
 
+    host_spans = {
+        slot: spans for slot in slots
+        if (spans := read_spans(spans_path(run_dir, slot)))
+    }
+
     base_candidates = [e["time"] for e in timed]
     base_candidates += [b["time"] for b in heartbeats.values()]
+    base_candidates += [
+        (s["t0"] + s["wall_offset_ns"]) / 1e9
+        for spans in host_spans.values() for s in spans[:1]
+    ]
     base = min(base_candidates) if base_candidates else 0.0
 
     def us(when: float) -> int:
@@ -202,6 +219,25 @@ def build_timeline(run_dir: str) -> dict:
                 if beat.get(k) is not None
             },
         })
+    for slot, spans in host_spans.items():
+        pid = PID_HOST_BASE + slot
+        threads = sorted({str(s.get("thread", "?")) for s in spans})
+        for i, thread in enumerate(threads):
+            trace.append({
+                "ph": "M", "name": "thread_name", "pid": pid,
+                "tid": TID_SPAN_BASE + i, "args": {"name": f"spans: {thread}"},
+            })
+        for span in spans:
+            body.append({
+                "ph": "X", "name": span["name"], "pid": pid,
+                "tid": TID_SPAN_BASE + threads.index(str(span.get("thread", "?"))),
+                "ts": us((span["t0"] + span["wall_offset_ns"]) / 1e9),
+                "dur": max(0, int(round((span["t1"] - span["t0"]) / 1e3))),
+                "args": {
+                    k: span[k] for k in ("step", "epoch", "n", "open")
+                    if span.get(k) is not None
+                },
+            })
     body.sort(key=lambda e: (e["ts"], e["pid"], e["tid"]))
     trace.extend(body)
 

@@ -9,15 +9,25 @@ land in a :class:`TraceRecorder`, which keeps a bounded ring of the
 slowest requests (served at ``GET /debug/slow``) and optionally samples
 a deterministic fraction into a ``requests.jsonl`` sidecar.
 
-Everything here is host-clock arithmetic on floats the serve path
-already computes — tracing never touches a device value, so the
+The trainer uses the same span primitive through one process-wide
+:class:`SpanRecorder` (``recorder()``): always on, in memory only, written
+to ``<save_dir>/spans.jsonl`` at flush points. The names of its spans and of
+the step program's ``jax.named_scope`` scopes live here, in two tuples,
+because the readers (``obs/timeline.py``, ``benchmarks/spans.py``,
+``benchmarks/scopes.py``) find them by name.
+
+Everything here is host-clock arithmetic on numbers the serve path and the
+host loop already have — tracing never touches a device value, so the
 zero-sync discipline of ``docs/OBSERVABILITY.md`` holds with spans on.
 """
 
 from __future__ import annotations
 
+import collections
 import heapq
+import itertools
 import json
+import os
 import threading
 import time
 import uuid
@@ -57,6 +67,8 @@ class RequestTrace:
     """
 
     __slots__ = ("request_id", "t0", "_spans", "_lock")
+
+    clock = staticmethod(time.perf_counter)
 
     def __init__(self, request_id: str | None = None):
         self.request_id = request_id or new_request_id()
@@ -99,18 +111,20 @@ class RequestTrace:
 
 
 class _SpanContext:
+    """``with sink.span(name):`` — one span on the sink's ``clock``."""
+
     __slots__ = ("_trace", "_name", "_start")
 
-    def __init__(self, trace: RequestTrace, name: str):
+    def __init__(self, trace, name: str):
         self._trace = trace
         self._name = name
 
     def __enter__(self) -> "_SpanContext":
-        self._start = time.perf_counter()
+        self._start = self._trace.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self._trace.add(self._name, self._start, time.perf_counter())
+        self._trace.add(self._name, self._start, self._trace.clock())
 
 
 class TraceRecorder:
@@ -167,3 +181,249 @@ class TraceRecorder:
         with self._lock:
             items = sorted(self._heap, key=lambda t: (-t[0], -t[1]))
         return [rec for _, _, rec in items]
+
+
+# -- the trainer's spans ------------------------------------------------------
+
+# Host spans of a pretraining run, by where the work happens. The names are
+# the contract with the readers; docs/OBSERVABILITY.md says what each covers.
+TRAIN_SPANS = (
+    "boot/runtime", "boot/data", "boot/state", "boot/build_step", "boot/compile",
+    "epoch", "input_wait", "gather", "device_put", "dispatch", "beat",
+    "boundary", "boundary/loss_fetch", "boundary/telemetry", "boundary/log",
+    "boundary/event", "boundary/results", "boundary/probe", "boundary/shuffle",
+    "boundary/first_batch",
+    "checkpoint", "checkpoint/drain", "checkpoint/save", "checkpoint/digest",
+    "checkpoint/sidecar",
+    "preempt", "preempt/notice", "preempt/event", "preempt/beat", "preempt/flush",
+)
+# the ones a step records (about five a step): kept in the ring; every other
+# span is rare and kept apart, so that a long run cannot evict its boot
+STEP_SPANS = frozenset(("input_wait", "gather", "device_put", "dispatch", "beat"))
+
+# ``jax.named_scope`` names inside the step program (parallel/steps.py,
+# parallel/tp.py, models/contrastive.py). Backward ops carry the same name
+# inside ``transpose(jvp(...))``.
+DEVICE_SCOPES = ("augment", "encoder", "head", "ntxent", "grad_reduce", "lars")
+(
+    SCOPE_AUGMENT, SCOPE_ENCODER, SCOPE_HEAD, SCOPE_NTXENT, SCOPE_GRAD_REDUCE,
+    SCOPE_LARS,
+) = DEVICE_SCOPES
+
+SPANS_NAME = "spans.jsonl"
+
+# two minutes of the fastest cell (30 ms steps, five spans a step) is 20,000
+RING_CAPACITY = 32768
+# a 1000-epoch run that never flushes holds ~12,000 rare spans
+RARE_CAPACITY = 65536
+
+
+def spans_path(save_dir: str, process_index: int = 0) -> str:
+    """``spans.jsonl`` for process 0, ``spans.p<i>.jsonl`` beyond (the
+    heartbeat's naming)."""
+    if process_index:
+        return os.path.join(save_dir, f"spans.p{int(process_index)}.jsonl")
+    return os.path.join(save_dir, SPANS_NAME)
+
+
+class _RecordedSpan(_SpanContext):
+    """One span of the :class:`SpanRecorder`: besides its times it knows the
+    span that was open on its thread when it began, and the step and epoch it
+    serves. ``step``, ``epoch`` and ``n`` may be set while it is open; spans
+    begun under it inherit ``step`` and ``epoch``."""
+
+    __slots__ = ("id", "parent", "thread", "step", "epoch", "n")
+
+    def __init__(self, trace, name, step, epoch, n):
+        super().__init__(trace, name)
+        self.step, self.epoch, self.n = step, epoch, n
+
+    @property
+    def name(self) -> str:
+        return self._name
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._trace._begin(self)
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._trace._end(self, self._trace.clock())
+
+
+class SpanRecorder:
+    """Host spans of one process, in memory until :meth:`flush`.
+
+    A span is ``name, t0, t1`` in ``time.monotonic_ns()``, its ``parent`` (the
+    span open on the same thread when it began; 0 for none), the ``thread``,
+    and what ties the spans of one unit of work together: ``step`` (the
+    optimizer step a span serves, the same number on the prefetch thread and
+    on the main thread), ``epoch``, and an optional count ``n``. Recording is
+    two clock reads and one ``deque.append``; nothing is written, and no
+    device value is touched, until a flush.
+    """
+
+    clock = staticmethod(time.monotonic_ns)
+
+    def __init__(self, ring: int = RING_CAPACITY, rare: int = RARE_CAPACITY):
+        self._ring: collections.deque = collections.deque(maxlen=ring)
+        self._rare: collections.deque = collections.deque(maxlen=rare)
+        self._ids = itertools.count(1)
+        # open spans by thread ident, innermost last; an entry lives only
+        # while its thread has a span open
+        self._open: dict[int, list[_RecordedSpan]] = {}
+
+    # -- recording ------------------------------------------------------------
+    def span(self, name: str, *, step=None, epoch=None, n=None) -> _RecordedSpan:
+        """``with recorder().span("dispatch", step=k):`` records one span."""
+        return _RecordedSpan(self, name, step, epoch, n)
+
+    def begin(self, name: str, **ids) -> _RecordedSpan:
+        """Open a span that another block will :meth:`end` (a boundary runs
+        from the tail of one loop iteration into the head of the next)."""
+        return self.span(name, **ids).__enter__()
+
+    def end(self, span: _RecordedSpan | None) -> None:
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def add(self, name, t0, t1, *, parent=0, step=None, epoch=None, n=None) -> None:
+        """A span whose times were taken elsewhere (a signal handler's stamp)."""
+        self._keep((
+            next(self._ids), name, int(t0), int(t1), parent,
+            threading.current_thread().name, step, epoch, n,
+        ))
+
+    def current(self, thread_ident: int | None = None) -> _RecordedSpan | None:
+        """The innermost span open on a thread (the caller's by default)."""
+        stack = self._open.get(thread_ident or threading.get_ident())
+        try:
+            return stack[-1] if stack else None
+        except IndexError:  # popped by its own thread meanwhile
+            return None
+
+    def _begin(self, span: _RecordedSpan) -> None:
+        stack = self._open.setdefault(threading.get_ident(), [])
+        span.id = next(self._ids)
+        span.thread = threading.current_thread().name
+        if stack:
+            above = stack[-1]
+            span.parent = above.id
+            if span.step is None:
+                span.step = above.step
+            if span.epoch is None:
+                span.epoch = above.epoch
+        else:
+            span.parent = 0
+        stack.append(span)
+
+    def _end(self, span: _RecordedSpan, t1: int) -> None:
+        ident = threading.get_ident()
+        stack = self._open.get(ident)
+        if stack and span in stack:
+            # spans end innermost first; one left open by an exception that
+            # passed through it goes with the span that encloses it
+            del stack[stack.index(span):]
+            if not stack:
+                del self._open[ident]
+        self._keep(self._row(span, t1))
+
+    @staticmethod
+    def _row(span: _RecordedSpan, t1: int) -> tuple:
+        return (
+            span.id, span._name, span._start, t1, span.parent, span.thread,
+            span.step, span.epoch, span.n,
+        )
+
+    def _keep(self, row: tuple) -> None:
+        (self._ring if row[1] in STEP_SPANS else self._rare).append(row)
+
+    # -- reading and writing ----------------------------------------------------
+    def __len__(self) -> int:
+        return len(self._ring) + len(self._rare)
+
+    def drain(self) -> list[dict]:
+        """Every finished span since the last drain, oldest first, then the
+        spans still open (``"open": true``, ended now): what a crash or a
+        stall was sitting in."""
+        rows = []
+        for store in (self._rare, self._ring):
+            while True:
+                try:
+                    rows.append(store.popleft())
+                except IndexError:
+                    break
+        rows.sort(key=lambda r: r[2])
+        now = self.clock()
+        still_open = [
+            self._row(span, now)
+            for stack in list(self._open.values()) for span in list(stack)
+        ]
+        keys = ("id", "name", "t0", "t1", "parent", "thread", "step", "epoch", "n")
+
+        def as_dict(row: tuple, **more) -> dict:
+            return {**{k: v for k, v in zip(keys, row) if v is not None}, **more}
+
+        return [as_dict(r) for r in rows] + [as_dict(r, open=True) for r in still_open]
+
+    def flush(self, path: str, *, process_index: int = 0) -> int:
+        """Append a header and every span since the last flush to ``path``,
+        as one write; returns the number of spans written. The header is the
+        clock anchor: ``wall_ns`` and ``mono_ns`` read back to back, so a
+        span's Unix time is ``t + wall_ns - mono_ns``."""
+        spans = self.drain()
+        header = {
+            "header": SPANS_NAME, "wall_ns": time.time_ns(),
+            "mono_ns": time.monotonic_ns(), "pid": os.getpid(),
+            "process_index": int(process_index), "spans": len(spans),
+        }
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        atomic_append(
+            path, "".join(json.dumps(row) + "\n" for row in (header, *spans))
+        )
+        return len(spans)
+
+    def reset(self) -> None:
+        """Forget everything, open spans included: an entry point starts its
+        run with an empty recorder."""
+        self._ring.clear()
+        self._rare.clear()
+        self._open.clear()
+
+
+_RECORDER = SpanRecorder()
+
+
+def recorder() -> SpanRecorder:
+    """The process-wide recorder: ``utils/checkpoint.py`` and the prefetch
+    thread record into the same one as the host loop, with no argument
+    threaded through them."""
+    return _RECORDER
+
+
+def read_spans(path: str) -> list[dict]:
+    """The spans of a spans file, oldest first; ``[]`` when it is absent. A
+    span that was open at one flush and closed by a later one is in the file
+    twice under one ``id``: the later line wins. Each span carries the
+    ``pid`` and the clock anchor of its flush's header as ``wall_offset_ns``,
+    so its Unix time is ``t0 + wall_offset_ns``."""
+    spans: dict[tuple, dict] = {}
+    pid, offset = None, 0
+    try:
+        with open(path) as f:
+            for line in f:
+                try:
+                    row = json.loads(line)
+                except ValueError:
+                    continue  # a line torn by a kill
+                if not isinstance(row, dict):
+                    continue
+                if "header" in row:
+                    pid = row.get("pid")
+                    offset = int(row.get("wall_ns", 0)) - int(row.get("mono_ns", 0))
+                elif "id" in row:
+                    spans[(pid, row["id"])] = {
+                        **row, "pid": pid, "wall_offset_ns": offset,
+                    }
+    except OSError:
+        pass
+    return sorted(spans.values(), key=lambda s: s["t0"] + s["wall_offset_ns"])

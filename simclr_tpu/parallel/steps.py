@@ -40,6 +40,13 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from simclr_tpu.data.augment import simclr_augment_single, to_float
+from simclr_tpu.obs.trace import (
+    SCOPE_AUGMENT,
+    SCOPE_ENCODER,
+    SCOPE_GRAD_REDUCE,
+    SCOPE_LARS,
+    SCOPE_NTXENT,
+)
 from simclr_tpu.ops.augment_pallas import (
     fused_one_view,
     fused_two_views,
@@ -338,6 +345,20 @@ def _apply_concat(fwd, params, batch_stats, v0, v1):
     return z[:n], z[n:], mut["batch_stats"]
 
 
+def _ntxent(z0, z1, negatives: str, fused: bool, temperature: float):
+    """The configured NT-Xent variant on this data shard's embeddings (the
+    one dispatch the dp and tp steps share)."""
+    if fused and negatives == "global":
+        return ntxent_loss_fused_sharded(z0, z1, DATA_AXIS, temperature)
+    if fused:  # local negatives, per-shard fused kernel
+        return jax.lax.pmean(ntxent_loss_fused(z0, z1, temperature), DATA_AXIS)
+    if negatives == "global":
+        return ntxent_loss_sharded_rows(z0, z1, DATA_AXIS, temperature)
+    if negatives == "ring":
+        return ntxent_loss_ring(z0, z1, DATA_AXIS, temperature)
+    return ntxent_loss_local_negatives(z0, z1, DATA_AXIS, temperature)
+
+
 def _make_local_pretrain_step(
     model,
     tx: optax.GradientTransformation,
@@ -393,26 +414,20 @@ def _make_local_pretrain_step(
         # augmentation keys are global-batch-position-indexed (layout
         # invariant across an elastic remesh); the quantization stream
         # below stays per-shard via the shard-folded rng
-        keys = _global_sample_keys(rng, images.shape[0], views=2)
-        rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
-        v0, v1 = _augment_two_views(
-            rng, images, strength, out_size, augment_impl, keys=keys
-        )
+        with jax.named_scope(SCOPE_AUGMENT):
+            keys = _global_sample_keys(rng, images.shape[0], views=2)
+            rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
+            v0, v1 = _augment_two_views(
+                rng, images, strength, out_size, augment_impl, keys=keys
+            )
 
         def loss_fn(params):
-            z0, z1, new_stats = apply_views(forward, params, state.batch_stats, v0, v1)
-            if fused and negatives == "global":
-                loss = ntxent_loss_fused_sharded(z0, z1, DATA_AXIS, temperature)
-            elif fused:  # local negatives, per-shard fused kernel
-                loss = jax.lax.pmean(
-                    ntxent_loss_fused(z0, z1, temperature), DATA_AXIS
+            with jax.named_scope(SCOPE_ENCODER):
+                z0, z1, new_stats = apply_views(
+                    forward, params, state.batch_stats, v0, v1
                 )
-            elif negatives == "global":
-                loss = ntxent_loss_sharded_rows(z0, z1, DATA_AXIS, temperature)
-            elif negatives == "ring":
-                loss = ntxent_loss_ring(z0, z1, DATA_AXIS, temperature)
-            else:
-                loss = ntxent_loss_local_negatives(z0, z1, DATA_AXIS, temperature)
+            with jax.named_scope(SCOPE_NTXENT):
+                loss = _ntxent(z0, z1, negatives, fused, temperature)
             return loss, new_stats
 
         if comm_overlap == "async":
@@ -427,13 +442,15 @@ def _make_local_pretrain_step(
             (loss, new_stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         # the quantization stream forks off the same per-step, per-data-shard
         # rng the augmentations use (fold_in is the jax stream-split idiom)
-        grads = compress.grad_allreduce(
-            grads, DATA_AXIS, grad_allreduce,
-            key=jax.random.fold_in(rng, compress.KEY_FOLD_QUANT),
-            overlap=comm_overlap, chunks=comm_chunks,
-        )
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(SCOPE_GRAD_REDUCE):
+            grads = compress.grad_allreduce(
+                grads, DATA_AXIS, grad_allreduce,
+                key=jax.random.fold_in(rng, compress.KEY_FOLD_QUANT),
+                overlap=comm_overlap, chunks=comm_chunks,
+            )
+        with jax.named_scope(SCOPE_LARS):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1, params=params, batch_stats=new_stats, opt_state=new_opt
         )

@@ -47,16 +47,14 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from simclr_tpu.models.resnet import feature_dim
+from simclr_tpu.obs.trace import (
+    SCOPE_AUGMENT,
+    SCOPE_ENCODER,
+    SCOPE_GRAD_REDUCE,
+    SCOPE_LARS,
+    SCOPE_NTXENT,
+)
 from simclr_tpu.ops.augment_pallas import validate_impl as validate_augment_impl
-from simclr_tpu.ops.ntxent import (
-    ntxent_loss_local_negatives,
-    ntxent_loss_sharded_rows,
-)
-from simclr_tpu.ops.ntxent_pallas import (
-    ntxent_loss_fused,
-    ntxent_loss_fused_sharded,
-)
-from simclr_tpu.ops.ntxent_ring import ntxent_loss_ring
 from simclr_tpu.parallel import compress
 from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from simclr_tpu.parallel.steps import (
@@ -65,6 +63,7 @@ from simclr_tpu.parallel.steps import (
     _forward_fn,
     _global_sample_keys,
     _local_resident_block,
+    _ntxent,
     _sharded_rows_global_batch,
 )
 from simclr_tpu.parallel.train_state import TrainState
@@ -186,27 +185,19 @@ def _make_step_body(
         # global-batch-position-indexed, so model-axis replicas agree and
         # the draw survives an elastic remesh; the quant stream below stays
         # per-data-shard via the shard-folded rng
-        keys = _global_sample_keys(rng, images.shape[0], views=2)
-        rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
-        v0, v1 = _augment_two_views(
-            rng, images, strength, out_size, augment_impl, keys=keys
-        )
+        with jax.named_scope(SCOPE_AUGMENT):
+            keys = _global_sample_keys(rng, images.shape[0], views=2)
+            rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
+            v0, v1 = _augment_two_views(
+                rng, images, strength, out_size, augment_impl, keys=keys
+            )
 
         def loss_fn(p):
-            z0, mut = fwd(p, batch_stats, v0)
-            z1, mut = fwd(p, mut["batch_stats"], v1)
-            if fused and negatives == "global":
-                loss = ntxent_loss_fused_sharded(z0, z1, DATA_AXIS, temperature)
-            elif fused:  # local negatives, per-shard fused kernel
-                loss = jax.lax.pmean(
-                    ntxent_loss_fused(z0, z1, temperature), DATA_AXIS
-                )
-            elif negatives == "global":
-                loss = ntxent_loss_sharded_rows(z0, z1, DATA_AXIS, temperature)
-            elif negatives == "ring":
-                loss = ntxent_loss_ring(z0, z1, DATA_AXIS, temperature)
-            else:
-                loss = ntxent_loss_local_negatives(z0, z1, DATA_AXIS, temperature)
+            with jax.named_scope(SCOPE_ENCODER):
+                z0, mut = fwd(p, batch_stats, v0)
+                z1, mut = fwd(p, mut["batch_stats"], v1)
+            with jax.named_scope(SCOPE_NTXENT):
+                loss = _ntxent(z0, z1, negatives, fused, temperature)
             return loss, mut["batch_stats"]
 
         if comm_overlap == "async":
@@ -219,11 +210,12 @@ def _make_step_body(
             (loss, new_stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         # same convention as steps.py: sum over the data axis (compressed
         # per grad_allreduce), BEFORE the jit-level LARS update below
-        grads = compress.grad_allreduce(
-            grads, DATA_AXIS, grad_allreduce,
-            key=jax.random.fold_in(rng, compress.KEY_FOLD_QUANT),
-            overlap=comm_overlap, chunks=comm_chunks,
-        )
+        with jax.named_scope(SCOPE_GRAD_REDUCE):
+            grads = compress.grad_allreduce(
+                grads, DATA_AXIS, grad_allreduce,
+                key=jax.random.fold_in(rng, compress.KEY_FOLD_QUANT),
+                overlap=comm_overlap, chunks=comm_chunks,
+            )
         # No model-axis correction here: the head's f/g boundary operators
         # (models/heads.py) own the model-axis collectives in both forward
         # and backward, so encoder grads arrive complete and replica-
@@ -243,8 +235,9 @@ def _make_step_body(
         )
         loss, grads, new_stats = sharded(state.params, state.batch_stats, images, rng)
         # jit-level (GSPMD) optimizer update: norms over the GLOBAL arrays
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(SCOPE_LARS):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1,
             params=params,

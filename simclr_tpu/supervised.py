@@ -183,12 +183,9 @@ def run_supervised(cfg: Config) -> dict:
     save_dir = resolve_save_dir(cfg)
     # run telemetry + event timeline (simclr_tpu/obs/, docs/OBSERVABILITY.md),
     # constructed BEFORE the step builders so the compile sentry can watch
-    # them. arch=None: the roofline FLOP model covers the pretrain step only,
-    # so the supervised MFU gauge honestly reads 0.
+    # them
     n_hosts = mesh_host_count(mesh)
     telemetry = Telemetry(
-        arch=None,
-        per_device_batch=int(cfg.experiment.batches),
         global_batch=global_batch,
         n_devices=jax.device_count(),
         mesh_hosts=n_hosts,

@@ -25,6 +25,7 @@ from __future__ import annotations
 import math
 import signal
 import threading
+import time
 
 from simclr_tpu.supervisor.faults import FaultPlan
 from simclr_tpu.supervisor.heartbeat import (
@@ -108,6 +109,9 @@ class RunGuard:
         self.telemetry = telemetry
         self.events = events
         self._preempt = threading.Event()
+        # time.monotonic_ns() at the first SIGTERM/SIGINT: where the host
+        # loop's `preempt/notice` span begins (obs/trace.py)
+        self.preempt_ns: int | None = None
         self._previous_handlers: dict[int, object] = {}
         self._beats = True
 
@@ -123,6 +127,7 @@ class RunGuard:
         # handler does the minimum: the host loop owns the checkpoint save
         # (a save from inside a handler could re-enter orbax mid-write)
         if not self._preempt.is_set():
+            self.preempt_ns = time.monotonic_ns()
             self._preempt.set()
             logger.info(
                 "signal %d: checkpoint at the next step boundary, then exit %d",

@@ -25,6 +25,8 @@ import jax
 import numpy as np
 import orbax.checkpoint as ocp
 
+from simclr_tpu.obs.trace import recorder
+
 _EPOCH_RE = re.compile(r"epoch=(\d+)-")
 
 # integrity sidecar written next to each checkpoint directory: sha256 over
@@ -135,16 +137,20 @@ def save_checkpoint(path: str, state) -> None:
     loudly at load instead of silently serving garbage embeddings.
     """
     path = os.path.abspath(path)
-    with ocp.StandardCheckpointer() as ckptr:
-        ckptr.save(path, state, force=True)
+    spans = recorder()
+    with spans.span("checkpoint/save"):  # to the commit: the close waits for it
+        with ocp.StandardCheckpointer() as ckptr:
+            ckptr.save(path, state, force=True)
     if jax.process_index() == 0:
         from simclr_tpu.utils.ioutil import atomic_write
 
-        digest = checkpoint_digest(path)
-        atomic_write(
-            digest_path(path),
-            lambda f: f.write(f"{digest}  {os.path.basename(path)}\n"),
-        )
+        with spans.span("checkpoint/digest"):
+            digest = checkpoint_digest(path)
+        with spans.span("checkpoint/sidecar"):
+            atomic_write(
+                digest_path(path),
+                lambda f: f.write(f"{digest}  {os.path.basename(path)}\n"),
+            )
 
 
 class CheckpointCorruptionError(ValueError):

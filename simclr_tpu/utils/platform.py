@@ -41,6 +41,13 @@ def configure_compilation_cache() -> str:
     if not _listening:
         jax.monitoring.register_event_listener(_count_event)
         _listening = True
+    # The key covers a program's metadata too. JAX leaves it out by default,
+    # and an executable compiled from another version of the step (other
+    # named scopes, or none) would be served for this one with ITS op_names:
+    # obs/compile.py reads the scope map of the step from the executable's
+    # text, and a trace reader would set every op to the wrong scope, or to
+    # none (seen on the CPU: a cache entry from before the scopes existed).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get(CACHE_DIR_ENV, "").strip()
     if from_env:
         return from_env

@@ -29,6 +29,21 @@ def start_server(port: int = 9999):
     return jax.profiler.start_server(port)
 
 
+def device_trace_options():
+    """Profiler options for a capture of the device planes only, as the
+    benchmark's capture takes it. With JAX's defaults (Python and host
+    tracers on) a 3 s capture of the training loop was 70-140 MB and blocked
+    its caller for one to two minutes while it was written out (one host
+    event per image row in ``device_put``); with both off, 13 MB and a few
+    seconds (chip runs, PR 24, root PERF.md). What the host did meanwhile is
+    in ``spans.jsonl`` (``obs/trace.py``), on a clock the trace's
+    ``profile_start_time`` ties to."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0
+    return options
+
+
 @contextlib.contextmanager
 def trace(log_dir: str):
     """Capture a profiler trace of the enclosed region into ``log_dir``."""
@@ -60,7 +75,9 @@ def capture_trace(log_dir: str, seconds: float) -> None:
         raise TraceInProgressError("a profiler capture is already in progress")
     try:
         try:
-            jax.profiler.start_trace(str(log_dir))
+            jax.profiler.start_trace(
+                str(log_dir), profiler_options=device_trace_options()
+            )
         except Exception as e:  # e.g. a StepTraceWindow already tracing
             raise TraceInProgressError(str(e)) from e
         try:
@@ -90,7 +107,9 @@ class StepTraceWindow:
         if not self.enabled:
             return
         if not self._active and step == self.start:
-            jax.profiler.start_trace(str(self.log_dir))
+            jax.profiler.start_trace(
+                str(self.log_dir), profiler_options=device_trace_options()
+            )
             self._active = True
         elif self._active and step >= self.stop_at:
             synchronize(pending)

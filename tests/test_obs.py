@@ -7,7 +7,7 @@ Covers the four tentpole layers plus their contracts:
   primitive classes and render ``/metrics`` byte-identically to the
   pre-refactor implementation (golden generated from that implementation);
 * the :class:`Telemetry` registry — throughput/MFU/wire-bytes math against
-  the roofline and compress models it reuses, snapshot shape;
+  XLA's step cost and the compress model it reuses, snapshot shape;
 * the ``events.jsonl`` timeline — atomic appends, attempt tagging, torn-line
   tolerance, and the resume re-seat discipline;
 * the HTTP exporter — scrape/healthz/trace endpoints, port semantics;
@@ -289,22 +289,31 @@ class TestTelemetry:
         from simclr_tpu.obs.peaks import CHIP_PEAKS, V5E
 
         base = dict(
-            arch="resnet18", per_device_batch=8, global_batch=64, n_devices=8,
+            global_batch=64, n_devices=8,
             peak_flops=CHIP_PEAKS[V5E].bf16_flops_per_s,
         )
         base.update(kw)
-        return Telemetry(**base)
+        flops = base.pop("step_flops", 5.86e9 * 8)
+        t = Telemetry(**base)
+        if flops:
+            # what the compile sentry reports for the step program
+            t.observe_xla_cost("pretrain_step", flops_per_step=flops)
+        return t
 
-    def test_flops_match_roofline_model(self):
-        import importlib.util
+    def test_flops_are_xlas_count_of_the_newest_step_program(self):
+        """The MFU gauge divides the compiler's own FLOPs per device-step,
+        as the sentry normalises them (an epoch program's cost over its
+        steps), never a model's."""
+        from simclr_tpu.obs.compile import CompileSentry
 
-        spec = importlib.util.spec_from_file_location(
-            "roofline", os.path.join(REPO_ROOT, "scripts", "roofline_model.py")
-        )
-        roofline = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(roofline)
-        expected = sum(op[1] for op in roofline.model_step("resnet18", 8, d=128))
-        assert self._make().flops_per_step == pytest.approx(expected)
+        t = self._make(step_flops=None)
+        assert t.flops_per_step is None
+        sentry = CompileSentry(telemetry=t)
+        sentry.record_compile("pretrain_epoch", seconds=1.0, flops=24e9, steps_per_call=24)
+        assert t.flops_per_step == pytest.approx(1e9)
+        sentry.record_compile("pretrain_step", seconds=1.0, flops=3e9)
+        assert t.flops_per_step == pytest.approx(3e9)
+        assert "mfu_roofline_xla_drift" not in t.render()
 
     def test_observe_epoch_sets_rates_and_mfu(self):
         t = self._make()
@@ -321,8 +330,8 @@ class TestTelemetry:
         )
         assert t.step_time.count == 1
 
-    def test_no_arch_means_honest_zero_mfu(self):
-        t = self._make(arch=None)
+    def test_no_step_program_means_honest_zero_mfu(self):
+        t = self._make(step_flops=None)
         assert t.flops_per_step is None
         t.observe_epoch(1, epochs=2, step=2, steps=2, seconds=1.0, loss=1.0, lr=0.1)
         assert t.mfu.value == 0.0
@@ -334,11 +343,16 @@ class TestTelemetry:
         t = self._make(peak_flops=None)
         assert t.flops_per_step
         t.observe_epoch(1, epochs=2, step=2, steps=2, seconds=1.0, loss=1.0, lr=0.1)
-        assert t.mfu.value == 0.0 and t.exposed_comm_ms.value == 0.0
+        assert t.mfu.value == 0.0
+        assert "exposed_comm" not in t.render()
         assert t.imgs_per_sec.value > 0
 
-    def test_unknown_arch_degrades_to_none(self):
-        assert self._make(arch="not-a-model").flops_per_step is None
+    def test_a_costless_executable_leaves_the_flops_alone(self):
+        """A backend whose cost analysis reports no flops (0.0) must not
+        zero the gauge's numerator."""
+        t = self._make(step_flops=2e9)
+        t.observe_xla_cost("pretrain_step", flops_per_step=0.0)
+        assert t.flops_per_step == pytest.approx(2e9)
 
     def test_wire_bytes_match_compress_model(self):
         from simclr_tpu.parallel.compress import allreduce_wire_bytes
@@ -359,7 +373,7 @@ class TestTelemetry:
         snap = t.snapshot()
         assert set(snap) == {
             "epoch", "step", "loss", "lr", "step_time_s", "imgs_per_sec",
-            "imgs_per_sec_per_chip", "mfu", "exposed_comm_ms", "slow_steps",
+            "imgs_per_sec_per_chip", "mfu", "slow_steps",
             "stalls", "auto_traces", "compiles", "recompile_alarms",
             "uptime_s", "mesh_hosts",
         }
@@ -563,6 +577,14 @@ class _AnomalyCounters:
         self.trace += 1
 
 
+class _StepTime(_AnomalyCounters):
+    """A telemetry that knows the last epoch's mean step time."""
+
+    def __init__(self, step_time_s):
+        super().__init__()
+        self.step_time_s = step_time_s
+
+
 def _fake_clock(start=100.0):
     state = {"t": start}
     return state, (lambda: state["t"])
@@ -586,8 +608,10 @@ class TestAnomalyDetector:
         state, clock = _fake_clock()
         events = EventLog(str(tmp_path))
         telem = _AnomalyCounters()
+        # a window the steady ticks fill: a tick that stands out before the
+        # window has filled once is the end of the run-ahead burst (below)
         det = StepAnomalyDetector(
-            str(tmp_path), warmup=4, events=events, telemetry=telem,
+            str(tmp_path), warmup=4, window=8, events=events, telemetry=telem,
             clock=clock,
         )
         try:
@@ -607,6 +631,77 @@ class TestAnomalyDetector:
         assert slow["seconds"] == pytest.approx(1.0)
         assert slow["median_s"] == pytest.approx(0.1)
         assert slow["threshold_s"] < 1.0
+
+    def test_run_ahead_burst_does_not_seed_the_window(self, tmp_path):
+        """On the chip the host dispatches 11 steps ahead in ~5 ms each, then
+        every dispatch blocks for a device step (~126 ms). The median of the
+        burst called every steady step slow: ~20 events in a healthy run."""
+        state, clock = _fake_clock()
+        events = EventLog(str(tmp_path))
+        det = StepAnomalyDetector(str(tmp_path), events=events, clock=clock)
+        try:
+            for i in range(11):
+                state["t"] += 0.005
+                assert det.tick(i, epoch=1) is None
+            for i in range(11, 61):
+                state["t"] += 0.126
+                assert det.tick(i, epoch=1) is None
+            assert det.slow_steps == 0
+            assert min(det._samples) > 0.1  # seeded from blocked dispatches only
+            # an epoch boundary's sync, then the next burst: the epoch's mean
+            # step time is known now, and ticks far under it are dispatches
+            det.pause()
+            det.telemetry = _StepTime(0.127)
+            for i in range(61, 72):
+                state["t"] += 0.005
+                assert det.tick(i, epoch=2) is None
+            assert min(det._samples) > 0.1  # dispatches are not steps
+            for i in range(72, 85):
+                state["t"] += 0.126
+                assert det.tick(i, epoch=2) is None
+            # and a step that really is slow still alarms
+            state["t"] += 1.0
+            assert det.tick(85, epoch=2) == "slow_step"
+        finally:
+            det.close()
+        assert [
+            e["step"] for e in read_events(events.path)
+            if e["event"] == "slow_step"
+        ] == [85]
+
+    def test_an_epoch_shorter_than_the_burst_never_alarms(self, tmp_path):
+        """Four chips, 6-step epochs: the host dispatches a whole epoch in
+        ~50 ms and waits 0.8 s in the boundary's loss fetch. Every tick is a
+        dispatch of 8-15 ms; their jitter read as 14 slow steps in one run on
+        the chip. The epoch's mean step time (140 ms) says none is a step."""
+        state, clock = _fake_clock()
+        events = EventLog(str(tmp_path))
+        det = StepAnomalyDetector(
+            str(tmp_path), events=events, clock=clock, stall_min_s=1e6
+        )
+        jitter = (0.0085, 0.0083, 0.0117, 0.0086, 0.0155, 0.0469)
+        try:
+            for epoch in range(1, 40):
+                for k in range(6):
+                    state["t"] += jitter[(epoch + k) % 6]
+                    assert det.tick(6 * (epoch - 1) + k + 1, epoch) is None
+                det.pause()
+                state["t"] += 0.8  # the loss fetch: the device's six steps
+                det.telemetry = _StepTime(0.140)
+            assert det.slow_steps == 0
+            # dispatches are not samples: the first epoch's five, taken
+            # before any epoch's mean was known, and the one of 47 ms an epoch
+            assert all(dt > 0.035 for dt in list(det._samples)[5:])
+            # a dispatch that hangs for seconds is still a step's worth
+            det.tick(998, 40)  # the first tick after a pause only anchors
+            state["t"] += 3.0
+            det.tick(999, 40)
+            assert det._samples[-1] == pytest.approx(3.0)
+        finally:
+            det.close()
+        assert not [
+            e for e in read_events(events.path) if e["event"] == "slow_step"
+        ]
 
     def test_warmup_grace_swallows_early_outliers(self, tmp_path):
         # fewer than `warmup` samples (e.g. right after a compile) must never
@@ -670,9 +765,9 @@ class TestAnomalyDetector:
             raise RuntimeError("profiler busy")
 
         det = StepAnomalyDetector(
-            str(tmp_path), warmup=2, auto_trace=True, auto_trace_max=1,
-            auto_trace_cooldown_s=0.0, capture_fn=failing_capture,
-            clock=clock,
+            str(tmp_path), warmup=2, window=4, auto_trace=True,
+            auto_trace_max=1, auto_trace_cooldown_s=0.0,
+            capture_fn=failing_capture, clock=clock,
         )
         try:
             for i in range(6):
@@ -1082,6 +1177,40 @@ SYNTH = [
 ]
 
 
+class _NoSpans:
+    """The span recorder's surface with nothing behind it: the baseline of
+    the zero-sync proof runs the same host loop and records no span."""
+
+    class _Span:
+        id, name, step, epoch, n = 0, None, None, None, None
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    def span(self, name, **ids):
+        return self._Span()
+
+    begin = span
+
+    def end(self, span):
+        pass
+
+    def add(self, *args, **ids):
+        pass
+
+    def current(self, thread_ident=None):
+        return None
+
+    def flush(self, path, **kw):
+        return 0
+
+    def reset(self):
+        pass
+
+
 def _run_pretrain_counting_syncs(overrides, monkeypatch, scrape=None):
     """Run a tiny in-process pretrain with ``utils.profiling.synchronize``
     wrapped by a counter; optionally run ``scrape(ready_path)`` concurrently
@@ -1129,9 +1258,18 @@ class TestEndToEnd:
         # median/MAD classification path actually runs inside this short
         # run, so the zero-sync proof covers the detector too
         base = SYNTH + ["parameter.epochs=2", "telemetry.anomaly_warmup=2"]
-        _, baseline_syncs = _run_pretrain_counting_syncs(
-            base + [f"experiment.save_dir={tmp_path / 'plain'}"], monkeypatch
-        )
+        # ... and the span recorder, which is always on: the baseline runs
+        # with no recorder behind the spans, the observed run with the real
+        # one, so the equal counts below cover every span around a fence
+        # (boundary/loss_fetch, checkpoint/drain) as well
+        from simclr_tpu.obs import trace as obs_trace
+
+        with monkeypatch.context() as m:
+            m.setattr(obs_trace, "_RECORDER", _NoSpans())
+            _, baseline_syncs = _run_pretrain_counting_syncs(
+                base + [f"experiment.save_dir={tmp_path / 'plain'}"], m
+            )
+        assert not os.path.exists(obs_trace.spans_path(str(tmp_path / "plain")))
 
         obs_dir = tmp_path / "observed"
         ready = obs_dir / "ready.json"
@@ -1171,6 +1309,11 @@ class TestEndToEnd:
         assert scrapes[0] > 0, "no scrape actually landed mid-run"
         assert observed_syncs == baseline_syncs
         assert summary["complete"] is True
+        recorded = {s["name"] for s in obs_trace.read_spans(
+            obs_trace.spans_path(str(obs_dir))
+        )}
+        assert {"dispatch", "boundary/loss_fetch", "checkpoint/drain",
+                "checkpoint/save", "input_wait", "device_put"} <= recorded
 
         # the same run also wrote a coherent single-attempt timeline
         events = read_events(events_path(str(obs_dir)))

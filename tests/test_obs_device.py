@@ -69,9 +69,7 @@ class _FakeDevice:
 
 
 def _make_telemetry():
-    return Telemetry(
-        arch=None, per_device_batch=4, global_batch=4, n_devices=1,
-    )
+    return Telemetry(global_batch=4, n_devices=1)
 
 
 # ---------------------------------------------------------------------------
@@ -118,21 +116,32 @@ class TestDeviceMonitor:
         assert "device=" not in text
 
     def test_per_device_gauges_and_watermark(self):
+        """The peak is arrays plus the runtime's reservation for program
+        temporaries (on the v5e the second is 20x the first): what
+        ``benchmarks/runners/train.py::memory_peak_bytes`` reports."""
         monitor = DeviceMonitor(
             devices=[
                 _FakeDevice(0, {"bytes_in_use": 100, "peak_bytes_in_use": 150,
-                                "bytes_limit": 1000}),
+                                "bytes_reserved": 3000,
+                                "peak_bytes_reserved": 4000,
+                                "bytes_limit": 10000}),
                 _FakeDevice(1, {"bytes_in_use": 200, "peak_bytes_in_use": 250,
-                                "bytes_limit": 1000}),
+                                "bytes_reserved": 3000,
+                                "peak_bytes_reserved": 5000,
+                                "bytes_limit": 10000}),
+                # a backend without the reserved keys: the allocator's peak
+                _FakeDevice(2, {"bytes_in_use": 10, "peak_bytes_in_use": 20}),
             ]
         )
         text = monitor.render()
         assert 'simclr_train_hbm_bytes_in_use{device="0"} 100' in text
         assert 'simclr_train_hbm_bytes_in_use{device="1"} 200' in text
-        assert 'simclr_train_hbm_peak_bytes{device="1"} 250' in text
-        assert 'simclr_train_hbm_bytes_limit{device="0"} 1000' in text
-        assert monitor.high_watermark_bytes == 250
-        assert "simclr_train_hbm_high_watermark_bytes 250" in text
+        assert 'simclr_train_hbm_peak_bytes{device="0"} 4150' in text
+        assert 'simclr_train_hbm_peak_bytes{device="1"} 5250' in text
+        assert 'simclr_train_hbm_peak_bytes{device="2"} 20' in text
+        assert 'simclr_train_hbm_bytes_limit{device="0"} 10000' in text
+        assert monitor.high_watermark_bytes == 5250
+        assert "simclr_train_hbm_high_watermark_bytes 5250" in text
 
     def test_partial_stats_render_partial_gauges(self):
         """A backend reporting only bytes_in_use must yield only that gauge

@@ -26,17 +26,40 @@ def config_updates(monkeypatch):
     return calls
 
 
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 class TestCompilationCachePlacement:
-    def test_env_set_leaves_the_config_alone(self, monkeypatch, config_updates):
+    def test_env_set_leaves_the_directory_alone(self, monkeypatch, config_updates):
         monkeypatch.setenv(platform.CACHE_DIR_ENV, "/somewhere/outside")
         assert platform.configure_compilation_cache() == "/somewhere/outside"
-        assert config_updates == []
+        assert config_updates == [METADATA_IN_KEY]
 
     def test_unset_places_it_in_the_checkout(self, monkeypatch, config_updates):
         monkeypatch.delenv(platform.CACHE_DIR_ENV, raising=False)
         expected = os.path.join(REPO_ROOT, ".jax_cache")
         assert platform.configure_compilation_cache() == expected
-        assert config_updates == [("jax_compilation_cache_dir", expected)]
+        assert config_updates == [
+            METADATA_IN_KEY, ("jax_compilation_cache_dir", expected)
+        ]
+
+    def test_the_key_covers_metadata_so_scopes_are_the_programs_own(self):
+        """Two programs that differ in their ``jax.named_scope`` names alone
+        lower to the same text once metadata is stripped, which is all JAX's
+        default cache key sees: the second would be handed the first's
+        executable, op_names and all, and ``step_scopes.json`` (read from the
+        executable's text) would name another program's scopes."""
+        import jax.numpy as jnp
+
+        def step(scope):
+            def f(x):
+                with jax.named_scope(scope):
+                    return jnp.tanh(x) * 2.0
+            return jax.jit(f).lower(jnp.ones((8,)))
+
+        a, b = step("encoder"), step("ContrastiveModel")
+        assert a.as_text() == b.as_text()
+        assert a.as_text(debug_info=True) != b.as_text(debug_info=True)
 
     def test_same_directory_from_two_processes(self, tmp_path):
         """A directory that moves never hits: two processes started from

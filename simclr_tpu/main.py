@@ -110,6 +110,19 @@ def build_model(cfg: Config) -> ContrastiveModel:
     )
 
 
+def leads_over_boundary(
+    epoch: int, *, epochs: int, save_model_epoch: int, eval_every: int
+) -> bool:
+    """Whether the per-step loop may queue the next epoch's first step before
+    it fetches ``epoch``'s loss, so that the device runs that step while the
+    host does the boundary's work. Not where the boundary has to see the state
+    as the epoch left it (the step donates it): a checkpoint or a probe is
+    due, or no epoch follows."""
+    if epoch >= epochs or epoch % save_model_epoch == 0:
+        return False
+    return not (eval_every > 0 and epoch % eval_every == 0)
+
+
 def run_pretrain(cfg: Config) -> dict:
     """Train; returns a summary dict (final loss, steps, save_dir)."""
     check_pretrain_conf(cfg)
@@ -756,8 +769,12 @@ def run_pretrain(cfg: Config) -> dict:
     )
     spans_file = spans_path(save_dir, jax.process_index())
     # the open `boundary` span: from the step loop's exit to the next
-    # epoch's first batch in hand
+    # epoch's first batch in hand, or to the results file where that batch
+    # and its step went out before the boundary opened
     boundary_span = None
+    # the per-step loop's one-step lead over a boundary: the next epoch's
+    # batches, first step dispatched, with its start step and start time
+    led = None
 
     def flush_spans() -> None:
         try:
@@ -786,6 +803,39 @@ def run_pretrain(cfg: Config) -> dict:
         spans.end(build_span)
         build_span = None
 
+    def open_epoch(at_epoch: int, skip: int = 0):
+        """The epoch's prefetched batches and the first of them (``None`` if
+        it has none), waited for in ``boundary/first_batch``."""
+        batches = iterator.batches(at_epoch)
+        if skip:
+            # mid-epoch resume: replay the epoch's deterministic batch order
+            # past the consumed prefix; step RNG folds on the absolute
+            # cur_step, so the continuation is exact
+            batches = itertools.islice(batches, skip, None)
+        batches = prefetch(batches)
+        with spans.span("boundary/first_batch", epoch=at_epoch):
+            return batches, next(batches, None)
+
+    def train_step(batch, at_epoch: int) -> None:
+        """One step of the per-step loop: dispatch, tick, beat."""
+        nonlocal state, metrics, cur_step
+        tracer.tick(cur_step, pending=metrics["loss"])
+        # the key's fold_in is a device program too, and the first of the
+        # two to meet the run-ahead limit: in steady state this span is the
+        # wait for a free slot
+        with spans.span("dispatch"):
+            step_rng = jax.random.fold_in(base_key, cur_step)
+            state, metrics = step_fn(state, batch["image"], step_rng)
+        timer.tick(metrics["loss"])
+        cur_step += 1
+        if detector is not None:
+            # BEFORE the beat: the beat is where fault injection wedges, and
+            # the watchdog must already be armed to catch exactly that class
+            # of hang
+            detector.tick(cur_step, at_epoch)
+        with spans.span("beat"):
+            guard.beat(cur_step, at_epoch)
+
     def leave_preempted(path: str, at_epoch: int):
         """The tail of a preemption, inside its ``preempt`` span: event,
         last beat, the recorder's flush (inside ``preempt_s``, so timed)."""
@@ -803,8 +853,10 @@ def run_pretrain(cfg: Config) -> dict:
     try:
         epoch = start_epoch
         while epoch <= epochs:
-            epoch_start_step = cur_step
-            epoch_t0 = time.perf_counter()
+            if led is None:
+                epoch_start_step, epoch_t0 = cur_step, time.perf_counter()
+            else:
+                _, epoch_start_step, epoch_t0 = led
             # full-K superepoch chunk: one compiled call runs K epochs (and
             # their probes) on device; the host only syncs here, at the
             # boundary, to fetch the stacked per-epoch metrics. The tail
@@ -1013,40 +1065,25 @@ def run_pretrain(cfg: Config) -> dict:
                         # is whatever the host loop's unit of progress is
                         detector.tick(cur_step, epoch)
             else:
-                batches = iterator.batches(epoch)
-                if skip_steps:
-                    # mid-epoch resume: replay the epoch's deterministic
-                    # batch order past the consumed prefix; step RNG folds on
-                    # the absolute cur_step, so the continuation is exact
-                    batches = itertools.islice(batches, skip_steps, None)
+                if led is None:
+                    batches, batch = open_epoch(epoch, skip_steps)
                     skip_steps = 0
-                batches = prefetch(batches)
-                with spans.span("boundary/first_batch", epoch=epoch):
-                    batch = next(batches, None)
-                end_boundary()
-                if batch is not None:
-                    first_dispatch_next(
-                        step_fn, state, batch["image"],
-                        jax.random.fold_in(base_key, cur_step),
-                    )
+                    end_boundary()
+                    if batch is not None:
+                        first_dispatch_next(
+                            step_fn, state, batch["image"],
+                            jax.random.fold_in(base_key, cur_step),
+                        )
+                else:
+                    # the epoch's first step went out before the last
+                    # boundary. A preemption seen since (in that step's beat,
+                    # or while the host sat in the fetch) checkpoints behind
+                    # that one step, below, before a second is dispatched
+                    (batches, _, _), led = led, None
+                    batch = None if guard.preempt_requested else next(batches, None)
                 with spans.span("epoch", epoch=epoch, step=cur_step + 1) as epoch_span:
                     while batch is not None:
-                        tracer.tick(cur_step, pending=metrics["loss"])
-                        # the key's fold_in is a device program too, and the
-                        # first of the two to meet the run-ahead limit: in
-                        # steady state this span is the wait for a free slot
-                        with spans.span("dispatch"):
-                            step_rng = jax.random.fold_in(base_key, cur_step)
-                            state, metrics = step_fn(state, batch["image"], step_rng)
-                        timer.tick(metrics["loss"])
-                        cur_step += 1
-                        if detector is not None:
-                            # BEFORE the beat: the beat is where fault
-                            # injection wedges, and the watchdog must already
-                            # be armed to catch exactly that class of hang
-                            detector.tick(cur_step, epoch)
-                        with spans.span("beat"):
-                            guard.beat(cur_step, epoch)
+                        train_step(batch, epoch)
                         if guard.preempt_requested:
                             break
                         epoch_span.step = cur_step + 1  # what the next spans serve
@@ -1102,25 +1139,59 @@ def run_pretrain(cfg: Config) -> dict:
                         timed_save(path)
                     leave_preempted(path, epoch)
 
-            boundary_span = spans.begin("boundary", epoch=epoch, step=cur_step)
+            # the epoch's end, as the boundary below reports it; cur_step and
+            # metrics move on by one where the boundary is led
+            end_step, last_loss = cur_step, metrics["loss"]
+            # the learning rate the boundary reports is a handful of tiny
+            # device programs (the eager optax schedule). Queued here, behind
+            # the epoch's last step, it is in hand with the loss; evaluated
+            # after the fetch it would queue behind a led step, and the host
+            # would sit out that step in the middle of the boundary
+            lr_pending = schedule(max(end_step - 1, 0))
+            if not epoch_compile and leads_over_boundary(
+                epoch, epochs=epochs, save_model_epoch=save_model_epoch,
+                eval_every=eval_every,
+            ):
+                # a one-step lead: the next epoch's first batch is taken and
+                # its first step dispatched BEFORE this epoch's loss is
+                # fetched, so the device runs that step while the host does
+                # the boundary's work (fetch wake-up, telemetry, log, event,
+                # results) instead of standing still for it. Same key, same
+                # batch, same order as without the lead; last_loss is an
+                # output of the finished step, not donated
+                next_t0 = time.perf_counter()
+                batches, batch = open_epoch(epoch + 1)
+                if batch is None:
+                    batches.close()
+                else:
+                    with spans.span("epoch", epoch=epoch + 1, step=cur_step + 1):
+                        train_step(batch, epoch + 1)
+                    led = (batches, end_step, next_t0)
+                    if detector is not None:
+                        detector.pause()  # the led step's tick armed it again
+            # n: the steps of the next epoch dispatched before the fetch
+            boundary_span = spans.begin(
+                "boundary", epoch=epoch, step=end_step, n=int(led is not None)
+            )
             with spans.span("boundary/loss_fetch"):
-                epoch_loss = guard.checked_loss(cur_step, float(metrics["loss"]))
+                epoch_loss = guard.checked_loss(end_step, float(last_loss))
             # epoch telemetry BEFORE the boundary beat, so the beat's
             # snapshot (and any scrape) reflects the epoch that just
             # finished; every input is a host float already in hand, and
             # every host updates its OWN gauges for the fleet view
             with spans.span("boundary/telemetry"):
+                lr_now = float(lr_pending)
                 telemetry.observe_epoch(
                     epoch,
                     epochs=epochs,
-                    step=cur_step,
-                    steps=cur_step - epoch_start_step,
+                    step=end_step,
+                    steps=end_step - epoch_start_step,
                     seconds=time.perf_counter() - epoch_t0,
                     loss=epoch_loss,
-                    lr=float(schedule(max(cur_step - 1, 0))),
+                    lr=lr_now,
                 )
             with spans.span("beat"):
-                guard.beat(cur_step, epoch, loss=epoch_loss)
+                guard.beat(end_step, epoch, loss=epoch_loss)
             if not math.isfinite(epoch_loss):
                 # roll back to the newest verified checkpoint; a different
                 # RNG stream on the retry — deterministically replaying the
@@ -1134,6 +1205,10 @@ def run_pretrain(cfg: Config) -> dict:
                     raise PoisonedRun(str(e)) from e
                 guard.record_rollback(epoch_loss, rpath)
                 telemetry.observe_restore(time.perf_counter() - t_restore)
+                if led is not None:
+                    # the led step goes with the state it produced
+                    led[0].close()
+                    led = None
                 state = restored
                 cur_step = int(state.step)
                 epoch, skip_steps = resume_point(cur_step, steps_per_epoch)
@@ -1149,9 +1224,8 @@ def run_pretrain(cfg: Config) -> dict:
             if is_logging_host():
                 # one line per epoch, the reference's rank-0 log (main.py:124-127)
                 with spans.span("boundary/log"):
-                    lr_now = float(schedule(max(cur_step - 1, 0)))
                     imgs_per_sec = (
-                        (cur_step - (start_epoch - 1) * steps_per_epoch)
+                        (end_step - (start_epoch - 1) * steps_per_epoch)
                         * global_batch / max(time.time() - t_start, 1e-9)
                     )
                     logger.info(
@@ -1163,7 +1237,7 @@ def run_pretrain(cfg: Config) -> dict:
             loss_history.append([epoch, epoch_loss])
             with spans.span("boundary/event"):
                 events.emit(
-                    "epoch", epoch=epoch, step=cur_step, loss=epoch_loss,
+                    "epoch", epoch=epoch, step=end_step, loss=epoch_loss,
                     seconds=round(time.perf_counter() - epoch_t0, 6),
                 )
             if eval_every > 0 and (epoch % eval_every == 0 or epoch == epochs):
@@ -1193,6 +1267,8 @@ def run_pretrain(cfg: Config) -> dict:
                         "complete": False,
                     }
                 )
+            if led is not None:
+                end_boundary()  # the next epoch's first batch and step are out
             epoch += 1
         end_boundary()
     except Exception as exc:

@@ -298,15 +298,20 @@ def _fail_the_save(monkeypatch):
     monkeypatch.setattr(main, "save_checkpoint", save_checkpoint)
 
 
-@pytest.mark.parametrize("ending", ["preempted", "exception"])
+@pytest.mark.parametrize("ending", ["preempted", "preempted_led", "exception"])
 def test_run_pretrain_flushes_however_it_ends(tmp_path, monkeypatch, ending):
     from simclr_tpu.config import load_config
     from simclr_tpu.main import run_pretrain
     from simclr_tpu.supervisor.guard import PreemptedRun
 
     save_dir = str(tmp_path / ending)
-    cfg = load_config("config", overrides=TOY + [f"experiment.save_dir={save_dir}"])
-    if ending == "preempted":
+    overrides = TOY + [f"experiment.save_dir={save_dir}"]
+    if ending == "preempted_led":
+        # no save due after epoch 1: its boundary is led by step 3, which
+        # goes out with epoch 2's first batch before the boundary opens
+        overrides.append("experiment.save_model_epoch=3")
+    cfg = load_config("config", overrides=overrides)
+    if ending.startswith("preempted"):
         _signal_at_step(monkeypatch, at_step=3)  # mid-epoch 2
         with pytest.raises(PreemptedRun):
             run_pretrain(cfg)
@@ -330,7 +335,7 @@ def test_run_pretrain_flushes_however_it_ends(tmp_path, monkeypatch, ending):
         assert {"gather", "device_put", "dispatch", "beat"} <= {
             s["name"] for s in spans if s.get("step") == step
         }
-    if ending == "preempted":
+    if ending.startswith("preempted"):
         (preempt,) = names["preempt"]
         under = {s["name"] for s in spans if s["parent"] == preempt["id"]}
         assert under == {"preempt/notice", "checkpoint", "preempt/event",
@@ -341,9 +346,24 @@ def test_run_pretrain_flushes_however_it_ends(tmp_path, monkeypatch, ending):
             "checkpoint/sidecar",
         ]
         assert not any(s.get("open") for s in spans)
-        # epoch 1's boundary saved a checkpoint and flushed after it
+        (boundary,) = names["boundary"]
+        second = names["boundary/first_batch"][1]
         with open(spans_path(save_dir)) as f:
-            assert sum("header" in line for line in f) >= 3
+            flushes = sum("header" in line for line in f)
+        if ending == "preempted":
+            # epoch 1's boundary saved a checkpoint and flushed after it,
+            # and stayed open until epoch 2's first batch was in hand
+            assert flushes >= 3
+            assert boundary["n"] == 0 and second["parent"] == boundary["id"]
+        else:
+            # the batch and step 3 before the boundary, which ends with its
+            # results file; the signal, seen in step 3's beat, is acted on
+            # after it
+            assert flushes == 2 and boundary["n"] == 1 and second["parent"] == 0
+            led = next(s for s in names["dispatch"] if s["step"] == 3)
+            assert second["t1"] <= led["t0"] <= led["t1"] <= boundary["t0"]
+            assert boundary["t1"] <= preempt["t0"]
+            assert [s["name"] for s in spans if s["parent"] == boundary["id"]][-1] == "boundary/results"
     else:
         # the save that raised: its checkpoint span ended with the drain in
         # it and no save, and the boundary it sat in is written as open

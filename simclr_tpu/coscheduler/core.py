@@ -105,6 +105,7 @@ class CoScheduler:
         import jax.numpy as jnp
 
         from simclr_tpu.eval import build_eval_model
+        from simclr_tpu.parallel.train_state import init_variables
         from simclr_tpu.serve.metrics import ServeMetrics
         from simclr_tpu.serve.replica import ReplicaPool
         from simclr_tpu.serve.server import _write_ready_file, start_server
@@ -117,7 +118,10 @@ class CoScheduler:
         # real checkpoint stages shape-identically — zero recompiles
         variables = jax.tree.map(
             np.asarray,
-            model.init(jax.random.key(seed), jnp.zeros((2, 32, 32, 3))),
+            init_variables(
+                model, jax.random.key(seed),
+                jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32),
+            ),
         )
         self.metrics = metrics = ServeMetrics()
         logger.info(

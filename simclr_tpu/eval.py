@@ -56,6 +56,7 @@ from simclr_tpu.parallel.mesh import (
     validate_per_device_batch,
 )
 from simclr_tpu.parallel.steps import make_encode_step
+from simclr_tpu.parallel.train_state import init_variables
 from simclr_tpu.utils.checkpoint import list_checkpoints_or_raise, restore_checkpoint
 from simclr_tpu.utils.fetch import fetch
 from simclr_tpu.utils.ioutil import atomic_write
@@ -515,10 +516,13 @@ def learnable_probe(
         max(total_steps, 1),
         mesh,
     )
-    variables = clf.init(jax.random.key(seed), jnp.zeros((2, train_X.shape[1])))
+    variables = init_variables(
+        clf, jax.random.key(seed),
+        jax.ShapeDtypeStruct((2, train_X.shape[1]), jnp.float32),
+    )
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
-    opt_state = tx.init(params)
+    opt_state = jax.jit(tx.init)(params)
 
     X = jnp.asarray(train_X)
     y = jnp.asarray(train_y)

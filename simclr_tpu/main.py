@@ -17,6 +17,7 @@ checkpoint even when ``epochs % save_model_epoch != 0``.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import math
@@ -57,7 +58,6 @@ from simclr_tpu.parallel.mesh import (
     mesh_host_count,
     put_replicated,
     put_row_sharded,
-    put_tree,
     replicated_sharding,
     validate_per_device_batch,
 )
@@ -88,7 +88,11 @@ from simclr_tpu.utils.checkpoint import (
     save_checkpoint,
 )
 from simclr_tpu.utils.logging import get_logger, is_logging_host
-from simclr_tpu.utils.platform import compilation_cache_stats, device_identity
+from simclr_tpu.utils.platform import (
+    backend_compilations,
+    compilation_cache_stats,
+    device_identity,
+)
 from simclr_tpu.utils.profiling import StepTimer, StepTraceWindow
 from simclr_tpu.utils.schedule import calculate_initial_lr, warmup_cosine_schedule
 
@@ -199,9 +203,6 @@ def run_pretrain(cfg: Config) -> dict:
     )
 
     model = build_model(cfg)
-    state = create_train_state(
-        model, tx, jax.random.key(seed), jnp.zeros((2, 32, 32, 3), jnp.float32)
-    )
     n_model = mesh.shape[MODEL_AXIS]
     if n_model > 1:
         # tensor-parallel layout from the start: head leaves sharded over the
@@ -209,9 +210,21 @@ def run_pretrain(cfg: Config) -> dict:
         # restore template, so resume keeps the layout
         from simclr_tpu.parallel.tp import tp_state_shardings
 
-        state = put_tree(state, tp_state_shardings(mesh, state))
+        state_shardings = functools.partial(tp_state_shardings, mesh)
     else:
-        state = put_tree(state, replicated_sharding(mesh))
+        state_shardings = replicated_sharding(mesh)
+    # one compiled program whose outputs are placed at their shardings; n is
+    # the backend compilations JAX reported inside (1, or 0 from its
+    # in-process cache: the eager init it replaced made about 160-290)
+    init_key = jax.random.key(seed)
+    with spans.span("boot/state/init") as init_span:
+        compiled_before = backend_compilations()
+        state = create_train_state(
+            model, tx, init_key,
+            jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32),
+            shardings=state_shardings,
+        )
+        init_span.n = backend_compilations() - compiled_before
 
     save_dir = resolve_save_dir(cfg)
     # run telemetry (simclr_tpu/obs/, docs/OBSERVABILITY.md): metric

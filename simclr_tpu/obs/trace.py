@@ -188,7 +188,8 @@ class TraceRecorder:
 # Host spans of a pretraining run, by where the work happens. The names are
 # the contract with the readers; docs/OBSERVABILITY.md says what each covers.
 TRAIN_SPANS = (
-    "boot/runtime", "boot/data", "boot/state", "boot/build_step", "boot/compile",
+    "boot/runtime", "boot/data", "boot/state", "boot/state/init",
+    "boot/build_step", "boot/compile",
     "epoch", "input_wait", "gather", "device_put", "dispatch", "beat",
     "boundary", "boundary/loss_fetch", "boundary/telemetry", "boundary/log",
     "boundary/event", "boundary/results", "boundary/probe", "boundary/shuffle",

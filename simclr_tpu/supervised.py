@@ -52,7 +52,6 @@ from simclr_tpu.parallel.mesh import (
     put_global_batch,
     put_replicated,
     put_row_sharded,
-    put_tree,
     replicated_sharding,
     validate_per_device_batch,
 )
@@ -176,9 +175,10 @@ def run_supervised(cfg: Config) -> dict:
         bn_cross_replica_axis=DATA_AXIS,
     )
     state = create_train_state(
-        model, tx, jax.random.key(seed), jnp.zeros((2, 32, 32, 3), jnp.float32)
+        model, tx, jax.random.key(seed),
+        jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32),
+        shardings=replicated_sharding(mesh),
     )
-    state = put_tree(state, replicated_sharding(mesh))
 
     save_dir = resolve_save_dir(cfg)
     # run telemetry + event timeline (simclr_tpu/obs/, docs/OBSERVABILITY.md),

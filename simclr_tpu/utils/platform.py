@@ -22,13 +22,24 @@ DEFAULT_CACHE_DIR = os.path.join(
     ".jax_cache",
 )
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
 # jax.monitoring event names -> occurrences in this process
 _events: collections.Counter = collections.Counter()
 _listening = False
 
 
-def _count_event(event: str, **_kwargs) -> None:
+def _count_event(event: str, *_duration, **_kwargs) -> None:
     _events[event] += 1
+
+
+def _listen() -> None:
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_listener(_count_event)
+        # a duration event hands its seconds over as a second argument
+        jax.monitoring.register_event_duration_secs_listener(_count_event)
+        _listening = True
 
 
 def configure_compilation_cache() -> str:
@@ -37,10 +48,7 @@ def configure_compilation_cache() -> str:
     Also starts counting JAX's own cache hit/miss monitoring events, which
     :func:`compilation_cache_stats` reports.
     """
-    global _listening
-    if not _listening:
-        jax.monitoring.register_event_listener(_count_event)
-        _listening = True
+    _listen()
     # The key covers a program's metadata too. JAX leaves it out by default,
     # and an executable compiled from another version of the step (other
     # named scopes, or none) would be served for this one with ITS op_names:
@@ -64,6 +72,16 @@ def compilation_cache_stats() -> dict:
         "hits": _events["/jax/compilation_cache/cache_hits"],
         "misses": _events["/jax/compilation_cache/cache_misses"],
     }
+
+
+def backend_compilations() -> int:
+    """Programs this process has handed to the backend's compiler, or loaded
+    from the persistent cache in its place, since the first call of this
+    function or of :func:`configure_compilation_cache`: JAX records one
+    duration around both. A difference of two readings counts the device
+    programs a piece of code built."""
+    _listen()
+    return _events[BACKEND_COMPILE_EVENT]
 
 
 def device_identity() -> dict:

@@ -296,7 +296,8 @@ def test_put_row_sharded_upload_feeds_only_addressable_rows(monkeypatch):
 
 
 def test_put_tree_single_process_matches_device_put():
-    """put_tree is the state-placement path (main.py/supervised.py): in a
+    """put_tree places a host-computed tree (a fresh state is placed by its
+    own program, parallel/train_state.py): in a
     single process it must be exactly device_put — same values, same
     shardings — whether given one sharding for every leaf or a matching
     pytree of per-leaf shardings (the tensor-parallel layout case)."""

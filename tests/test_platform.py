@@ -104,6 +104,20 @@ def test_cache_stats_count_jax_monitoring_events(monkeypatch):
     assert "dir" in stats
 
 
+def test_backend_compilations_counts_programs_built():
+    """One reading per program handed to the compiler: a new jitted function
+    is one, its second call none."""
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.arange(7)
+    before = platform.backend_compilations()
+    f(x)
+    assert platform.backend_compilations() == before + 1
+    f(x)
+    assert platform.backend_compilations() == before + 1
+
+
 def test_device_identity_is_what_jax_reports():
     assert platform.device_identity() == {
         "platform": "cpu",

@@ -323,9 +323,13 @@ def test_run_pretrain_flushes_however_it_ends(tmp_path, monkeypatch, ending):
     spans = read_spans(spans_path(save_dir))
     names = _by_name(spans)
     assert set(names) <= set(TRAIN_SPANS)
-    assert {"boot/runtime", "boot/data", "boot/state", "boot/build_step",
-            "boot/compile", "epoch", "dispatch", "beat", "input_wait",
-            "gather", "device_put", "boundary/first_batch"} <= set(names)
+    assert {"boot/runtime", "boot/data", "boot/state", "boot/state/init",
+            "boot/build_step", "boot/compile", "epoch", "dispatch", "beat",
+            "input_wait", "gather", "device_put",
+            "boundary/first_batch"} <= set(names)
+    # the state is one compiled program
+    (state,), (init,) = names["boot/state"], names["boot/state/init"]
+    assert init["parent"] == state["id"] and 0 <= init["n"] <= 2
     # the first dispatch is a dispatch: the compilation sat in boot/build_step
     build, compiled = names["boot/build_step"][0], names["boot/compile"][0]
     assert compiled["parent"] == build["id"]

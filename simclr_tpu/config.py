@@ -436,11 +436,6 @@ def check_pretrain_conf(cfg: Config) -> None:
 
 
 def _check_runtime_conf(cfg: Config) -> None:
-    _require(
-        cfg.select("runtime.dataset_residency", "replicated")
-        in ("replicated", "sharded"),
-        "runtime.dataset_residency must be 'replicated' or 'sharded'",
-    )
     # the one true universe lives in ops/augment_pallas.AUGMENT_IMPLS; the
     # import is lazy so merely validating a config stays jax-free
     impl = cfg.select("runtime.augment_impl", "xla")
@@ -450,17 +445,6 @@ def _check_runtime_conf(cfg: Config) -> None:
         impl in AUGMENT_IMPLS,
         f"runtime.augment_impl must be {'|'.join(AUGMENT_IMPLS)}, "
         f"got {impl!r}",
-    )
-    k = cfg.select("runtime.epochs_per_compile", 1)
-    _require(
-        isinstance(k, int) and not isinstance(k, bool) and k >= 1,
-        f"runtime.epochs_per_compile must be an int >= 1, got {k!r}",
-    )
-    _require(
-        k == 1 or bool(cfg.select("runtime.epoch_compile", False)),
-        "runtime.epochs_per_compile > 1 (superepochs) requires "
-        "runtime.epoch_compile=true — the superepoch scan is the epoch "
-        "scan's outer loop",
     )
     _check_parallel_conf(cfg)
     _check_supervisor_conf(cfg)

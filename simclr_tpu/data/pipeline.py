@@ -36,20 +36,6 @@ def epoch_permutation(num_samples: int, seed: int, epoch: int) -> np.ndarray:
     )
 
 
-def epoch_index_matrix(
-    num_samples: int, seed: int, epoch: int, steps: int, global_batch: int
-) -> np.ndarray:
-    """(steps, global_batch) int32 shuffled row indices for one epoch.
-
-    The epoch-compiled training paths feed this to the on-device gather;
-    truncation matches :class:`EpochIterator`'s ``drop_last`` semantics, so
-    the data order is identical to the per-step pipeline (load-bearing for
-    the epoch-compile equivalence guarantee, tests/test_epoch_compile.py).
-    """
-    order = epoch_permutation(num_samples, seed, epoch)
-    return order[: steps * global_batch].reshape(steps, global_batch).astype(np.int32)
-
-
 class EpochIterator:
     """Iterates one split in globally-shuffled, host-sharded batches.
 

@@ -191,129 +191,6 @@ def kmeans(
     )
 
 
-def make_local_centroid_monitor(
-    model,
-    *,
-    num_classes: int,
-    n_train: int,
-    n_test: int,
-    top_k: int = 5,
-    chunk: int = 512,
-    data_axis: str = None,
-):
-    """The centroid monitor as a PURE jittable per-shard function — the
-    device-resident counterpart of :func:`extract_features` +
-    :func:`centroid_probe`, built to run INSIDE a ``shard_map`` over the data
-    axis (the superepoch scan, ``parallel/steps.py``) so ``eval_every``
-    monitoring costs zero host syncs.
-
-    Contract of the returned callable (all inputs device-resident)::
-
-        local_monitor(params, batch_stats, train_rows, train_labels,
-                      test_rows, test_labels) -> {metric: scalar}
-
-    where ``train_rows``/``test_rows`` are this shard's CONTIGUOUS row block
-    of the (tail-padded) split — shard ``k`` holds global rows
-    ``[k*R, (k+1)*R)``, the ``mesh.put_row_sharded`` layout — and
-    ``train_labels``/``test_labels`` are the full replicated label vectors
-    padded to ``n_shards * R``. Padding rows are excluded by position
-    (``k*R + i >= n``), so the label padding value is irrelevant.
-
-    Numerics mirror the host path exactly by construction: the same f32
-    ``build_eval_model`` encode forward (``train=False``, running BN stats),
-    per-class mean centroids (``centroid_weights``), ``features @ centroids``
-    logits (``centroid_logits``), and top-1/top-k corrects (``_topk_correct``)
-    — except features never leave the device and the per-class sums/corrects
-    are assembled with ``psum`` over the data axis instead of a host
-    concatenate. Correct counts are integer sums, so the accuracies agree
-    with the host path up to feature-level float drift flipping an argmax
-    tie (test-asserted in tests/test_superepoch.py).
-
-    The forward is chunked with an inner ``lax.scan`` (``chunk`` rows per
-    iteration) to bound activation memory; the returned callable exposes
-    ``metric_names`` so callers can build a structurally-identical skip
-    branch for the ``eval_every`` gating ``lax.cond``.
-    """
-    if data_axis is None:
-        from simclr_tpu.parallel.mesh import DATA_AXIS
-
-        data_axis = DATA_AXIS
-
-    def _features(params, batch_stats, rows):
-        rows_local = rows.shape[0]
-        c = min(chunk, rows_local)
-        n_chunks = -(-rows_local // c)
-        pad = n_chunks * c - rows_local
-        if pad:
-            rows = jnp.concatenate(
-                [rows, jnp.zeros((pad, *rows.shape[1:]), rows.dtype)]
-            )
-        chunks = rows.reshape(n_chunks, c, *rows.shape[1:])
-
-        def body(carry, xb):
-            f = model.apply(
-                {"params": params, "batch_stats": batch_stats},
-                to_float(xb), train=False, method=model.encode,
-            ).astype(jnp.float32)
-            return carry, f
-
-        _, feats = jax.lax.scan(body, None, chunks)
-        return feats.reshape(n_chunks * c, -1)[:rows_local]
-
-    def _split(params, batch_stats, rows, labels_all, n):
-        rows_local = rows.shape[0]
-        shard = jax.lax.axis_index(data_axis)
-        feats = _features(params, batch_stats, rows)
-        labels = jax.lax.dynamic_slice_in_dim(
-            labels_all, shard * rows_local, rows_local
-        )
-        valid = (jnp.arange(rows_local) + shard * rows_local) < n
-        return feats, labels, valid
-
-    def _corrects(feats, labels, valid, weights):
-        logits = centroid_logits(feats, weights)
-        _, pred = jax.lax.top_k(logits, top_k)
-        top1 = jnp.sum((pred[:, 0] == labels) & valid)
-        topk = jnp.sum(jnp.any(pred == labels[:, None], axis=1) & valid)
-        return (
-            jax.lax.psum(top1, data_axis).astype(jnp.float32),
-            jax.lax.psum(topk, data_axis).astype(jnp.float32),
-        )
-
-    def local_monitor(
-        params, batch_stats, train_rows, train_labels, test_rows, test_labels
-    ):
-        tr_f, tr_y, tr_v = _split(
-            params, batch_stats, train_rows, train_labels, n_train
-        )
-        # per-class mean centroids (centroid_weights semantics), assembled
-        # from per-shard partial sums: one psum of a (d, C) matrix + (C,)
-        one_hot = (
-            jax.nn.one_hot(tr_y, num_classes, dtype=jnp.float32)
-            * tr_v[:, None].astype(jnp.float32)
-        )
-        sums = jax.lax.psum(tr_f.T @ one_hot, data_axis)
-        counts = jax.lax.psum(one_hot.sum(axis=0), data_axis)
-        weights = sums / jnp.clip(counts, 1.0, None)
-
-        tr1, trk = _corrects(tr_f, tr_y, tr_v, weights)
-        te_f, te_y, te_v = _split(
-            params, batch_stats, test_rows, test_labels, n_test
-        )
-        va1, vak = _corrects(te_f, te_y, te_v, weights)
-        return {
-            "train_acc": tr1 / n_train,
-            f"train_top_{top_k}_acc": trk / n_train,
-            "val_acc": va1 / n_test,
-            f"val_top_{top_k}_acc": vak / n_test,
-        }
-
-    local_monitor.metric_names = (
-        "train_acc", f"train_top_{top_k}_acc", "val_acc", f"val_top_{top_k}_acc",
-    )
-    return local_monitor
-
-
 @functools.lru_cache(maxsize=8)
 def _probe_program(
     kind: str,

@@ -27,7 +27,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from simclr_tpu.config import (
     Config,
@@ -36,7 +35,7 @@ from simclr_tpu.config import (
     resolve_save_dir,
 )
 from simclr_tpu.data.cifar import load_dataset
-from simclr_tpu.data.pipeline import EpochIterator, epoch_index_matrix
+from simclr_tpu.data.pipeline import EpochIterator
 from simclr_tpu.data.prefetch import prefetch
 from simclr_tpu.models.contrastive import ContrastiveModel
 from simclr_tpu.obs.anomaly import maybe_detector
@@ -56,18 +55,10 @@ from simclr_tpu.parallel.mesh import (
     enable_async_collective_flags,
     mesh_from_config,
     mesh_host_count,
-    put_replicated,
-    put_row_sharded,
     replicated_sharding,
     validate_per_device_batch,
 )
-from simclr_tpu.parallel.steps import (
-    check_epoch_compile_preconditions,
-    make_pretrain_epoch_fn,
-    make_pretrain_step,
-    make_pretrain_superepoch_fn,
-    superepoch_steps_from_args,
-)
+from simclr_tpu.parallel.steps import make_pretrain_step
 from simclr_tpu.parallel.train_state import create_train_state, param_count
 from simclr_tpu.supervisor.guard import (
     PoisonedRun,
@@ -117,7 +108,7 @@ def build_model(cfg: Config) -> ContrastiveModel:
 def leads_over_boundary(
     epoch: int, *, epochs: int, save_model_epoch: int, eval_every: int
 ) -> bool:
-    """Whether the per-step loop may queue the next epoch's first step before
+    """Whether the loop may queue the next epoch's first step before
     it fetches ``epoch``'s loss, so that the device runs that step while the
     host does the boundary's work. Not where the boundary has to see the state
     as the epoch left it (the step donates it): a checkpoint or a probe is
@@ -167,8 +158,7 @@ def run_pretrain(cfg: Config) -> dict:
     # Reference step accounting (drop_last truncation, main.py:76-80)
     steps_per_epoch = len(dataset) // global_batch
     if steps_per_epoch == 0:
-        # early, before any compile; check_epoch_compile_preconditions and
-        # EpochIterator repeat this at their own boundaries
+        # early, before any compile; EpochIterator repeats this
         raise ValueError(
             f"dataset of {len(dataset)} samples smaller than global batch "
             f"{global_batch}"
@@ -303,9 +293,7 @@ def run_pretrain(cfg: Config) -> dict:
             )
             # cross-topology resume (elastic remesh): accepted only when the
             # global batch is preserved and the checkpoint sits on an epoch
-            # boundary; anything else raises here, before any compile. The
-            # HBM preflight is inherently revalidated — the epoch_compile
-            # precondition check below runs against the CURRENT mesh.
+            # boundary; anything else raises here, before any compile.
             topology_change = check_resume_topology(
                 prior_topology,
                 n_devices=jax.device_count(),
@@ -370,271 +358,27 @@ def run_pretrain(cfg: Config) -> dict:
         # step through an instrumented AOT lower/compile path when set
         sentry=sentry,
     )
-    epoch_compile = bool(cfg.select("runtime.epoch_compile", False))
-    if epoch_compile and skip_steps:
-        # epoch_compile only ever checkpoints at epoch boundaries (the scan
-        # is one indivisible XLA program); a mid-epoch checkpoint must have
-        # come from a per-step-mode run, which can replay the partial epoch
-        raise ValueError(
-            f"checkpoint at step {int(state.step)} is mid-epoch "
-            f"({skip_steps}/{steps_per_epoch} steps into epoch {start_epoch}) "
-            "and cannot resume under runtime.epoch_compile=true; resume with "
-            "runtime.epoch_compile=false"
-        )
-    # runtime.epochs_per_compile=K > 1: superepochs — one XLA program per K
-    # epochs (the Podracer pattern); full-K chunks cover epochs
-    # 1..K*(epochs//K), the tail (< K epochs) runs on the single-epoch path
-    # so every compiled program keeps one stable signature
-    epochs_per_compile = int(cfg.select("runtime.epochs_per_compile", 1) or 1)
-    superepoch = epoch_compile and epochs_per_compile > 1
-    full_super_end = (epochs // epochs_per_compile) * epochs_per_compile
-
-    def _check_superepoch_resume(at_epoch: int) -> None:
-        """Superepoch chunks are indivisible like epochs are: a checkpoint
-        inside a full-K chunk (not on a K boundary, not in the tail) cannot
-        seed a resume — rejected the way mid-epoch checkpoints are above."""
-        if (
-            superepoch
-            and at_epoch <= full_super_end
-            and (at_epoch - 1) % epochs_per_compile
-        ):
-            raise ValueError(
-                f"checkpoint at epoch {at_epoch - 1} is mid-superepoch "
-                f"(epoch {(at_epoch - 1) % epochs_per_compile} of a "
-                f"{epochs_per_compile}-epoch chunk) and cannot resume under "
-                f"runtime.epochs_per_compile={epochs_per_compile}; resume "
-                "with runtime.epochs_per_compile=1"
-            )
-
-    _check_superepoch_resume(start_epoch)
-    # runtime.dataset_residency: "replicated" keeps the whole dataset in every
-    # chip's HBM; "sharded" keeps N/n_data rows per data shard and reassembles
-    # each step's batch with one O(global_batch) psum inside the epoch scan
-    # (docs/PERF.md §"Dataset residency")
-    residency = str(cfg.select("runtime.dataset_residency", "replicated"))
-    put_dataset = put_replicated if residency == "replicated" else put_row_sharded
-    data_shard = batch_sharding(mesh)
-    # experiment.eval_every > 0: centroid-probe the test split every N
-    # epochs — a REAL monitor where the reference's validation() is an
-    # empty stub (/root/reference/main.py:53-58, SURVEY §2.5.6). Off by
-    # default for recipe parity. Read before the builders: under
-    # superepochs the probe compiles INTO the training program.
-    eval_every = int(cfg.select("experiment.eval_every", 0) or 0)
-    test_ds = None
-    if eval_every > 0:
-        test_ds = load_dataset(
-            cfg.experiment.name, "test",
-            data_dir=cfg.select("experiment.data_dir"),
-            synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
-            synthetic_size=cfg.select("experiment.synthetic_size"),
-            synthetic_noise=cfg.select("experiment.synthetic_noise"),
-        )
-
-    def _pad_rows(a: np.ndarray, mult: int) -> np.ndarray:
-        """Zero-pad rows to a multiple of ``mult``. Padding appends AFTER the
-        real rows, so global row indices are unchanged: training gathers
-        (index < N) never see it and the monitor masks it by row position."""
-        pad = -len(a) % mult
-        if pad == 0:
-            return a
-        return np.concatenate(
-            [a, np.zeros((pad,) + a.shape[1:], dtype=a.dtype)]
-        )
-
-    # superepoch in-program monitor: the centroid probe runs INSIDE the
-    # compiled K-epoch program against an HBM-resident test split, so
-    # monitoring costs zero extra host syncs (eval.py's host path stays the
-    # parity reference and serves the tail/epoch-0 probes)
-    probe_local = None
-    probe_arrays: tuple = ()
-    if superepoch and eval_every > 0:
-        from simclr_tpu.eval import build_eval_model, make_local_centroid_monitor
-
-        probe_local = make_local_centroid_monitor(
-            build_eval_model(cfg),
-            num_classes=dataset.num_classes,
-            n_train=len(dataset),
-            n_test=len(test_ds),
-            top_k=5,
-        )
-    # analytic per-chip resident dataset bytes from the epoch-compile
-    # preflight; the DeviceMonitor reconciles it against measured live HBM
-    resident_bytes = None
     if n_model > 1:
-        # tensor-parallel projection head over the model axis (parallel/tp.py).
-        # Support matrix: docs/PERF.md §"Tensor-parallel support matrix"
-        from simclr_tpu.parallel.tp import (
-            make_pretrain_epoch_fn_tp,
-            make_pretrain_step_tp,
-        )
-
-        # every loss.negatives/loss.fused variant now threads through the tp
-        # builders with the dp path's dispatch (parallel/tp.py); only the
-        # forward-mode restriction remains
-        unsupported = {
-            "model.forward_mode != two_pass": step_kwargs["forward_mode"] != "two_pass",
-        }
-        bad = [k for k, v in unsupported.items() if v]
-        if bad:
-            raise ValueError(
-                f"mesh.model={n_model} (tensor parallelism) does not combine "
-                f"with: {', '.join(bad)} "
-                "(see docs/PERF.md, tensor-parallel support matrix)"
-            )
-        if epoch_compile:
-            resident_bytes = check_epoch_compile_preconditions(
-                len(dataset), global_batch, cfg.select("experiment.profile_dir"),
-                dataset_bytes=dataset.images.nbytes,
-                n_data_shards=n_data,
-                residency=residency,
-                epochs_per_compile=epochs_per_compile,
-                steps_per_epoch=steps_per_epoch,
-                probe_bytes=(
-                    test_ds.images.nbytes if probe_local is not None else None
-                ),
-                probe_samples=len(test_ds) if probe_local is not None else 0,
-            )
-            epoch_fn = make_pretrain_epoch_fn_tp(
-                model, tx, mesh,
-                temperature=step_kwargs["temperature"],
-                strength=step_kwargs["strength"],
-                negatives=step_kwargs["negatives"],
-                fused=step_kwargs["fused"],
-                remat=step_kwargs["remat"],
-                residency=residency,
-                grad_allreduce=step_kwargs["grad_allreduce"],
-                comm_overlap=step_kwargs["comm_overlap"],
-                comm_chunks=step_kwargs["comm_chunks"],
-                augment_impl=step_kwargs["augment_impl"],
-            )
-            if sentry is not None:
-                # the TP builders predate the sentry kwarg; wrap at the
-                # call site with the same epoch-scan step extractor
-                epoch_fn = sentry.watch(
-                    epoch_fn, "pretrain_epoch",
-                    steps_from_args=lambda args: int(args[2].shape[0]),
-                )
-            superepoch_fn = None
-            if superepoch:
-                from simclr_tpu.parallel.tp import make_pretrain_superepoch_fn_tp
-
-                superepoch_fn = make_pretrain_superepoch_fn_tp(
-                    model, tx, mesh,
-                    temperature=step_kwargs["temperature"],
-                    strength=step_kwargs["strength"],
-                    negatives=step_kwargs["negatives"],
-                    fused=step_kwargs["fused"],
-                    remat=step_kwargs["remat"],
-                    residency=residency,
-                    grad_allreduce=step_kwargs["grad_allreduce"],
-                    comm_overlap=step_kwargs["comm_overlap"],
-                    comm_chunks=step_kwargs["comm_chunks"],
-                    augment_impl=step_kwargs["augment_impl"],
-                    monitor=probe_local,
-                )
-                if sentry is not None:
-                    # its own watched name: the K-epoch program legitimately
-                    # has a different signature from the single-epoch one
-                    superepoch_fn = sentry.watch(
-                        superepoch_fn, "pretrain_superepoch",
-                        steps_from_args=superepoch_steps_from_args(
-                            2 + (3 if probe_local is not None else 0)
-                        ),
-                    )
-            train_rows = (
-                _pad_rows(dataset.images, n_data)
-                if probe_local is not None and residency == "replicated"
-                else dataset.images
-            )
-            images_all = put_dataset(train_rows, mesh)
-            iterator = None
-        else:
-            step_fn = make_pretrain_step_tp(
-                model, tx, mesh,
-                temperature=step_kwargs["temperature"],
-                strength=step_kwargs["strength"],
-                negatives=step_kwargs["negatives"],
-                fused=step_kwargs["fused"],
-                remat=step_kwargs["remat"],
-                grad_allreduce=step_kwargs["grad_allreduce"],
-                comm_overlap=step_kwargs["comm_overlap"],
-                comm_chunks=step_kwargs["comm_chunks"],
-                augment_impl=step_kwargs["augment_impl"],
-            )
-            if sentry is not None:
-                step_fn = sentry.watch(step_fn, "pretrain_step")
-            iterator = EpochIterator(
-                dataset, global_batch, seed=seed, shuffle=True, sharding=data_shard,
-                gather_threads=int(cfg.parameter.num_workers),
-            )
-    elif epoch_compile:
-        resident_bytes = check_epoch_compile_preconditions(
-            len(dataset), global_batch, cfg.select("experiment.profile_dir"),
-            dataset_bytes=dataset.images.nbytes,
-            n_data_shards=n_data,
-            residency=residency,
-            epochs_per_compile=epochs_per_compile,
-            steps_per_epoch=steps_per_epoch,
-            probe_bytes=(
-                test_ds.images.nbytes if probe_local is not None else None
-            ),
-            probe_samples=len(test_ds) if probe_local is not None else 0,
-        )
-        epoch_fn = make_pretrain_epoch_fn(
-            model, tx, mesh, residency=residency, **step_kwargs
-        )
-        superepoch_fn = None
-        if superepoch:
-            superepoch_fn = make_pretrain_superepoch_fn(
-                model, tx, mesh, residency=residency, monitor=probe_local,
-                **step_kwargs,
-            )
-        # the uint8 dataset lives in HBM for the run (full per chip, or
-        # N/n_data rows per shard under sharded residency); batches are
-        # gathered on device by shuffled index inside the epoch scan.
-        # both uploads are multi-host safe. With the in-program monitor under
-        # replicated residency the rows are zero-padded to a multiple of the
-        # data shards so each shard's probe block slices evenly; padding sits
-        # after the real rows and training indices (< N) never touch it
-        train_rows = (
-            _pad_rows(dataset.images, n_data)
-            if probe_local is not None and residency == "replicated"
-            else dataset.images
-        )
-        images_all = put_dataset(train_rows, mesh)
-        iterator = None
+        # tensor-parallel projection head over the model axis; what it
+        # combines with: docs/PERF.md §"Tensor-parallel support matrix"
+        from simclr_tpu.parallel.tp import make_pretrain_step_tp as build_step
     else:
-        step_fn = make_pretrain_step(model, tx, mesh, **step_kwargs)
-        iterator = EpochIterator(
-            dataset, global_batch, seed=seed, shuffle=True, sharding=data_shard,
-            gather_threads=int(cfg.parameter.num_workers),
-        )
-
-    if probe_local is not None:
-        # HBM-resident probe inputs for the in-program monitor: labels are
-        # replicated (tiny), the test split follows the training residency.
-        # Rows are padded to a multiple of the data shards so each shard owns
-        # one contiguous block; the validity masks built into probe_local use
-        # the REAL row counts, so padding never scores
-        probe_arrays = (
-            put_replicated(_pad_rows(dataset.labels, n_data), mesh),
-            put_dataset(
-                _pad_rows(test_ds.images, n_data)
-                if residency == "replicated" else test_ds.images,
-                mesh,
-            ),
-            put_replicated(_pad_rows(test_ds.labels, n_data), mesh),
-        )
+        # the module's attribute as it is now: the benchmark's reference
+        # replaces it to follow the first steps
+        build_step = make_pretrain_step
+    step_fn = build_step(model, tx, mesh, **step_kwargs)
+    iterator = EpochIterator(
+        dataset, global_batch, seed=seed, shuffle=True,
+        sharding=batch_sharding(mesh),
+        gather_threads=int(cfg.parameter.num_workers),
+    )
 
     # live HBM accounting (obs/device.py): per-device memory_stats gauges
     # sampled at scrape time from the exporter thread — host-side allocator
-    # queries, zero device syncs — reconciled against the preflight's
-    # analytic footprint when epoch_compile computed one
-    # every host monitors its OWN local devices' HBM — per-host watermarks
-    # are fleet gauges (the events stream stays logging-host-only)
-    monitor = maybe_monitor(
-        cfg, events=events, expected_resident_bytes=resident_bytes
-    )
+    # queries, zero device syncs. Every host monitors its OWN local devices'
+    # HBM — per-host watermarks are fleet gauges (the events stream stays
+    # logging-host-only)
+    monitor = maybe_monitor(cfg, events=events)
     if monitor is not None:
         telemetry.attach_device_monitor(monitor)
 
@@ -702,10 +446,19 @@ def run_pretrain(cfg: Config) -> dict:
             lambda f: json.dump(summary, f, indent=1),
         )
 
+    # experiment.eval_every > 0: centroid-probe the test split every N
+    # epochs — a REAL monitor where the reference's validation() is an
+    # empty stub (/root/reference/main.py:53-58, SURVEY §2.5.6). Off by
+    # default for recipe parity.
+    eval_every = int(cfg.select("experiment.eval_every", 0) or 0)
     if eval_every > 0:
-        # host-side probe: used every eval_every epochs on the per-step and
-        # single-epoch paths, and for the epoch-0/tail probes under
-        # superepochs (in-chunk probes run inside the compiled program)
+        test_ds = load_dataset(
+            cfg.experiment.name, "test",
+            data_dir=cfg.select("experiment.data_dir"),
+            synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
+            synthetic_size=cfg.select("experiment.synthetic_size"),
+            synthetic_noise=cfg.select("experiment.synthetic_noise"),
+        )
         # on-device reshard to replicated: the encode program expects
         # replicated variables, and a TP run's live head leaves are
         # model-sharded global arrays that span non-addressable devices
@@ -762,16 +515,8 @@ def run_pretrain(cfg: Config) -> dict:
     )
     t_start = time.time()
     # steady-state throughput, excluding the first (compiling) steps; the
-    # per-epoch log line reports the cumulative rate instead. In
-    # epoch_compile mode one tick covers a whole epoch of steps; under
-    # superepochs one tick covers K epochs (tail epochs, a different
-    # program, skip the timer — mixed tick sizes would skew the rate)
-    imgs_per_tick = global_batch
-    if epoch_compile:
-        imgs_per_tick = global_batch * steps_per_epoch
-        if superepoch:
-            imgs_per_tick *= epochs_per_compile
-    timer = StepTimer(imgs_per_tick, warmup=1 if epoch_compile else 3)
+    # per-epoch log line reports the cumulative rate instead
+    timer = StepTimer(global_batch)
     stem = str(cfg.experiment.output_model_name)
     # per-host /metrics + /debug/trace exporter; None unless telemetry.port
     # (or telemetry.ready_file for an ephemeral port) is configured. Every
@@ -785,7 +530,7 @@ def run_pretrain(cfg: Config) -> dict:
     # epoch's first batch in hand, or to the results file where that batch
     # and its step went out before the boundary opened
     boundary_span = None
-    # the per-step loop's one-step lead over a boundary: the next epoch's
+    # the loop's one-step lead over a boundary: the next epoch's
     # batches, first step dispatched, with its start step and start time
     led = None
 
@@ -805,17 +550,6 @@ def run_pretrain(cfg: Config) -> dict:
         save_checkpoint(path, state)  # checkpoint/save, /digest, /sidecar
         telemetry.observe_save(time.perf_counter() - t_save)
 
-    def first_dispatch_next(fn, *args) -> None:
-        """Ends boot/build_step before the run's first dispatch, the step's
-        compilation (the sentry's boot/compile) still inside it."""
-        nonlocal build_span
-        if build_span is None:
-            return
-        if hasattr(fn, "compile"):  # obs/compile.py WatchedFunction
-            fn.compile(*args)
-        spans.end(build_span)
-        build_span = None
-
     def open_epoch(at_epoch: int, skip: int = 0):
         """The epoch's prefetched batches and the first of them (``None`` if
         it has none), waited for in ``boundary/first_batch``."""
@@ -830,7 +564,7 @@ def run_pretrain(cfg: Config) -> dict:
             return batches, next(batches, None)
 
     def train_step(batch, at_epoch: int) -> None:
-        """One step of the per-step loop: dispatch, tick, beat."""
+        """One step of the loop: dispatch, tick, beat."""
         nonlocal state, metrics, cur_step
         tracer.tick(cur_step, pending=metrics["loss"])
         # the key's fold_in is a device program too, and the first of the
@@ -868,239 +602,34 @@ def run_pretrain(cfg: Config) -> dict:
         while epoch <= epochs:
             if led is None:
                 epoch_start_step, epoch_t0 = cur_step, time.perf_counter()
-            else:
-                _, epoch_start_step, epoch_t0 = led
-            # full-K superepoch chunk: one compiled call runs K epochs (and
-            # their probes) on device; the host only syncs here, at the
-            # boundary, to fetch the stacked per-epoch metrics. The tail
-            # (epochs past the last full chunk) falls through to the
-            # single-epoch program below.
-            if (
-                superepoch
-                and (epoch - 1) % epochs_per_compile == 0
-                and epoch + epochs_per_compile - 1 <= epochs
-            ):
-                K = epochs_per_compile
-                chunk = list(range(epoch, epoch + K))
-                boundary = chunk[-1]
-                with spans.span("boundary/shuffle", epoch=epoch):
-                    idx_super = jnp.asarray(
-                        np.stack([
-                            epoch_index_matrix(
-                                len(dataset), seed, e, steps_per_epoch, global_batch
-                            )
-                            for e in chunk
-                        ])
-                    )
-                probed = [False] * K
-                args = (state, images_all, idx_super, base_key, cur_step)
-                if probe_local is not None:
-                    probed = [e % eval_every == 0 or e == epochs for e in chunk]
-                    args = (
-                        state, images_all, *probe_arrays,
-                        idx_super, jnp.asarray(probed), base_key, cur_step,
-                    )
+                batches, batch = open_epoch(epoch, skip_steps)
+                skip_steps = 0
                 end_boundary()
-                first_dispatch_next(superepoch_fn, *args)
-                with spans.span("epoch", epoch=boundary, step=cur_step + 1):
-                    with spans.span("dispatch"):
-                        state, hist = superepoch_fn(*args)
-                    metrics = {"loss": hist["loss"][-1, -1]}
-                    timer.tick(hist["loss"])
-                boundary_span = spans.begin(
-                    "boundary", epoch=boundary, step=cur_step + K * steps_per_epoch
-                )
-                # the boundary fetch: K epochs of losses (and probe rows)
-                # come back in one transfer of K*steps_per_epoch floats
-                with spans.span("boundary/loss_fetch"):
-                    hist = jax.device_get(hist)
-                losses = np.asarray(hist["loss"])
-                cur_step += K * steps_per_epoch
-                if detector is not None:
-                    detector.tick(cur_step, boundary)
-                    detector.pause()
-                if guard.preempt_requested:
-                    # same boundary-checkpoint contract as below; cur_step is
-                    # a multiple of steps_per_epoch so this lands as the
-                    # regular boundary checkpoint name
-                    seen_ns = time.monotonic_ns()
-                    end_boundary()
-                    with spans.span("preempt", epoch=boundary, step=cur_step) as pre:
-                        spans.add(
-                            "preempt/notice", guard.preempt_ns or seen_ns, seen_ns,
-                            parent=pre.id, epoch=boundary, step=cur_step,
-                        )
-                        path = os.path.join(
-                            save_dir,
-                            preempt_checkpoint_name(cur_step, steps_per_epoch, stem),
-                        )
-                        with spans.span("checkpoint"):
-                            with spans.span("checkpoint/drain"):
-                                timer.pause(metrics["loss"])
-                            timed_save(path)
-                        leave_preempted(path, boundary)
-                chunk_losses = [float(losses[j, -1]) for j in range(K)]
-                # checked_loss is the fault-injection seam on the single-epoch
-                # path; route the boundary loss through it so injected NaNs
-                # still poison superepoch runs
-                chunk_losses[-1] = guard.checked_loss(cur_step, chunk_losses[-1])
-                epoch_loss = chunk_losses[-1]
-                dt = time.perf_counter() - epoch_t0
-                # per-host telemetry on EVERY host (the fleet skew gauge
-                # divides per-host step times): all inputs are host floats
-                # already in hand, so this adds no device syncs anywhere
-                with spans.span("boundary/telemetry"):
-                    for j, e in enumerate(chunk):
-                        step_e = epoch_start_step + (j + 1) * steps_per_epoch
-                        telemetry.observe_epoch(
-                            e,
-                            epochs=epochs,
-                            step=step_e,
-                            steps=steps_per_epoch,
-                            seconds=dt / K,
-                            loss=chunk_losses[j],
-                            lr=float(schedule(max(step_e - 1, 0))),
-                        )
-                with spans.span("beat"):
-                    guard.beat(cur_step, boundary, loss=epoch_loss)
-                if any(not math.isfinite(l) for l in chunk_losses):
-                    # same rollback as the single-epoch path; under
-                    # superepochs every checkpoint lands on a K boundary, so
-                    # the resume point realigns (validated below — a stale
-                    # mid-chunk checkpoint from a K=1 run cannot seed this)
-                    first_bad = next(
-                        l for l in chunk_losses if not math.isfinite(l)
-                    )
-                    try:
-                        t_restore = time.perf_counter()
-                        restored, rpath = restore_checkpoint_with_fallback(
-                            save_dir, state
-                        )
-                    except CheckpointCorruptionError as e:
-                        raise PoisonedRun(str(e)) from e
-                    guard.record_rollback(first_bad, rpath)
-                    telemetry.observe_restore(time.perf_counter() - t_restore)
-                    state = restored
-                    cur_step = int(state.step)
-                    epoch, skip_steps = resume_point(cur_step, steps_per_epoch)
-                    _check_superepoch_resume(epoch)
-                    loss_history = [r for r in loss_history if r[0] < epoch]
-                    monitor_history = [r for r in monitor_history if r[0] < epoch]
-                    events.reseat(epoch)
-                    base_key = jax.random.fold_in(
-                        jax.random.key(seed + 1), guard.nan_rollbacks
-                    )
-                    continue
-                if is_logging_host():
-                    with spans.span("boundary/log"):
-                        lr_now = float(schedule(max(cur_step - 1, 0)))
-                        imgs_per_sec = (
-                            (cur_step - (start_epoch - 1) * steps_per_epoch)
-                            * global_batch / max(time.time() - t_start, 1e-9)
-                        )
-                        logger.info(
-                            "Epoch:%d/%d progress:%.3f loss:%.3f, lr:%.7f, "
-                            "imgs/sec:%.0f (superepoch of %d)",
-                            boundary, epochs, boundary / epochs, epoch_loss,
-                            lr_now, imgs_per_sec, K,
-                        )
-                # per-epoch rows reconstructed from the stacked metrics:
-                # results/events keep the exact shape K=1 produces
-                for j, e in enumerate(chunk):
-                    step_e = epoch_start_step + (j + 1) * steps_per_epoch
-                    loss_history.append([e, chunk_losses[j]])
-                    with spans.span("boundary/event"):
-                        events.emit(
-                            "epoch", epoch=e, step=step_e, loss=chunk_losses[j],
-                            seconds=round(dt / K, 6),
-                        )
-                    if probed[j]:
-                        monitor_val_acc = float(hist["monitor/val_acc"][j])
-                        telemetry.observe_val_acc(monitor_val_acc)
-                        if is_logging_host():
-                            logger.info(
-                                "Epoch:%d centroid probe: val top-1 %.4f "
-                                "(top-5 %.4f)",
-                                e, monitor_val_acc,
-                                float(hist["monitor/val_top_5_acc"][j]),
-                            )
-                        monitor_history.append([e, monitor_val_acc])
-                if (
-                    any(e % save_model_epoch == 0 for e in chunk)
-                    or boundary == epochs
-                ):
-                    path = os.path.join(save_dir, checkpoint_name(boundary, stem))
-                    with spans.span("checkpoint"):
-                        with spans.span("checkpoint/drain"):
-                            timer.pause(metrics["loss"])
-                        timed_save(path)
-                    events.emit("checkpoint", epoch=boundary, path=path)
-                    guard.after_save(boundary, path)
-                    flush_spans()  # the timer is paused for I/O already
-                    timer.resume()
-                with spans.span("boundary/results"):
-                    write_results(
-                        {
-                            "epochs": epochs,
-                            "save_dir": save_dir,
-                            "loss_history": loss_history,
-                            "monitor_history": monitor_history,
-                            "complete": False,
-                        }
-                    )
-                epoch += K
-                continue
-            if epoch_compile:
-                with spans.span("boundary/shuffle", epoch=epoch):
-                    idx_e = jnp.asarray(
-                        epoch_index_matrix(
-                            len(dataset), seed, epoch, steps_per_epoch, global_batch
-                        )
-                    )
-                end_boundary()
-                first_dispatch_next(
-                    epoch_fn, state, images_all, idx_e, base_key, cur_step
-                )
-                with spans.span("epoch", epoch=epoch, step=cur_step + 1):
-                    with spans.span("dispatch"):
-                        state, hist = epoch_fn(
-                            state, images_all, idx_e, base_key, cur_step
-                        )
-                    metrics = {"loss": hist["loss"][-1]}
-                    if not superepoch:
-                        # under superepochs this path only runs tail epochs;
-                        # the timer's tick unit is K epochs, so tail epochs
-                        # stay out
-                        timer.tick(hist["loss"])
-                    cur_step += steps_per_epoch
-                    if detector is not None:
-                        # one tick per epoch here: the detector's "step" unit
-                        # is whatever the host loop's unit of progress is
-                        detector.tick(cur_step, epoch)
-            else:
-                if led is None:
-                    batches, batch = open_epoch(epoch, skip_steps)
-                    skip_steps = 0
-                    end_boundary()
-                    if batch is not None:
-                        first_dispatch_next(
-                            step_fn, state, batch["image"],
+                if batch is not None and build_span is not None:
+                    # boot/build_step ends before the run's first dispatch,
+                    # the step's compilation (the sentry's boot/compile)
+                    # still inside it
+                    if hasattr(step_fn, "compile"):  # a WatchedFunction
+                        step_fn.compile(
+                            state, batch["image"],
                             jax.random.fold_in(base_key, cur_step),
                         )
-                else:
-                    # the epoch's first step went out before the last
-                    # boundary. A preemption seen since (in that step's beat,
-                    # or while the host sat in the fetch) checkpoints behind
-                    # that one step, below, before a second is dispatched
-                    (batches, _, _), led = led, None
-                    batch = None if guard.preempt_requested else next(batches, None)
-                with spans.span("epoch", epoch=epoch, step=cur_step + 1) as epoch_span:
-                    while batch is not None:
-                        train_step(batch, epoch)
-                        if guard.preempt_requested:
-                            break
-                        epoch_span.step = cur_step + 1  # what the next spans serve
-                        batch = next(batches, None)
+                    spans.end(build_span)
+                    build_span = None
+            else:
+                # the epoch's first step went out before the last
+                # boundary. A preemption seen since (in that step's beat,
+                # or while the host sat in the fetch) checkpoints behind
+                # that one step, below, before a second is dispatched
+                (batches, epoch_start_step, epoch_t0), led = led, None
+                batch = None if guard.preempt_requested else next(batches, None)
+            with spans.span("epoch", epoch=epoch, step=cur_step + 1) as epoch_span:
+                while batch is not None:
+                    train_step(batch, epoch)
+                    if guard.preempt_requested:
+                        break
+                    epoch_span.step = cur_step + 1  # what the next spans serve
+                    batch = next(batches, None)
             if detector is not None:
                 # epoch-boundary work (probe, checkpoint I/O, preempt saves)
                 # is not a step: disarm so it can never read as a stall, and
@@ -1161,7 +690,7 @@ def run_pretrain(cfg: Config) -> dict:
             # after the fetch it would queue behind a led step, and the host
             # would sit out that step in the middle of the boundary
             lr_pending = schedule(max(end_step - 1, 0))
-            if not epoch_compile and leads_over_boundary(
+            if leads_over_boundary(
                 epoch, epochs=epochs, save_model_epoch=save_model_epoch,
                 eval_every=eval_every,
             ):
@@ -1305,16 +834,10 @@ def run_pretrain(cfg: Config) -> dict:
     tracer.close(pending=metrics["loss"])
     throughput = timer.summary()
     if is_logging_host() and throughput["steps"] > 0:
-        # in epoch_compile mode the timer ticks once per EPOCH (once per K
-        # epochs under superepochs); report steps
-        timed_steps = throughput["steps"] * (
-            steps_per_epoch * (epochs_per_compile if superepoch else 1)
-            if epoch_compile else 1
-        )
         logger.info(
             "steady-state: %.0f imgs/sec (%.0f per chip) over %d steps",
             throughput["imgs_per_sec"], throughput["imgs_per_sec_per_chip"],
-            timed_steps,
+            throughput["steps"],
         )
     summary = {
         "final_loss": float(metrics["loss"]),

@@ -1,4 +1,4 @@
-"""Multi-host dryrun WORKER: rendezvous + sharded residency + chunked ring.
+"""Multi-host dryrun WORKER: rendezvous + chunked ring.
 
 One JAX process of a (possibly) multi-process job. Run under
 ``simclr_tpu.launch`` (which exports the ``JAX_COORDINATOR_ADDRESS`` /
@@ -11,10 +11,7 @@ What it exercises, deliberately end to end:
 
   1. ``maybe_initialize_multihost`` — the real rendezvous path (honoring
      ``JAX_COORDINATOR_TIMEOUT_S`` so a wedged coordinator fails fast);
-  2. ``mesh.put_row_sharded`` — the epoch-compile residency upload; the
-     worker reports how many rows this process actually addresses, so the
-     driver can assert each host feeds ONLY its local mesh rows;
-  3. ``compress.grad_allreduce(..., overlap="chunked")`` — the chunked
+  2. ``compress.grad_allreduce(..., overlap="chunked")`` — the chunked
      ppermute ring across the full global mesh, int8 wire format, with the
      per-device key convention the train step uses.
 
@@ -26,7 +23,7 @@ must reproduce the 1-process 8-device run bitwise. That is the parity the
 Prints exactly one JSON line from process 0:
 
     {"worker": "multihost_dryrun", "process_count": N, "n_devices": D,
-     "checksum": ..., "local_rows": ..., "expected_local_rows": ...}
+     "checksum": ..., "local_total": ...}
 """
 
 from __future__ import annotations
@@ -39,45 +36,23 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from simclr_tpu.parallel import compress
-from simclr_tpu.parallel.mesh import (
-    DATA_AXIS,
-    MeshSpec,
-    create_mesh,
-    put_row_sharded,
-)
+from simclr_tpu.parallel.mesh import DATA_AXIS, MeshSpec, create_mesh
 from simclr_tpu.parallel.multihost import maybe_initialize_multihost
 from simclr_tpu.utils.platform import configure_compilation_cache
 
-# dataset rows per data shard; small enough to run anywhere, large enough
-# that a wrong row block changes the checksum
-ROWS_PER_SHARD = 8
-ROW_WIDTH = 16
 COMM_CHUNKS = 3  # non-divisible into the flat length: exercises the tail
 
 
 def run() -> dict:
     maybe_initialize_multihost()
     mesh = create_mesh(MeshSpec(data=-1, model=1))
-    n_data = mesh.shape[DATA_AXIS]
 
-    # deterministic "dataset": row r is r, r+1, ... — any misrouted block
-    # shifts the per-shard sums and breaks parity
-    n_rows = ROWS_PER_SHARD * n_data
-    rows = (
-        np.arange(n_rows * ROW_WIDTH, dtype=np.float32).reshape(n_rows, ROW_WIDTH)
-        / n_rows
-    )
-    resident = put_row_sharded(rows, mesh)
-    local_rows = sum(s.data.shape[0] for s in resident.addressable_shards)
-    expected_local = ROWS_PER_SHARD * len(
-        [d for d in mesh.devices.flat if d.process_index == jax.process_index()]
-    )
-
-    def body(local_block):
-        # a gradient-shaped vector built from THIS shard's resident rows and
-        # logical index — physical device/process placement cancels out
+    def body():
+        # a gradient-shaped vector built from THIS shard's logical index
+        # alone — physical device/process placement cancels out, and a
+        # misrouted hop shifts the reduced vector and breaks parity
         i = jax.lax.axis_index(DATA_AXIS)
-        g = jnp.sum(local_block) * jnp.linspace(
+        g = (1.0 + i.astype(jnp.float32)) * jnp.linspace(
             -1.0, 1.0, 257, dtype=jnp.float32
         ) + 0.01 * i.astype(jnp.float32)
         key = jax.random.fold_in(jax.random.key(0), i)
@@ -90,11 +65,11 @@ def run() -> dict:
 
     fn = jax.jit(
         jax.shard_map(
-            body, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS),
+            body, mesh=mesh, in_specs=(), out_specs=P(DATA_AXIS),
             check_vma=False,
         )
     )
-    per_shard = fn(resident)
+    per_shard = fn()
     # per-shard sums of a replica-identical result; fetch this process's
     # addressable piece and psum on host via the replicated total
     checksum = float(jnp.sum(per_shard.addressable_shards[0].data))
@@ -109,8 +84,6 @@ def run() -> dict:
         # vector, so shard 0's sum IS the global checksum on every process
         "checksum": checksum,
         "local_total": total,
-        "local_rows": int(local_rows),
-        "expected_local_rows": int(expected_local),
     }
 
 

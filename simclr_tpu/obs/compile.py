@@ -217,28 +217,24 @@ class CompileSentry:
         fingerprint: str = "",
         flops: float = 0.0,
         bytes_accessed: float = 0.0,
-        steps_per_call: int = 1,
         warm: bool = False,
         cache_hit: bool | None = None,
     ) -> dict:
         """Book one observed compilation of executable ``name``.
 
-        ``steps_per_call`` normalizes cost for epoch-compiled programs (one
-        executable runs a whole epoch's scan) so per-step cost gauges and
-        the MFU drift compare like with like. ``warm=True`` marks a
+        Every watched executable runs one step per call, so its cost is the
+        per-step cost the MFU gauge divides. ``warm=True`` marks a
         post-warmup compilation and fires the alarm path. ``cache_hit``
         says whether JAX's persistent compilation cache supplied the
         executable (``seconds`` is then lowering plus a load, not a
         compile); ``None`` when the caller cannot tell.
         """
-        steps = max(int(steps_per_call), 1)
         record = {
             "name": str(name),
             "seconds": float(seconds),
             "fingerprint": fingerprint,
             "flops": float(flops),
             "bytes_accessed": float(bytes_accessed),
-            "steps_per_call": steps,
             "recompile": bool(warm),
         }
         known = {} if cache_hit is None else {"cache_hit": bool(cache_hit)}
@@ -252,8 +248,8 @@ class CompileSentry:
             self.telemetry.record_compile(seconds)
             self.telemetry.observe_xla_cost(
                 name,
-                flops_per_step=record["flops"] / steps,
-                bytes_per_step=record["bytes_accessed"] / steps,
+                flops_per_step=record["flops"],
+                bytes_per_step=record["bytes_accessed"],
             )
         if self.events is not None:
             self.events.emit(
@@ -309,9 +305,9 @@ class CompileSentry:
         except Exception as e:  # a backend without as_text, a full disk
             logger.warning("%s not written: %r", STEP_SCOPES_NAME, e)
 
-    def watch(self, fn, name: str, *, steps_from_args=None):
+    def watch(self, fn, name: str):
         """Wrap a jitted callable so its every compilation is observed."""
-        return WatchedFunction(fn, name, self, steps_from_args=steps_from_args)
+        return WatchedFunction(fn, name, self)
 
 
 class WatchedFunction:
@@ -330,21 +326,12 @@ class WatchedFunction:
     functions it wraps are used); the sentry's own bookkeeping is locked.
     """
 
-    def __init__(self, fn, name: str, sentry: CompileSentry, *, steps_from_args=None):
+    def __init__(self, fn, name: str, sentry: CompileSentry):
         self._fn = fn
         self.name = str(name)
         self._sentry = sentry
-        self._steps_from_args = steps_from_args
         self._cache: dict = {}
         self._calls = 0
-
-    def _steps_per_call(self, args) -> int:
-        if self._steps_from_args is None:
-            return 1
-        try:
-            return max(int(self._steps_from_args(args)), 1)
-        except Exception:
-            return 1
 
     def __call__(self, *args):
         entry = self._cache.get(args_signature(args))
@@ -366,7 +353,6 @@ class WatchedFunction:
             self.name,
             seconds=clock() - t0,
             warm=self._calls > 0,
-            steps_per_call=self._steps_per_call(args),
         )
         self._cache[args_signature(args)] = self._fn
         self._calls += 1
@@ -399,7 +385,6 @@ class WatchedFunction:
                 flops=flops,
                 bytes_accessed=bytes_accessed,
                 warm=self._calls > 0,
-                steps_per_call=self._steps_per_call(args),
                 cache_hit=cache_hit,
             )
         self._cache[sig] = compiled

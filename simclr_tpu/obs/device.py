@@ -1,9 +1,6 @@
-"""Live HBM accounting: per-device allocator gauges, preflight drift, OOM
-forensics.
+"""Live HBM accounting: per-device allocator gauges and OOM forensics.
 
-The epoch-compile preflight (``parallel/steps.py``) does analytic HBM math
-once at startup; this module supplies the live ground truth. A
-:class:`DeviceMonitor` attached to the telemetry registry samples
+A :class:`DeviceMonitor` attached to the telemetry registry samples
 ``device.memory_stats()`` for every local device **at render time** — i.e.
 on the exporter's handler thread, per scrape. ``memory_stats`` is a
 host-side allocator query (no device sync, no dispatch), so continuous
@@ -116,28 +113,11 @@ def sample_memory_stats(device) -> dict | None:
 
 class DeviceMonitor:
     """Per-device HBM sampler rendered into the ``/metrics`` payload.
-
-    ``expected_resident_bytes`` is the analytic per-chip dataset footprint
-    the epoch-compile preflight computed (``check_epoch_compile_
-    preconditions``); when present, the drift gauge reports measured live
-    bytes minus that analytic value — the preflight's reconciliation
-    against ground truth. Thread-safe: scrapes arrive on exporter handler
-    threads.
+    Thread-safe: scrapes arrive on exporter handler threads.
     """
 
-    def __init__(
-        self,
-        *,
-        events=None,
-        expected_resident_bytes: int | None = None,
-        devices=None,
-    ):
+    def __init__(self, *, events=None, devices=None):
         self.events = events
-        self.expected_resident_bytes = (
-            int(expected_resident_bytes)
-            if expected_resident_bytes is not None
-            else None
-        )
         self._devices = devices
         self._lock = threading.Lock()
         self._peaks: dict[str, int] = {}
@@ -179,20 +159,6 @@ class DeviceMonitor:
         with self._lock:
             return self._high_watermark
 
-    def drift_bytes(self, samples: dict[str, dict]) -> int | None:
-        """Measured live bytes minus the analytic preflight footprint.
-
-        Uses the first sampled device's ``bytes_in_use`` (the preflight's
-        budget math is per chip). None when either side is unknown.
-        """
-        if self.expected_resident_bytes is None or not samples:
-            return None
-        first = next(iter(samples.values()))
-        in_use = first.get("bytes_in_use")
-        if in_use is None:
-            return None
-        return int(in_use - self.expected_resident_bytes)
-
     def _maybe_emit(self, samples: dict[str, dict]) -> None:
         if self.events is None or not samples:
             return
@@ -207,8 +173,6 @@ class DeviceMonitor:
                 HBM_EVENT,
                 per_device=peaks,
                 high_watermark=watermark,
-                expected_resident_bytes=self.expected_resident_bytes,
-                drift=self.drift_bytes(samples),
             )
         except Exception:
             pass
@@ -236,14 +200,6 @@ class DeviceMonitor:
             "# TYPE simclr_train_hbm_high_watermark_bytes gauge\n"
             f"simclr_train_hbm_high_watermark_bytes {self.high_watermark_bytes:g}\n"
         )
-        drift = self.drift_bytes(samples)
-        if drift is not None:
-            parts.append(
-                "# HELP simclr_train_hbm_preflight_drift_bytes Measured live "
-                "bytes minus the analytic epoch-compile preflight footprint\n"
-                "# TYPE simclr_train_hbm_preflight_drift_bytes gauge\n"
-                f"simclr_train_hbm_preflight_drift_bytes {drift:g}\n"
-            )
         return "".join(parts)
 
 
@@ -288,12 +244,8 @@ def maybe_dump_oom_profile(
     return path
 
 
-def maybe_monitor(
-    cfg, *, events=None, expected_resident_bytes=None
-) -> DeviceMonitor | None:
+def maybe_monitor(cfg, *, events=None) -> DeviceMonitor | None:
     """Config-gated constructor used by the trainers (process 0 only)."""
     if not bool(cfg.select("telemetry.hbm", True)):
         return None
-    return DeviceMonitor(
-        events=events, expected_resident_bytes=expected_resident_bytes
-    )
+    return DeviceMonitor(events=events)

@@ -26,7 +26,7 @@ import time
 from simclr_tpu.obs.metrics import Counter, Gauge, Histogram, Summary
 
 # step-time bucket bounds (seconds): 1 ms (CIFAR-small steps on chip) up
-# through minutes (epoch_compile ticks once per epoch)
+# through minutes (a large model's step on a CPU test mesh)
 STEP_TIME_BUCKETS = (
     0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0,
@@ -198,9 +198,8 @@ class Telemetry:
     ) -> None:
         """Once per completed epoch: ``steps`` host-loop steps took
         ``seconds`` of wall clock (non-step work like eval/saves excluded by
-        the caller's timer pauses where it matters). Works identically for
-        per-step and ``epoch_compile`` loops — both know the epoch's step
-        count and duration without extra syncs."""
+        the caller's timer pauses where it matters). The loop knows the
+        epoch's step count and duration without extra syncs."""
         self.epoch.set(float(epoch))
         self.epochs_total.set(float(epochs))
         self.step.set(float(step))

@@ -163,55 +163,6 @@ def put_global_batch(local_rows: np.ndarray, sharding: NamedSharding) -> jax.Arr
     return jax.device_put(local_rows, sharding)
 
 
-def put_replicated(array, mesh: Mesh) -> jax.Array:
-    """Fully-replicated device placement, multi-host safe.
-
-    For uncommitted/numpy inputs ``jax.device_put`` supports replicated
-    shardings spanning non-addressable devices, and on multi-host it runs a
-    cross-process equality check on the value — exactly the invariant our
-    callers rely on (every process passes the same dataset / index
-    matrices), so divergent per-process data fails loudly instead of
-    training silently. Exercised under 2 real processes by the
-    epoch_compile launch tests.
-
-    Cost note: the multi-host equality check allgathers the value across
-    processes once per upload — fine for CIFAR-scale data (~150 MB uint8,
-    once per run). For much larger replicated uploads, switch to a
-    checksum-compare plus ``make_array_from_process_local_data`` (which
-    skips the value check) rather than paying an O(dataset x processes)
-    collective.
-    """
-    return jax.device_put(np.asarray(array), replicated_sharding(mesh))
-
-
-def put_row_sharded(array, mesh: Mesh) -> jax.Array:
-    """Row-sharded (over the ``data`` axis) device placement, multi-host safe.
-
-    The sharded-residency counterpart of :func:`put_replicated`
-    (``runtime.dataset_residency=sharded``): data-axis shard ``k`` holds the
-    contiguous row block ``[k*R, (k+1)*R)`` with ``R = ceil(N / n_data)`` —
-    per-chip residency is ~``N/n_data`` rows instead of ``N``. The tail is
-    zero-padded so every shard is equal-sized; padding rows are never
-    touched because epoch index matrices only draw from ``[0, N)``.
-
-    Every process passes the same full host array (the invariant shared
-    with ``put_replicated``); ``make_array_from_callback`` fills only the
-    shards this process addresses, so the upload is O(N / n_processes) per
-    host and — unlike ``put_replicated``'s multi-host equality check — sends
-    no cross-process traffic at all. Divergent per-process data is instead
-    caught downstream by the psum-assembled batches diverging loudly in the
-    loss (the same failure mode as divergent index matrices).
-    """
-    arr = np.asarray(array)
-    n_data = mesh.shape[DATA_AXIS]
-    pad = -len(arr) % n_data
-    if pad:
-        arr = np.concatenate([arr, np.zeros((pad, *arr.shape[1:]), arr.dtype)])
-    return jax.make_array_from_callback(
-        arr.shape, batch_sharding(mesh), lambda idx: arr[idx]
-    )
-
-
 def serve_replica_devices(replicas: int = -1) -> list[jax.Device]:
     """The serve tier's replica placement: the first ``replicas`` local devices.
 
@@ -242,39 +193,6 @@ def retrieval_mesh(devices: Sequence[jax.Device] | None = None) -> Mesh:
         MeshSpec(data=-1, model=1),
         devices=list(devices if devices is not None else jax.local_devices()),
     )
-
-
-def put_tree(tree, shardings):
-    """Place a host-computed pytree onto per-leaf shardings, multi-host safe.
-
-    The state-placement counterpart of :func:`put_replicated`. Single
-    process: plain ``jax.device_put``. Multi-process: ``device_put`` onto a
-    non-fully-addressable sharding runs jax's per-leaf cross-process
-    equality check, and a train-state pytree is dozens of differently-sized
-    leaves — on the gloo CPU backend those back-to-back differently-sized
-    broadcasts race in the TCP pairs and abort the process (``pair.cc``
-    enforce ``op.preamble.length <= op.nbytes``). State is derived from the
-    shared seed identically on every process, so the check buys nothing:
-    build each leaf with ``make_array_from_callback`` instead (this process
-    fills only the shards it addresses — zero cross-process traffic).
-    Divergent per-process state would surface loudly as diverging losses,
-    the same failure mode as divergent index matrices.
-
-    ``shardings`` is a matching pytree of shardings (or a single sharding
-    applied to every leaf).
-    """
-    if isinstance(shardings, jax.sharding.Sharding):
-        shardings = jax.tree.map(lambda _: shardings, tree)
-    if jax.process_count() == 1:
-        return jax.device_put(tree, shardings)
-
-    def place(x, s):
-        arr = np.asarray(x)
-        return jax.make_array_from_callback(
-            arr.shape, s, lambda idx, a=arr: a[idx]
-        )
-
-    return jax.tree.map(place, tree, shardings)
 
 
 def process_local_rows(n_global_rows: int) -> slice:

@@ -58,13 +58,11 @@ from simclr_tpu.ops.augment_pallas import validate_impl as validate_augment_impl
 from simclr_tpu.parallel import compress
 from simclr_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from simclr_tpu.parallel.steps import (
-    RESIDENCIES,
     _augment_two_views,
     _forward_fn,
     _global_sample_keys,
-    _local_resident_block,
     _ntxent,
-    _sharded_rows_global_batch,
+    _watch,
 )
 from simclr_tpu.parallel.train_state import TrainState
 
@@ -130,27 +128,36 @@ def _local_view(model, tp: int):
     return model.clone(head_hidden=hidden // tp, head_tp_axis=MODEL_AXIS)
 
 
-def _make_step_body(
+def make_pretrain_step_tp(
     model,
     tx: optax.GradientTransformation,
     mesh,
     *,
-    temperature: float,
-    strength: float,
-    out_size: int,
+    temperature: float = 0.5,
+    strength: float = 0.5,
     negatives: str = "global",
     fused: bool = False,
+    forward_mode: str = "two_pass",
     remat: bool = False,
+    out_size: int = 32,
     grad_allreduce: str = "exact",
     comm_overlap: str = "off",
     comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
     augment_impl: str = "xla",
-):
-    """The un-jitted TP step: shard_map'ed forward/backward + jit-level
-    optimizer update. Shared by the dispatch-per-step and epoch-compiled
-    paths so their numerics can never diverge (same pattern as
-    ``steps._make_local_pretrain_step``). ``remat`` rematerializes the
-    forward during backward exactly like ``steps._forward_fn``.
+    sentry=None,
+) -> Callable[[TrainState, jax.Array, jax.Array], tuple[TrainState, dict]]:
+    """Contrastive train step with the projection head tensor-parallel over
+    the ``model`` mesh axis: shard_map'ed forward/backward + jit-level
+    optimizer update.
+
+    Same contract and keywords as
+    :func:`simclr_tpu.parallel.steps.make_pretrain_step`:
+    ``(state, images_u8, rng) -> (state, metrics)``, watched by ``sentry`` as
+    ``pretrain_step``; ``state`` must be laid out with
+    :func:`tp_state_shardings`. With ``model=1`` this degenerates to the
+    data-parallel step (tested equivalent). ``forward_mode`` other than
+    ``"two_pass"`` is refused: concat's joint-BN deviation is orthogonal to
+    TP and stays out of this step's equivalence surface.
 
     ``grad_allreduce`` compresses the DATA-axis gradient all-reduce only
     (``parallel/compress.py``); the head's model-axis f/g collectives stay
@@ -162,10 +169,17 @@ def _make_step_body(
     and the gather phase forwards bytes verbatim, so model-axis replicas
     still dequantize identical gradients.
 
-    ``negatives``/``fused`` select the NT-Xent variant with the dp path's
-    exact dispatch (``steps._make_local_pretrain_step``) — the loss operates
-    on the per-data-shard embeddings the TP head psum-completes, so every
-    data-axis variant composes with head sharding unchanged."""
+    ``negatives``/``fused`` select the NT-Xent variant with the dp step's
+    dispatch (``steps._ntxent``) — the loss operates on the per-data-shard
+    embeddings the TP head psum-completes, so every data-axis variant
+    composes with head sharding unchanged."""
+    tp = mesh.shape[MODEL_AXIS]
+    if forward_mode != "two_pass":
+        raise ValueError(
+            f"mesh.model={tp} (tensor parallelism) does not combine with "
+            f"model.forward_mode={forward_mode!r}: only two_pass "
+            "(see docs/PERF.md, tensor-parallel support matrix)"
+        )
     compress.validate_mode(grad_allreduce)
     compress.validate_overlap(comm_overlap, comm_chunks)
     validate_augment_impl(augment_impl)
@@ -176,7 +190,6 @@ def _make_step_body(
             "loss.fused does not combine with negatives='ring' (the ring loss "
             "is already blockwise); use negatives='global' with fused"
         )
-    tp = mesh.shape[MODEL_AXIS]
     local_model = _local_view(model, tp)
     fwd = _forward_fn(local_model, remat)  # the dp step's forward/remat recipe
 
@@ -201,7 +214,7 @@ def _make_step_body(
             return loss, mut["batch_stats"]
 
         if comm_overlap == "async":
-            # staged backward (see steps._make_local_pretrain_step): explicit
+            # staged backward (see steps.make_pretrain_step): explicit
             # VJP + per-bucket ring assembly in grad_allreduce lets tail
             # buckets' data-axis rings issue under earlier backward matmuls
             loss, vjp_fn, new_stats = jax.vjp(loss_fn, params, has_aux=True)
@@ -246,263 +259,4 @@ def _make_step_body(
         )
         return new_state, {"loss": loss}
 
-    return step
-
-
-def make_pretrain_step_tp(
-    model,
-    tx: optax.GradientTransformation,
-    mesh,
-    *,
-    temperature: float = 0.5,
-    strength: float = 0.5,
-    out_size: int = 32,
-    negatives: str = "global",
-    fused: bool = False,
-    remat: bool = False,
-    grad_allreduce: str = "exact",
-    comm_overlap: str = "off",
-    comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
-    augment_impl: str = "xla",
-) -> Callable[[TrainState, jax.Array, jax.Array], tuple[TrainState, dict]]:
-    """Contrastive train step with the projection head tensor-parallel over
-    the ``model`` mesh axis (NT-Xent negatives per ``negatives``/``fused``,
-    defaulting to global rows over ``data``).
-
-    Same contract as :func:`simclr_tpu.parallel.steps.make_pretrain_step`:
-    ``(state, images_u8, rng) -> (state, metrics)``; ``state`` must be laid
-    out with :func:`tp_state_shardings`. With ``model=1`` this degenerates to
-    the data-parallel step (tested equivalent).
-    """
-    step = _make_step_body(
-        model, tx, mesh,
-        temperature=temperature, strength=strength, out_size=out_size,
-        negatives=negatives, fused=fused,
-        remat=remat, grad_allreduce=grad_allreduce,
-        comm_overlap=comm_overlap, comm_chunks=comm_chunks,
-        augment_impl=augment_impl,
-    )
-    return jax.jit(step, donate_argnums=(0,))
-
-
-def make_pretrain_epoch_fn_tp(
-    model,
-    tx: optax.GradientTransformation,
-    mesh,
-    *,
-    temperature: float = 0.5,
-    strength: float = 0.5,
-    out_size: int = 32,
-    negatives: str = "global",
-    fused: bool = False,
-    remat: bool = False,
-    residency: str = "replicated",
-    grad_allreduce: str = "exact",
-    comm_overlap: str = "off",
-    comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
-    augment_impl: str = "xla",
-) -> Callable[..., tuple[TrainState, dict]]:
-    """Epoch-compiled TP training: ``lax.scan`` over steps at the JIT level.
-
-    Same contract as :func:`simclr_tpu.parallel.steps.make_pretrain_epoch_fn`
-    — ``(state, images_all, idx_epoch, base_key, step0) -> (state,
-    {"loss": (steps,)})`` with ``images_all`` the full uint8 dataset, placed
-    per ``residency`` (replicated via ``mesh.put_replicated``, or row-sharded
-    over the data axis via ``mesh.put_row_sharded``). Structure differs from
-    the dp epoch fn by necessity: the dp path wraps the WHOLE scan in one
-    shard_map, but the TP optimizer update must run at the jit level (LARS
-    trust-ratio norms over the GLOBAL head arrays — see module docstring),
-    so here the scan lives at the jit level and each body iteration
-    re-enters shard_map for the forward/backward only. The per-step batch is
-    gathered by index at the jit level — replicated residency takes rows
-    directly and constrains to the data-axis sharding; sharded residency
-    re-enters shard_map to psum-assemble each shard's slice from the row
-    shards (``steps._sharded_rows_global_batch``), emerging already
-    data-sharded. RNG streams (``fold_in(base_key, step0 + i)``) match the
-    per-step loop exactly.
-    """
-    if residency not in RESIDENCIES:
-        raise ValueError(
-            f"residency must be one of {RESIDENCIES}, got {residency!r}"
-        )
-    step = _make_step_body(
-        model, tx, mesh,
-        temperature=temperature, strength=strength, out_size=out_size,
-        negatives=negatives, fused=fused,
-        remat=remat, grad_allreduce=grad_allreduce,
-        comm_overlap=comm_overlap, comm_chunks=comm_chunks,
-        augment_impl=augment_impl,
-    )
-    batched = NamedSharding(mesh, P(DATA_AXIS))
-
-    def _local_batch_from_shards(local_rows, idx_step):
-        full = _sharded_rows_global_batch(local_rows, idx_step)
-        shard = jax.lax.axis_index(DATA_AXIS)
-        n_local = idx_step.shape[0] // jax.lax.axis_size(DATA_AXIS)
-        return jax.lax.dynamic_slice_in_dim(full, shard * n_local, n_local)
-
-    gather_sharded = jax.shard_map(
-        _local_batch_from_shards,
-        mesh=mesh,
-        in_specs=(P(DATA_AXIS), P()),
-        out_specs=P(DATA_AXIS),
-        check_vma=False,
-    )
-
-    def epoch(state: TrainState, images_all, idx_epoch, base_key, step0):
-        def body(state, xs):
-            idx_step, i = xs
-            if residency == "replicated":
-                batch = jax.lax.with_sharding_constraint(
-                    jnp.take(images_all, idx_step, axis=0), batched
-                )
-            else:
-                batch = gather_sharded(images_all, idx_step)
-            return step(state, batch, jax.random.fold_in(base_key, step0 + i))
-
-        steps = idx_epoch.shape[0]
-        return jax.lax.scan(
-            body, state, (idx_epoch, jnp.arange(steps, dtype=jnp.int32))
-        )
-
-    return jax.jit(epoch, donate_argnums=(0,))
-
-
-def make_pretrain_superepoch_fn_tp(
-    model,
-    tx: optax.GradientTransformation,
-    mesh,
-    *,
-    temperature: float = 0.5,
-    strength: float = 0.5,
-    out_size: int = 32,
-    negatives: str = "global",
-    fused: bool = False,
-    remat: bool = False,
-    residency: str = "replicated",
-    grad_allreduce: str = "exact",
-    comm_overlap: str = "off",
-    comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
-    augment_impl: str = "xla",
-    monitor=None,
-) -> Callable[..., tuple[TrainState, dict]]:
-    """Superepoch-compiled TP training: an outer ``lax.scan`` over K epochs
-    around the :func:`make_pretrain_epoch_fn_tp` step scan, all at the JIT
-    level (the TP optimizer update needs GLOBAL arrays — module docstring).
-
-    Same calling convention as
-    :func:`simclr_tpu.parallel.steps.make_pretrain_superepoch_fn`:
-    ``(state, images_all, [train_labels, test_rows, test_labels,]
-    idx_super, [probe_mask,] base_key, step0) -> (state, stacked metrics)``
-    with ``idx_super`` the ``(K, steps, global_batch)`` index stack, RNG
-    folded on absolute step indices (``step0 + k*steps + i``), and — when
-    ``monitor`` is set — the in-program centroid probe gated per epoch by
-    ``probe_mask``. The probe re-enters ``shard_map`` with the TP param
-    specs; it only applies ``model.encode`` (encoder leaves are replicated
-    under TP), so the model-sharded head leaves pass through untouched.
-    """
-    if residency not in RESIDENCIES:
-        raise ValueError(
-            f"residency must be one of {RESIDENCIES}, got {residency!r}"
-        )
-    step = _make_step_body(
-        model, tx, mesh,
-        temperature=temperature, strength=strength, out_size=out_size,
-        negatives=negatives, fused=fused,
-        remat=remat, grad_allreduce=grad_allreduce,
-        comm_overlap=comm_overlap, comm_chunks=comm_chunks,
-        augment_impl=augment_impl,
-    )
-    batched = NamedSharding(mesh, P(DATA_AXIS))
-    array_spec = P() if residency == "replicated" else P(DATA_AXIS)
-
-    def _local_batch_from_shards(local_rows, idx_step):
-        full = _sharded_rows_global_batch(local_rows, idx_step)
-        shard = jax.lax.axis_index(DATA_AXIS)
-        n_local = idx_step.shape[0] // jax.lax.axis_size(DATA_AXIS)
-        return jax.lax.dynamic_slice_in_dim(full, shard * n_local, n_local)
-
-    gather_sharded = jax.shard_map(
-        _local_batch_from_shards,
-        mesh=mesh,
-        in_specs=(P(DATA_AXIS), P()),
-        out_specs=P(DATA_AXIS),
-        check_vma=False,
-    )
-
-    def _probe(state, images_all, train_labels, test_rows, test_labels):
-        def local(params, batch_stats, imgs, tr_labels, te_rows, te_labels):
-            return monitor(
-                params, batch_stats,
-                _local_resident_block(imgs, residency), tr_labels,
-                _local_resident_block(te_rows, residency), te_labels,
-            )
-
-        sharded = jax.shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(
-                tree_pspecs(state.params), tree_pspecs(state.batch_stats),
-                array_spec, P(), array_spec, P(),
-            ),
-            out_specs=P(),
-            check_vma=False,
-        )
-        return sharded(
-            state.params, state.batch_stats, images_all,
-            train_labels, test_rows, test_labels,
-        )
-
-    def superepoch(state: TrainState, *rest):
-        images_all = rest[0]
-        if monitor is not None:
-            train_labels, test_rows, test_labels = rest[1:4]
-            idx_super, probe_mask, base_key, step0 = rest[4:]
-        else:
-            idx_super, base_key, step0 = rest[1:]
-        steps = idx_super.shape[1]
-
-        def step_body(state, xs):
-            idx_step, i = xs
-            if residency == "replicated":
-                batch = jax.lax.with_sharding_constraint(
-                    jnp.take(images_all, idx_step, axis=0), batched
-                )
-            else:
-                batch = gather_sharded(images_all, idx_step)
-            return step(state, batch, jax.random.fold_in(base_key, step0 + i))
-
-        def epoch_body(state, xs):
-            if monitor is not None:
-                idx_epoch, k, pm = xs
-            else:
-                idx_epoch, k = xs
-            offsets = k * steps + jnp.arange(steps, dtype=jnp.int32)
-            state, hist = jax.lax.scan(step_body, state, (idx_epoch, offsets))
-            if monitor is not None:
-                probe = jax.lax.cond(
-                    pm,
-                    lambda s: _probe(
-                        s, images_all, train_labels, test_rows, test_labels
-                    ),
-                    lambda s: {
-                        name: jnp.full((), jnp.nan, jnp.float32)
-                        for name in monitor.metric_names
-                    },
-                    state,
-                )
-                hist = dict(hist) | {
-                    f"monitor/{name}": v for name, v in probe.items()
-                }
-            return state, hist
-
-        n_epochs = idx_super.shape[0]
-        epoch_ids = jnp.arange(n_epochs, dtype=jnp.int32)
-        xs = (
-            (idx_super, epoch_ids, probe_mask)
-            if monitor is not None
-            else (idx_super, epoch_ids)
-        )
-        return jax.lax.scan(epoch_body, state, xs)
-
-    return jax.jit(superepoch, donate_argnums=(0,))
+    return _watch(jax.jit(step, donate_argnums=(0,)), sentry, "pretrain_step")

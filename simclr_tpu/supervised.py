@@ -30,7 +30,7 @@ import numpy as np
 
 from simclr_tpu.config import Config, check_supervised_conf, load_config, resolve_save_dir
 from simclr_tpu.data.cifar import NUM_CLASSES, load_dataset
-from simclr_tpu.data.pipeline import EpochIterator, epoch_index_matrix
+from simclr_tpu.data.pipeline import EpochIterator
 from simclr_tpu.data.prefetch import prefetch
 from simclr_tpu.models.contrastive import SupervisedModel
 from simclr_tpu.obs.anomaly import maybe_detector
@@ -50,14 +50,10 @@ from simclr_tpu.parallel.mesh import (
     mesh_host_count,
     process_local_rows,
     put_global_batch,
-    put_replicated,
-    put_row_sharded,
     replicated_sharding,
     validate_per_device_batch,
 )
 from simclr_tpu.parallel.steps import (
-    check_epoch_compile_preconditions,
-    make_supervised_epoch_fn,
     make_supervised_eval_step,
     make_supervised_step,
 )
@@ -96,15 +92,6 @@ def _compute_dtype(cfg: Config):
 
 def run_supervised(cfg: Config) -> dict:
     check_supervised_conf(cfg)
-    if int(cfg.select("runtime.epochs_per_compile", 1) or 1) > 1:
-        # superepochs fold the pretrain monitor into the compiled program;
-        # the supervised loop validates/early-stops on host every epoch, so
-        # a K-epoch program has no correct place to put that logic
-        raise ValueError(
-            "runtime.epochs_per_compile > 1 (superepochs) applies to "
-            "contrastive pretraining only; supervised training validates "
-            "every epoch on host — set runtime.epochs_per_compile=1"
-        )
     seed = int(cfg.parameter.seed)
 
     comm_overlap = str(
@@ -221,53 +208,24 @@ def run_supervised(cfg: Config) -> dict:
         cfg, telemetry=telemetry, events=events, detector=detector
     )
 
-    epoch_compile = bool(cfg.select("runtime.epoch_compile", False))
     eval_step = make_supervised_eval_step(model, mesh)
     data_shard = batch_sharding(mesh)
-    # analytic per-chip resident dataset bytes from the epoch-compile
-    # preflight; the DeviceMonitor reconciles it against measured live HBM
-    resident_bytes = None
-    if epoch_compile:
-        # see main.py: sharded residency keeps N/n_data rows per data shard
-        residency = str(cfg.select("runtime.dataset_residency", "replicated"))
-        resident_bytes = check_epoch_compile_preconditions(
-            len(train_ds), global_batch, cfg.select("experiment.profile_dir"),
-            dataset_bytes=train_ds.images.nbytes + train_ds.labels.nbytes,
-            n_data_shards=mesh.shape[DATA_AXIS],
-            residency=residency,
-        )
-        epoch_fn = make_supervised_epoch_fn(
-            model, tx, mesh, strength=float(cfg.experiment.strength),
-            residency=residency,
-            grad_allreduce=str(cfg.select("parallel.grad_allreduce", "exact")),
-            comm_overlap=comm_overlap,
-            comm_chunks=comm_chunks,
-            augment_impl=str(cfg.select("runtime.augment_impl", "xla")),
-            sentry=sentry,
-        )
-        put_dataset = put_replicated if residency == "replicated" else put_row_sharded
-        images_all = put_dataset(train_ds.images, mesh)
-        labels_all = put_dataset(train_ds.labels, mesh)
-        train_iter = None
-    else:
-        train_step = make_supervised_step(
-            model, tx, mesh, strength=float(cfg.experiment.strength),
-            grad_allreduce=str(cfg.select("parallel.grad_allreduce", "exact")),
-            comm_overlap=comm_overlap,
-            comm_chunks=comm_chunks,
-            augment_impl=str(cfg.select("runtime.augment_impl", "xla")),
-            sentry=sentry,
-        )
-        train_iter = EpochIterator(
-            train_ds, global_batch, seed=seed, shuffle=True, sharding=data_shard,
-            gather_threads=int(cfg.parameter.num_workers),
-        )
+    train_step = make_supervised_step(
+        model, tx, mesh, strength=float(cfg.experiment.strength),
+        grad_allreduce=str(cfg.select("parallel.grad_allreduce", "exact")),
+        comm_overlap=comm_overlap,
+        comm_chunks=comm_chunks,
+        augment_impl=str(cfg.select("runtime.augment_impl", "xla")),
+        sentry=sentry,
+    )
+    train_iter = EpochIterator(
+        train_ds, global_batch, seed=seed, shuffle=True, sharding=data_shard,
+        gather_threads=int(cfg.parameter.num_workers),
+    )
     # live HBM accounting (obs/device.py): sampled per scrape from the
     # exporter thread — host-side allocator queries, zero device syncs
     # every host monitors its own local devices' HBM for the fleet view
-    monitor = maybe_monitor(
-        cfg, events=events, expected_resident_bytes=resident_bytes
-    )
+    monitor = maybe_monitor(cfg, events=events)
     if monitor is not None:
         telemetry.attach_device_monitor(monitor)
     # validation: no shuffle, keep every sample (reference drop_last=False,
@@ -399,24 +357,13 @@ def run_supervised(cfg: Config) -> dict:
             n_processes=n_hosts,
             global_batch=global_batch,
         )
-    if epoch_compile and skip_steps:
-        raise ValueError(
-            f"checkpoint at step {int(state.step)} is mid-epoch "
-            f"({skip_steps}/{steps_per_epoch} steps into epoch {start_epoch}) "
-            "and cannot resume under runtime.epoch_compile=true; resume with "
-            "runtime.epoch_compile=false"
-        )
     history = []
     t_start = time.time()
     # host-side mirror of state.step: avoids per-step device sync
     cur_step = (start_epoch - 1) * steps_per_epoch + skip_steps
     # steady-state training throughput like main.py's: validation sweeps and
-    # checkpoint I/O are pause()d out of the timed window. In epoch_compile
-    # mode one tick covers a whole epoch of steps.
-    timer = StepTimer(
-        global_batch * (steps_per_epoch if epoch_compile else 1),
-        warmup=1 if epoch_compile else 3,
-    )
+    # checkpoint I/O are pause()d out of the timed window
+    timer = StepTimer(global_batch)
     tracer = StepTraceWindow(
         cfg.select("experiment.profile_dir"),
         start=cur_step + 2,
@@ -439,44 +386,28 @@ def run_supervised(cfg: Config) -> dict:
         while epoch <= epochs:
             epoch_start_step = cur_step
             epoch_t0 = time.perf_counter()
-            if epoch_compile:
-                idx_e = jnp.asarray(
-                    epoch_index_matrix(
-                        len(train_ds), seed, epoch, steps_per_epoch, global_batch
-                    )
+            batches = train_iter.batches(epoch)
+            if skip_steps:
+                # mid-epoch resume: replay the epoch's deterministic batch
+                # order past the consumed prefix (step RNG folds on the
+                # absolute cur_step, so the continuation is exact)
+                batches = itertools.islice(batches, skip_steps, None)
+                skip_steps = 0
+            for batch in prefetch(batches):
+                tracer.tick(cur_step, pending=train_metrics["loss"])
+                step_rng = jax.random.fold_in(base_key, cur_step)
+                state, train_metrics = train_step(
+                    state, batch["image"], batch["label"], step_rng
                 )
-                state, epoch_metrics = epoch_fn(
-                    state, images_all, labels_all, idx_e, base_key, cur_step
-                )
-                train_metrics = {k: v[-1] for k, v in epoch_metrics.items()}
-                timer.tick(epoch_metrics["loss"])
-                cur_step += steps_per_epoch
+                timer.tick(train_metrics["loss"])
+                cur_step += 1
                 if detector is not None:
-                    # one tick per epoch: the loop's unit of progress here
+                    # BEFORE the beat: the beat is where fault injection
+                    # wedges, and the watchdog must already be armed
                     detector.tick(cur_step, epoch)
-            else:
-                batches = train_iter.batches(epoch)
-                if skip_steps:
-                    # mid-epoch resume: replay the epoch's deterministic batch
-                    # order past the consumed prefix (step RNG folds on the
-                    # absolute cur_step, so the continuation is exact)
-                    batches = itertools.islice(batches, skip_steps, None)
-                    skip_steps = 0
-                for batch in prefetch(batches):
-                    tracer.tick(cur_step, pending=train_metrics["loss"])
-                    step_rng = jax.random.fold_in(base_key, cur_step)
-                    state, train_metrics = train_step(
-                        state, batch["image"], batch["label"], step_rng
-                    )
-                    timer.tick(train_metrics["loss"])
-                    cur_step += 1
-                    if detector is not None:
-                        # BEFORE the beat: the beat is where fault injection
-                        # wedges, and the watchdog must already be armed
-                        detector.tick(cur_step, epoch)
-                    guard.beat(cur_step, epoch)
-                    if guard.preempt_requested:
-                        break
+                guard.beat(cur_step, epoch)
+                if guard.preempt_requested:
+                    break
             if detector is not None:
                 # validation/checkpoint work at the boundary is not a step:
                 # disarm so it can never read as a stall
@@ -599,11 +530,10 @@ def run_supervised(cfg: Config) -> dict:
     tracer.close(pending=train_metrics["loss"])
     throughput = timer.summary()
     if is_logging_host() and throughput["steps"] > 0:
-        timed_steps = throughput["steps"] * (steps_per_epoch if epoch_compile else 1)
         logger.info(
             "steady-state: %.0f imgs/sec (%.0f per chip) over %d steps",
             throughput["imgs_per_sec"], throughput["imgs_per_sec_per_chip"],
-            timed_steps,
+            throughput["steps"],
         )
     summary = {
         "imgs_per_sec_steady": throughput["imgs_per_sec"],

@@ -155,9 +155,9 @@ class RunGuard:
         loss: float | None = None,
         status: str = "running",
     ) -> None:
-        """Once per host-loop step (per epoch under ``epoch_compile`` — the
-        scan is one indivisible program). Fires the die/wedge faults first:
-        they must be able to kill the beat itself."""
+        """Once per host-loop step, and once more at each epoch boundary
+        with the epoch's loss. Fires the die/wedge faults first: they must
+        be able to kill the beat itself."""
         self.faults.maybe_die(step)
         self.faults.maybe_wedge(step)
         if self._beats:

@@ -125,46 +125,23 @@ class StepTraceWindow:
             self.enabled = False
 
 
-def time_step_loop(step, state, batches, rng, warmup: int, steps: int):
-    """Time ``steps`` invocations of a compiled ``(state, batch, rng) ->
-    (state, metrics)`` train step (``scripts/perf_explore.py``): warmup with
-    a loss fetch each iteration so nothing is left queued, then a timed
-    window closed by fetching the final loss. Returns
-    ``(seconds, final_loss, state)``.
-    """
-    metrics = None
-    for i in range(warmup):
-        state, metrics = step(state, batches[i % len(batches)], jax.random.fold_in(rng, i))
-        float(metrics["loss"])
-    t0 = time.perf_counter()
-    for i in range(steps):
-        state, metrics = step(
-            state, batches[i % len(batches)], jax.random.fold_in(rng, 100 + i)
-        )
-    final_loss = float(metrics["loss"])  # the fetch waits for the last step
-    dt = time.perf_counter() - t0
-    return dt, final_loss, state
-
-
 class StepTimer:
     """Steady-state throughput measurement for a compiled step.
 
-    Usage::
+    The first ``WARMUP`` ticks (the compiling step and the queue filling
+    behind it) are outside the window. Usage::
 
-        timer = StepTimer(global_batch, warmup=3)
+        timer = StepTimer(global_batch)
         for i in range(n):
             out = step(...)
-            timer.tick(out)        # blocks on the first post-warmup tick only
+            timer.tick(out)        # blocks on the WARMUP-th tick only
         print(timer.summary())
     """
 
-    def __init__(self, global_batch: int, warmup: int = 3):
-        if warmup < 1:
-            # timing starts at the warmup-th tick; with warmup=0 no tick
-            # would ever set t0 and summary() would silently report zeros
-            raise ValueError("warmup must be >= 1 (the first step compiles)")
+    WARMUP = 3  # timing starts at this tick
+
+    def __init__(self, global_batch: int):
         self.global_batch = global_batch
-        self.warmup = warmup
         self._count = 0
         self._t0: float | None = None
         self._timed_steps = 0
@@ -175,10 +152,10 @@ class StepTimer:
     def tick(self, device_output=None) -> None:
         self._count += 1
         self._last = device_output
-        if self._count == self.warmup:
+        if self._count == self.WARMUP:
             synchronize(device_output)
             self._t0 = time.perf_counter()
-        elif self._count > self.warmup:
+        elif self._count > self.WARMUP:
             self._timed_steps += 1
 
     def pause(self, device_output=None) -> None:

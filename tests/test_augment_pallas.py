@@ -12,12 +12,8 @@ The contract under test:
   own sampler would silently fork the augmentation distribution;
 - the ``augment_impl=xla`` default is BITWISE-identical to the pre-knob
   pipeline (the once-per-image ``to_float`` hoist is value-preserving);
-- dryrun-matrix loss parity: ``augment_impl=fused`` trains within 5e-2 of
-  xla at equal seeds for dp per-step, epoch_compile, superepoch K>1, and
-  dp×tp, across dataset residencies;
-- fused inside a superepoch still runs under
-  ``jax.transfer_guard("disallow")`` (the host-sync budget proof is
-  impl-independent);
+- loss parity: ``augment_impl=fused`` trains within 5e-2 of xla at equal
+  seeds for the dp, dp×tp and supervised steps;
 - config validation rejects unknown impls in both conf paths.
 """
 
@@ -29,7 +25,6 @@ import jax.numpy as jnp
 
 from simclr_tpu.data import augment as aug_mod
 from simclr_tpu.data.augment import simclr_augment_single, simclr_two_views, to_float
-from simclr_tpu.data.pipeline import epoch_index_matrix
 from simclr_tpu.ops.augment_pallas import (
     AUGMENT_IMPLS,
     _tile_and_pad,
@@ -42,15 +37,9 @@ from simclr_tpu.parallel.mesh import (
     DATA_AXIS,
     batch_sharding,
     create_mesh,
-    put_replicated,
-    put_row_sharded,
     replicated_sharding,
 )
-from simclr_tpu.parallel.steps import (
-    make_pretrain_epoch_fn,
-    make_pretrain_step,
-    make_pretrain_superepoch_fn,
-)
+from simclr_tpu.parallel.steps import make_pretrain_step
 from simclr_tpu.parallel.train_state import create_train_state
 from tests.helpers import TinyContrastive, random_images
 
@@ -58,9 +47,6 @@ PIXEL_ATOL = 1e-5
 LOSS_ATOL = 5e-2
 
 GLOBAL_BATCH = 16
-DATASET = 32
-STEPS_PER_EPOCH = DATASET // GLOBAL_BATCH
-K = 2
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +102,7 @@ def test_config_validates_augment_impl():
 
 def test_builders_reject_bad_impl():
     from simclr_tpu.parallel.steps import make_supervised_step
-    from simclr_tpu.parallel.tp import _make_step_body
+    from simclr_tpu.parallel.tp import make_pretrain_step_tp
 
     mesh = create_mesh()
     model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
@@ -128,9 +114,8 @@ def test_builders_reject_bad_impl():
     with pytest.raises(ValueError, match="augment_impl"):
         make_supervised_step(model, tx, mesh, strength=0.5, augment_impl="bogus")
     with pytest.raises(ValueError, match="augment_impl"):
-        _make_step_body(
-            model, tx, mesh, temperature=0.5, strength=0.5,
-            out_size=32, remat=False, augment_impl="bogus",
+        make_pretrain_step_tp(
+            model, tx, mesh, temperature=0.5, strength=0.5, augment_impl="bogus"
         )
 
 
@@ -278,7 +263,7 @@ def test_xla_impl_bitwise_identical_to_pre_knob_chain():
 
 
 # ---------------------------------------------------------------------------
-# dryrun matrix: fused trains like xla at equal seeds
+# fused trains like xla at equal seeds
 # ---------------------------------------------------------------------------
 
 def _tx():
@@ -290,12 +275,6 @@ def _init_state(model, tx, mesh):
         model, tx, jax.random.key(7), jnp.zeros((2, 32, 32, 3), jnp.float32)
     )
     return jax.device_put(state, replicated_sharding(mesh))
-
-
-def _put(images, mesh, residency):
-    if residency == "replicated":
-        return put_replicated(images, mesh)
-    return put_row_sharded(images, mesh)
 
 
 def _dp_step_losses(augment_impl, n_steps=3):
@@ -322,82 +301,6 @@ def test_dp_step_loss_parity_fused_vs_xla():
     fused = _dp_step_losses("fused")
     assert all(np.isfinite(xla)) and all(np.isfinite(fused))
     np.testing.assert_allclose(fused, xla, atol=LOSS_ATOL)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("residency", ["replicated", "sharded"])
-def test_epoch_compile_loss_parity_fused_vs_xla(residency):
-    mesh = create_mesh()
-    model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
-    tx = _tx()
-    images = random_images(DATASET, seed=3)
-    idx = jnp.asarray(
-        epoch_index_matrix(DATASET, 0, 1, STEPS_PER_EPOCH, GLOBAL_BATCH)
-    )
-    losses = {}
-    for impl in AUGMENT_IMPLS:
-        epoch_fn = make_pretrain_epoch_fn(
-            model, tx, mesh, temperature=0.5, strength=0.5,
-            residency=residency, augment_impl=impl,
-        )
-        state = _init_state(model, tx, mesh)
-        state, hist = epoch_fn(
-            state, _put(images, mesh, residency), idx, jax.random.key(11), 0
-        )
-        losses[impl] = np.asarray(hist["loss"])
-    assert np.isfinite(losses["fused"]).all()
-    np.testing.assert_allclose(losses["fused"], losses["xla"], atol=LOSS_ATOL)
-
-
-@pytest.mark.parametrize(
-    "residency",
-    ["replicated", pytest.param("sharded", marks=pytest.mark.slow)],
-)
-def test_superepoch_loss_parity_fused_vs_xla(residency):
-    """K>1 superepoch: same program shape, fused vs xla loss stack parity —
-    and (replicated) the superepoch host-sync budget proof holds with the
-    Pallas kernel inside the compiled program: with every input
-    device-resident, the warm fused superepoch re-executes under
-    ``jax.transfer_guard("disallow")``."""
-    mesh = create_mesh()
-    model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
-    tx = _tx()
-    rep = replicated_sharding(mesh)
-    images = random_images(DATASET, seed=6)
-    idx = jax.device_put(
-        jnp.asarray(
-            np.stack([
-                epoch_index_matrix(DATASET, 0, e, STEPS_PER_EPOCH, GLOBAL_BATCH)
-                for e in range(1, K + 1)
-            ])
-        ),
-        rep,
-    )
-    base_key = jax.device_put(jax.random.key(19), rep)
-    step0 = jax.device_put(jnp.zeros((), jnp.int32), rep)
-    images_d = _put(images, mesh, residency)
-    losses = {}
-    fns = {}
-    for impl in AUGMENT_IMPLS:
-        fns[impl] = make_pretrain_superepoch_fn(
-            model, tx, mesh, temperature=0.5, strength=0.5,
-            residency=residency, augment_impl=impl,
-        )
-        state = _init_state(model, tx, mesh)
-        state, hist = fns[impl](state, images_d, idx, base_key, step0)
-        losses[impl] = np.asarray(hist["loss"])
-        assert losses[impl].shape == (K, STEPS_PER_EPOCH)
-    assert np.isfinite(losses["fused"]).all()
-    np.testing.assert_allclose(losses["fused"], losses["xla"], atol=LOSS_ATOL)
-    if residency == "replicated":
-        # warm from the parity run above: a second fused call is pure
-        # device execution — no host transfers allowed (all inputs were
-        # device_put BEFORE the guard)
-        state2 = _init_state(model, tx, mesh)
-        with jax.transfer_guard("disallow"):
-            state2, hist = fns["fused"](state2, images_d, idx, base_key, step0)
-        guard_losses = np.asarray(hist["loss"])  # fetched OUTSIDE the guard
-        np.testing.assert_allclose(guard_losses, losses["fused"], atol=1e-6)
 
 
 @pytest.mark.slow

@@ -1,4 +1,4 @@
-"""The per-step loop's one-step lead over an epoch boundary (``main.py``):
+"""The host loop's one-step lead over an epoch boundary (``main.py``):
 the next epoch's first step is dispatched before the finished epoch's loss is
 fetched. The trajectory, the ``epoch`` events and the checkpoints are those of
 the loop that drains the device at every boundary; a NaN rollback and a
@@ -122,6 +122,54 @@ def _signal_in_fetch(monkeypatch, at_step):
         return real_checked(self, step, loss)
 
     monkeypatch.setattr(RunGuard, "checked_loss", checked_loss)
+
+
+# -- the step the loop dispatches ----------------------------------------------
+
+@pytest.mark.parametrize(
+    "model_axis, module, name",
+    [
+        # the dp builder is looked up on ``simclr_tpu.main`` when the step is
+        # built: benchmarks/references/first_steps.py replaces that attribute
+        (1, "simclr_tpu.main", "make_pretrain_step"),
+        (2, "simclr_tpu.parallel.tp", "make_pretrain_step_tp"),
+    ],
+)
+def test_the_step_is_built_in_one_place_from_one_set_of_keywords(
+    tmp_path, monkeypatch, model_axis, module, name
+):
+    import importlib
+    import inspect
+
+    from simclr_tpu.parallel.steps import make_pretrain_step
+    from simclr_tpu.parallel.tp import make_pretrain_step_tp
+
+    class Built(Exception):
+        pass
+
+    asked = {}
+
+    def builder(*args, **kwargs):
+        asked.update(args=args, kwargs=kwargs)
+        raise Built
+
+    _tiny(monkeypatch)
+    monkeypatch.setattr(importlib.import_module(module), name, builder)
+    with pytest.raises(Built):
+        _run(tmp_path, f"mesh.model={model_axis}", "parameter.epochs=1")
+    model, tx, mesh = asked["args"]
+    assert isinstance(model, TinyContrastive) and mesh.shape["model"] == model_axis
+    assert set(asked["kwargs"]) == {
+        "temperature", "strength", "negatives", "fused", "forward_mode", "remat",
+        "grad_allreduce", "comm_overlap", "comm_chunks", "augment_impl", "sentry",
+    }
+    assert asked["kwargs"]["sentry"] is not None
+    for real in (make_pretrain_step, make_pretrain_step_tp):
+        keywords = inspect.signature(real).parameters
+        assert set(asked["kwargs"]) <= set(keywords), real.__name__
+    assert list(inspect.signature(make_pretrain_step).parameters) == list(
+        inspect.signature(make_pretrain_step_tp).parameters
+    )
 
 
 # -- the same run, led and not -------------------------------------------------

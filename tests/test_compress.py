@@ -7,9 +7,9 @@ Three layers of evidence on the 8-device CPU mesh (conftest):
     elementwise worst-case error bound derived from the per-bucket scales;
   * drop-in equivalence — ``grad_allreduce`` against ``jax.lax.psum`` of the
     same pytree inside ``shard_map``, for every mode;
-  * train-path equivalence — the dp per-step, epoch-compiled, supervised,
-    and dp x tp steps each trained a few steps under ``bf16``/``int8``
-    land within tolerance of their ``exact`` trajectory.
+  * train-path equivalence — the dp, supervised and dp x tp steps each
+    trained a few steps under ``bf16``/``int8`` land within tolerance of
+    their ``exact`` trajectory.
 """
 
 import jax
@@ -37,11 +37,7 @@ from simclr_tpu.parallel.mesh import (
     batch_sharding,
     create_mesh,
 )
-from simclr_tpu.parallel.steps import (
-    make_pretrain_epoch_fn,
-    make_pretrain_step,
-    make_supervised_step,
-)
+from simclr_tpu.parallel.steps import make_pretrain_step, make_supervised_step
 from simclr_tpu.parallel.train_state import create_train_state
 from tests.helpers import TinyContrastive, TinySupervised, random_images
 
@@ -477,7 +473,7 @@ class TestAsyncRing:
 
 
 # ---------------------------------------------------------------------------
-# Train-path equivalence: dp per-step, epoch_compile, supervised
+# Train-path equivalence: dp step, supervised step
 # ---------------------------------------------------------------------------
 
 def _tx():
@@ -502,23 +498,6 @@ def _pretrain_losses(mode, n_steps=2, batch=16, **step_kwargs):
         state, metrics = step(state, images, jax.random.key(100 + i))
         losses.append(float(metrics["loss"]))
     return losses
-
-
-def _epoch_losses(mode, steps=2, batch=16, **step_kwargs):
-    mesh = create_mesh()
-    model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
-    tx = _tx()
-    state = create_train_state(
-        model, tx, jax.random.key(0), jnp.zeros((batch, 32, 32, 3), jnp.float32)
-    )
-    epoch_fn = make_pretrain_epoch_fn(
-        model, tx, mesh, temperature=0.5, strength=0.5, negatives="global",
-        grad_allreduce=mode, **step_kwargs,
-    )
-    images_all = jnp.asarray(random_images(steps * batch, seed=0))
-    idx = jnp.arange(steps * batch, dtype=jnp.int32).reshape(steps, batch)
-    _, hist = epoch_fn(state, images_all, idx, jax.random.key(9), 0)
-    return [float(x) for x in np.asarray(hist["loss"])]
 
 
 def _supervised_losses(mode, n_steps=2, batch=16, **step_kwargs):
@@ -549,7 +528,7 @@ def _supervised_losses(mode, n_steps=2, batch=16, **step_kwargs):
 TOL = {"bf16": 2e-2, "int8": 5e-2}
 
 # trajectory runs are deterministic, and several classes compare against
-# the same baselines (the exact dp/epoch/supervised losses) — share one
+# the same baselines (the exact dp/supervised losses) — share one
 # execution per signature, same economics as _CHUNKED_CACHE
 _TRAJ_CACHE: dict = {}
 
@@ -566,12 +545,6 @@ class TestTrainPathEquivalence:
     def test_dp_per_step(self, mode):
         exact = _cached(_pretrain_losses, "exact")
         got = _cached(_pretrain_losses, mode)
-        assert all(np.isfinite(got))
-        np.testing.assert_allclose(got, exact, atol=TOL[mode])
-
-    def test_epoch_compile(self, mode):
-        exact = _cached(_epoch_losses, "exact")
-        got = _cached(_epoch_losses, mode)
         assert all(np.isfinite(got))
         np.testing.assert_allclose(got, exact, atol=TOL[mode])
 
@@ -638,57 +611,6 @@ class TestTrainPathChunked:
         assert all(np.isfinite(got))
         np.testing.assert_allclose(got, off, atol=CHUNK_TOL[mode])
 
-    def test_epoch_compile(self):
-        off = _cached(_epoch_losses, "int8")
-        got = _cached(
-            _epoch_losses, "int8", comm_overlap="chunked", comm_chunks=3
-        )
-        assert all(np.isfinite(got))
-        np.testing.assert_allclose(got, off, atol=CHUNK_TOL["int8"])
-
-    # sharded is the multihost-relevant residency (put_row_sharded feeds
-    # only local rows); the replicated variant rides in the slow tier
-    @pytest.mark.parametrize("residency", [
-        "sharded", pytest.param("replicated", marks=pytest.mark.slow),
-    ])
-    def test_superepoch(self, residency):
-        """A chunked K=2 superepoch tracks the off superepoch for both
-        residency paths (the compiled-dataset program embeds the ring)."""
-        from simclr_tpu.data.pipeline import epoch_index_matrix
-        from simclr_tpu.parallel.mesh import put_replicated, put_row_sharded
-        from simclr_tpu.parallel.steps import make_pretrain_superepoch_fn
-
-        k, steps, batch = 2, 2, 16
-        dataset = steps * batch
-        mesh = create_mesh()
-        model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
-        images = random_images(dataset, seed=3)
-        put = put_replicated if residency == "replicated" else put_row_sharded
-        idx = jnp.asarray(
-            np.stack([
-                epoch_index_matrix(dataset, 0, e, steps, batch)
-                for e in range(1, 1 + k)
-            ])
-        )
-
-        def run(**kw):
-            tx = _tx()
-            state = create_train_state(
-                model, tx, jax.random.key(0),
-                jnp.zeros((batch, 32, 32, 3), jnp.float32),
-            )
-            fn = make_pretrain_superepoch_fn(
-                model, tx, mesh, temperature=0.5, strength=0.5,
-                residency=residency, grad_allreduce="int8", **kw,
-            )
-            _, hist = fn(state, put(images, mesh), idx, jax.random.key(9), 0)
-            return np.asarray(hist["loss"]).ravel()
-
-        off = run()
-        got = run(comm_overlap="chunked", comm_chunks=3)
-        assert np.all(np.isfinite(got))
-        np.testing.assert_allclose(got, off, atol=CHUNK_TOL["int8"])
-
 
 # ---------------------------------------------------------------------------
 # Train-path: comm_overlap=async under the staged backward (jax.vjp chain)
@@ -719,68 +641,12 @@ class TestTrainPathAsync:
         np.testing.assert_allclose(got, chunked, rtol=1e-5, atol=1e-6)
 
     @pytest.mark.slow
-    def test_epoch_compile(self):
-        off = _cached(_epoch_losses, "exact")
-        got = _epoch_losses("exact", comm_overlap="async", comm_chunks=3)
-        assert all(np.isfinite(got))
-        np.testing.assert_allclose(got, off, atol=CHUNK_TOL["exact"])
-
-    @pytest.mark.slow
-    def test_epoch_compile_int8(self):
-        chunked = _cached(
-            _epoch_losses, "int8", comm_overlap="chunked", comm_chunks=3
-        )
-        got = _epoch_losses("int8", comm_overlap="async", comm_chunks=3)
-        assert all(np.isfinite(got))
-        np.testing.assert_allclose(got, chunked, rtol=1e-5, atol=1e-6)
-
-    @pytest.mark.slow
     def test_supervised(self):
         """The supervised step's staged VJP carries a 3-tuple aux
         (stats, correct, n_local) — the async branch must thread it."""
         off = _cached(_supervised_losses, "exact")
         got = _supervised_losses("exact", comm_overlap="async", comm_chunks=3)
         assert all(np.isfinite(got))
-        np.testing.assert_allclose(got, off, atol=CHUNK_TOL["exact"])
-
-    @pytest.mark.slow
-    def test_superepoch(self):
-        """An async K=2 superepoch tracks the off superepoch (the
-        compiled-dataset scan embeds the staged backward + eager rings)."""
-        from simclr_tpu.data.pipeline import epoch_index_matrix
-        from simclr_tpu.parallel.mesh import put_row_sharded
-        from simclr_tpu.parallel.steps import make_pretrain_superepoch_fn
-
-        k, steps, batch = 2, 2, 16
-        dataset = steps * batch
-        mesh = create_mesh()
-        model = TinyContrastive(bn_cross_replica_axis=DATA_AXIS)
-        images = random_images(dataset, seed=3)
-        idx = jnp.asarray(
-            np.stack([
-                epoch_index_matrix(dataset, 0, e, steps, batch)
-                for e in range(1, 1 + k)
-            ])
-        )
-
-        def run(**kw):
-            tx = _tx()
-            state = create_train_state(
-                model, tx, jax.random.key(0),
-                jnp.zeros((batch, 32, 32, 3), jnp.float32),
-            )
-            fn = make_pretrain_superepoch_fn(
-                model, tx, mesh, temperature=0.5, strength=0.5,
-                residency="sharded", grad_allreduce="exact", **kw,
-            )
-            _, hist = fn(
-                state, put_row_sharded(images, mesh), idx, jax.random.key(9), 0
-            )
-            return np.asarray(hist["loss"]).ravel()
-
-        off = run()
-        got = run(comm_overlap="async", comm_chunks=3)
-        assert np.all(np.isfinite(got))
         np.testing.assert_allclose(got, off, atol=CHUNK_TOL["exact"])
 
 

@@ -210,6 +210,24 @@ def test_strict_overrides_reject_typos_but_allow_plus_prefix():
     assert cfg.parameter.extra == 5
 
 
+@pytest.mark.parametrize("name", ["config", "supervised_config"])
+@pytest.mark.parametrize(
+    "override",
+    [
+        "runtime.epoch_compile=true",
+        "runtime.epochs_per_compile=2",
+        "runtime.dataset_residency=sharded",
+    ],
+)
+def test_removed_epoch_scan_keys_are_refused_by_name(name, override):
+    """The epoch-scan family went in PR 31 with no alias: an old command line
+    fails at load, naming the key, before any device work (docs/MIGRATION.md)."""
+    key = override.split("=")[0]
+    with pytest.raises(ConfigError, match=f"override key '{key}' not in config"):
+        load_config(name, overrides=[override])
+    assert key.split(".")[1] not in load_config(name).runtime
+
+
 def test_scientific_notation_override_is_float():
     cfg = load_config("config", ["experiment.decay=1e-4"])
     assert cfg.experiment.decay == pytest.approx(1e-4)

@@ -741,28 +741,6 @@ class TestCrossTopologyResumeE2E:
         assert resumed.returncode != 0
         assert "epoch boundaries" in resumed.stderr
 
-    def test_superepoch_mid_boundary_resume_still_rejected(self, tmp_path):
-        """The superepoch indivisibility rule survives the elastic wiring: a
-        checkpoint OFF the K grid cannot seed a resume even when the
-        topology also changed — the superepoch rejection fires first."""
-        save_dir = str(tmp_path / "super")
-        proc = _run_pretrain(
-            ["experiment.batches=4", "parameter.epochs=1",
-             "parameter.warmup_epochs=1", "experiment.save_model_epoch=1",
-             f"experiment.save_dir={save_dir}"],
-            n_devices=8,
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        resumed = _run_pretrain(
-            ["experiment.batches=8", "parameter.epochs=4",
-             "parameter.warmup_epochs=1", "experiment.save_model_epoch=1",
-             "runtime.epoch_compile=true", "runtime.epochs_per_compile=2",
-             "experiment.resume=true", f"experiment.save_dir={save_dir}"],
-            n_devices=4,
-        )
-        assert resumed.returncode != 0
-        assert "mid-superepoch" in resumed.stderr
-
 
 @pytest.mark.slow
 class TestCrossTopologyResidency:

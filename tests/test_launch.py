@@ -226,34 +226,11 @@ def test_two_process_pretrain_with_monitor(tmp_path):
         timeout=600,
     )
     assert result.returncode == 0, result.stderr[-2000:]
-    assert result.stderr.count("centroid probe") == 1, result.stderr[-2000:]
-
-
-def test_two_process_epoch_compile(tmp_path):
-    """runtime.epoch_compile under 2 real processes: the replicated dataset
-    upload (mesh.put_replicated) must place onto devices this process cannot
-    address, with both processes deriving identical index matrices from the
-    seed (device_put cross-checks the values match)."""
-    save_dir = tmp_path / "ckpts"
-    result = _run_launcher(
-        [
-            "--nprocs", "2",
-            "--devices-per-proc", "2",
-            "-m", "simclr_tpu.main",
-            "runtime.epoch_compile=true",
-            "parameter.epochs=1",
-            "experiment.batches=8",
-            "parameter.warmup_epochs=0",
-            "experiment.save_model_epoch=1",
-            "experiment.synthetic_data=true",
-            "experiment.synthetic_size=64",
-            f"experiment.save_dir={save_dir}",
-        ],
-        timeout=900,  # two epoch-scan compiles on a 1-core host run ~7 min
-    )
-    assert result.returncode == 0, result.stderr[-2000:]
-    assert (save_dir / "epoch=1-cifar10").exists(), result.stderr[-2000:]
-    assert result.stderr.count("Epoch:1/1") == 1, result.stderr[-2000:]
+    # one process logs: the random-init anchor and the epoch's probe
+    for epoch in (0, 1):
+        assert result.stderr.count(f"Epoch:{epoch} centroid probe") == 1, (
+            result.stderr[-2000:]
+        )
 
 
 def test_two_process_tp_pretrain(tmp_path):
@@ -282,17 +259,15 @@ def test_two_process_tp_pretrain(tmp_path):
     assert result.stderr.count("Epoch:1/1") == 1, result.stderr[-2000:]
 
 
-def test_two_process_supervised_epoch_compile(tmp_path):
-    """Supervised epoch_compile under 2 real processes: covers the second
-    put_replicated call site (images AND labels), the on-device epoch scan,
-    and the masked distributed validation sweep multi-process."""
+def test_two_process_supervised(tmp_path):
+    """The supervised loop under 2 real processes: per-process row blocks of
+    images AND labels, and the masked distributed validation sweep."""
     save_dir = tmp_path / "ckpts"
     result = _run_launcher(
         [
             "--nprocs", "2",
             "--devices-per-proc", "2",
             "-m", "simclr_tpu.supervised",
-            "runtime.epoch_compile=true",
             "parameter.epochs=1",
             "experiment.batches=8",
             "parameter.warmup_epochs=0",
@@ -300,10 +275,12 @@ def test_two_process_supervised_epoch_compile(tmp_path):
             "experiment.synthetic_size=64",
             f"experiment.save_dir={save_dir}",
         ],
-        timeout=900,  # two epoch-scan compiles on a 1-core host run ~7 min
+        timeout=900,
     )
     assert result.returncode == 0, result.stderr[-2000:]
-    kept = [p for p in save_dir.iterdir() if p.name.startswith("epoch=")]
+    kept = [
+        p for p in save_dir.iterdir() if p.name.startswith("epoch=") and p.is_dir()
+    ]
     assert len(kept) == 1, result.stderr[-2000:]
 
 
@@ -432,7 +409,10 @@ def test_supervised_sigkill_then_resume(tmp_path):
     assert resumed["history"], "resumed run trained no epochs"
     assert resumed["history"][0]["epoch"] >= 2, "resume restarted from scratch"
     assert resumed["steps"] == 12
-    ckpts = [d for d in os.listdir(save_dir) if d.startswith("epoch=")]
+    ckpts = [
+        d for d in os.listdir(save_dir)
+        if d.startswith("epoch=") and os.path.isdir(os.path.join(save_dir, d))
+    ]
     assert len(ckpts) == 1  # best-only policy intact across the crash
 
 
@@ -441,10 +421,11 @@ def test_two_process_chunked_overlap_pretrain(tmp_path):
     hop of the chunked int8 ring crosses the process boundary (4+4 devices),
     so a rank bookkeeping bug in the ring schedule cannot hide behind
     single-process device shuffling. The run must train to a checkpoint,
-    not just rendezvous. (Also exercises mesh.put_tree: plain device_put of
-    the state pytree onto a non-addressable sharding runs per-leaf
-    equality-check broadcasts that crash gloo's TCP pairs at this device
-    count — pair.cc enforce op.preamble.length <= op.nbytes.)"""
+    not just rendezvous. (The state is born at its shardings by one compiled
+    program: a plain device_put of the state pytree onto a non-addressable
+    sharding runs per-leaf equality-check broadcasts that crash gloo's TCP
+    pairs at this device count — pair.cc enforce op.preamble.length <=
+    op.nbytes.)"""
     save_dir = tmp_path / "ckpts"
     result = _run_launcher(
         [
@@ -489,11 +470,6 @@ def test_multihost_dryrun_script_two_process_parity(tmp_path):
     assert payload.get("process_count") == 2, payload
     assert payload.get("parity") is True, payload
     assert "error" not in payload, payload
-    # residency preflight: each side fed exactly its addressable rows
-    for side in ("multi", "single"):
-        assert (
-            payload[side]["local_rows"] == payload[side]["expected_local_rows"]
-        ), payload
 
 
 def test_coordinator_timeout_env_fails_fast():
@@ -605,14 +581,13 @@ def test_proc_id_mode_runs_module_in_process(tmp_path):
     assert (save_dir / "epoch=1-cifar10").exists()
 
 
-def test_four_process_epoch_compile_and_resumed_eval(tmp_path):
+def test_four_process_pretrain_and_resumed_eval(tmp_path):
     """VERDICT r4 item 7 — the closest attainable rehearsal of the v4-32
     multi-host contract: 4 real processes x 2 devices each.
 
     Covers, at a process count where rank bookkeeping bugs can't hide as
-    binary symmetry: put_replicated's cross-process equality check (the
-    epoch_compile dataset upload allgather-compares all FOUR processes'
-    values), per-epoch checkpointing, then an eval sweep on the shared
+    binary symmetry: per-process row blocks of every batch, per-epoch
+    checkpointing, then an eval sweep on the shared
     filesystem interrupted and RESUMED — the skipped checkpoint carried
     verbatim from the results blob, the fingerprint surviving, only the
     missing checkpoint recomputed by all four processes in lockstep."""
@@ -624,7 +599,6 @@ def test_four_process_epoch_compile_and_resumed_eval(tmp_path):
             "--nprocs", "4",
             "--devices-per-proc", "2",
             "-m", "simclr_tpu.main",
-            "runtime.epoch_compile=true",
             "parameter.epochs=2",
             "experiment.batches=4",
             "parameter.warmup_epochs=0",
@@ -633,7 +607,7 @@ def test_four_process_epoch_compile_and_resumed_eval(tmp_path):
             "experiment.synthetic_size=64",
             f"experiment.save_dir={save_dir}",
         ],
-        timeout=1800,  # four epoch-scan compiles share the single host core
+        timeout=1800,  # four step compiles share the single host core
     )
     assert result.returncode == 0, result.stderr[-2000:]
     for epoch in (1, 2):

@@ -301,15 +301,14 @@ class TestTelemetry:
         return t
 
     def test_flops_are_xlas_count_of_the_newest_step_program(self):
-        """The MFU gauge divides the compiler's own FLOPs per device-step,
-        as the sentry normalises them (an epoch program's cost over its
-        steps), never a model's."""
+        """The MFU gauge divides the compiler's own FLOPs of the newest
+        watched program (every one runs one step a call), never a model's."""
         from simclr_tpu.obs.compile import CompileSentry
 
         t = self._make(step_flops=None)
         assert t.flops_per_step is None
         sentry = CompileSentry(telemetry=t)
-        sentry.record_compile("pretrain_epoch", seconds=1.0, flops=24e9, steps_per_call=24)
+        sentry.record_compile("supervised_step", seconds=1.0, flops=1e9)
         assert t.flops_per_step == pytest.approx(1e9)
         sentry.record_compile("pretrain_step", seconds=1.0, flops=3e9)
         assert t.flops_per_step == pytest.approx(3e9)

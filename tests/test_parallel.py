@@ -339,9 +339,9 @@ class TestStepTimer:
     def test_throughput_summary(self):
         from simclr_tpu.utils.profiling import StepTimer
 
-        timer = StepTimer(global_batch=32, warmup=2)
+        timer = StepTimer(global_batch=32)
         x = jnp.ones((4,))
-        for _ in range(6):
+        for _ in range(StepTimer.WARMUP + 4):
             timer.tick(x)
         summary = timer.summary()
         assert summary["steps"] == 4
@@ -353,20 +353,22 @@ class TestStepTimer:
 
         assert StepTimer(32).summary()["steps"] == 0
 
-    def test_warmup_zero_rejected(self):
+    def test_warmup_ticks_are_untimed(self):
         from simclr_tpu.utils.profiling import StepTimer
 
-        with pytest.raises(ValueError, match="warmup"):
-            StepTimer(32, warmup=0)
+        timer = StepTimer(32)
+        for _ in range(StepTimer.WARMUP):
+            timer.tick(jnp.ones((4,)))
+        assert timer.summary()["steps"] == 0
 
     def test_pause_excludes_interval(self):
         import time
 
         from simclr_tpu.utils.profiling import StepTimer
 
-        timer = StepTimer(global_batch=32, warmup=1)
+        timer = StepTimer(global_batch=32)
         x = jnp.ones((4,))
-        for _ in range(3):
+        for _ in range(StepTimer.WARMUP + 2):
             timer.tick(x)
         timer.pause(x)
         time.sleep(0.5)  # simulated checkpoint save

@@ -26,7 +26,6 @@ from simclr_tpu.parallel.mesh import (
     create_mesh,
 )
 from simclr_tpu.parallel.tp import (
-    make_pretrain_epoch_fn_tp,
     make_pretrain_step_tp,
     state_pspecs,
     tp_state_shardings,
@@ -109,7 +108,6 @@ def _run_steps(mesh, n_steps=2, per_device_batch=4, dtype=jnp.float32,
     return losses, jax.device_get(state.params)
 
 
-@pytest.mark.slow
 def test_tp_step_matches_degenerate_model_axis():
     """(data=2, model=4) == (data=2, model=1): same data-axis size keeps the
     augmentation key streams identical, so the ONLY difference is the head
@@ -134,7 +132,6 @@ def test_tp_step_matches_degenerate_model_axis():
         )
 
 
-@pytest.mark.slow
 def test_tp_entrypoint_and_eval_round_trip(tmp_path):
     """`mesh.model=2` end to end: pretrain on a (4,2) mesh, checkpoint
     (global-view arrays), then eval the checkpoint on the default (8,1)
@@ -231,65 +228,6 @@ def test_dp_checkpoint_resumes_under_tp(tmp_path):
 
 
 @pytest.mark.slow
-def test_tp_epoch_compile_matches_per_step():
-    """make_pretrain_epoch_fn_tp == the per-step TP loop: same batches (by
-    index matrix) and RNG streams (fold_in(base, step0+i)), so per-step
-    losses and final params must agree to float tolerance. Pins the one
-    structural difference — scan at jit level re-entering shard_map per
-    step, optimizer update outside shard_map both ways."""
-    mesh = create_mesh(MeshSpec(data=2, model=4))
-    model = ContrastiveModel(
-        base_cnn="resnet18", d=128, dtype=jnp.float32,
-        bn_cross_replica_axis=DATA_AXIS,
-    )
-    tx = lars(
-        warmup_cosine_schedule(0.1, 20, 2),
-        weight_decay=1e-4,
-        weight_decay_mask=simclr_weight_decay_mask,
-    )
-
-    def fresh_state():
-        s = create_train_state(
-            model, tx, jax.random.key(7), jnp.zeros((2, 32, 32, 3), jnp.float32)
-        )
-        return jax.device_put(s, tp_state_shardings(mesh, s))
-
-    images = np.random.default_rng(0).integers(
-        0, 256, size=(16, 32, 32, 3), dtype=np.uint8
-    )
-    idx = np.asarray(
-        [[3, 1, 8, 9, 12, 0, 5, 7], [2, 4, 6, 10, 11, 13, 14, 15]], np.int32
-    )
-    base = jax.random.key(42)
-
-    step = make_pretrain_step_tp(model, tx, mesh)
-    state_a = fresh_state()
-    losses_a = []
-    for i in range(idx.shape[0]):
-        batch = jax.device_put(images[idx[i]], batch_sharding(mesh))
-        state_a, m = step(state_a, batch, jax.random.fold_in(base, i))
-        losses_a.append(float(m["loss"]))
-
-    epoch_fn = make_pretrain_epoch_fn_tp(model, tx, mesh)
-    state_b, hist = epoch_fn(
-        fresh_state(), jnp.asarray(images), jnp.asarray(idx), base, 0
-    )
-    np.testing.assert_allclose(np.asarray(hist["loss"]), losses_a, rtol=1e-4)
-
-    flat_a = dict(
-        (jax.tree_util.keystr(p), v)
-        for p, v in jax.tree_util.tree_leaves_with_path(jax.device_get(state_a.params))
-    )
-    for path, leaf in jax.tree_util.tree_leaves_with_path(
-        jax.device_get(state_b.params)
-    ):
-        key = jax.tree_util.keystr(path)
-        np.testing.assert_allclose(
-            np.asarray(leaf), np.asarray(flat_a[key]), atol=2e-5, err_msg=key
-        )
-
-
-@pytest.mark.slow
 def test_tp_matches_degenerate_in_bf16():
     """bf16 dp-vs-tp sanity: whole-step losses track between a (2,4) and a
     (2,1) mesh with dtype=bfloat16. Coarse by nature (bf16 reorderings) —
@@ -355,7 +293,6 @@ def test_tp_output_psum_operand_is_f32():
     assert all(dt == jnp.float32 for dt in psum_in_dtypes), psum_in_dtypes
 
 
-@pytest.mark.slow
 def test_tp_remat_matches_non_remat():
     """model.remat under TP: jax.checkpoint recomputes the forward in the
     backward pass but must not change the math — one step, same state/batch/
@@ -393,90 +330,11 @@ def test_tp_remat_matches_non_remat():
     )
 
 
-@pytest.mark.slow
-def test_tp_epoch_compile_entrypoint(tmp_path):
-    """mesh.model=2 + runtime.epoch_compile=true end to end through main."""
-    from simclr_tpu.main import main as pretrain_main
-
-    save_dir = str(tmp_path / "tp-ec")
-    summary = pretrain_main(
-        [
-            "experiment.synthetic_data=true",
-            "experiment.synthetic_size=64",
-            "experiment.batches=4",
-            "mesh.model=2",
-            "runtime.epoch_compile=true",
-            "parameter.epochs=1",
-            "parameter.warmup_epochs=0",
-            "experiment.save_model_epoch=1",
-            f"experiment.save_dir={save_dir}",
-        ]
-    )
-    assert summary["steps"] == 64 // (4 * 4)
-    assert np.isfinite(summary["final_loss"])
-
-
-@pytest.mark.slow
-def test_tp_epoch_compile_sharded_residency_matches_replicated():
-    """dataset_residency=sharded on a (data=4, model=2) mesh reproduces the
-    replicated epoch fn's loss history and params while each data shard
-    holds only N/4 dataset rows (pinned on the uploaded array's sharding).
-    Exercises the shard_map psum-gather path under tensor parallelism."""
-    from simclr_tpu.parallel.mesh import put_row_sharded
-
-    mesh = create_mesh(MeshSpec(data=4, model=2))
-    model = ContrastiveModel(
-        base_cnn="resnet18", d=128, dtype=jnp.float32,
-        bn_cross_replica_axis=DATA_AXIS,
-    )
-    tx = lars(
-        warmup_cosine_schedule(0.1, 20, 2),
-        weight_decay=1e-4,
-        weight_decay_mask=simclr_weight_decay_mask,
-    )
-
-    def fresh_state():
-        s = create_train_state(
-            model, tx, jax.random.key(7), jnp.zeros((2, 32, 32, 3), jnp.float32)
-        )
-        return jax.device_put(s, tp_state_shardings(mesh, s))
-
-    n = 16
-    images = np.random.default_rng(0).integers(
-        0, 256, size=(n, 32, 32, 3), dtype=np.uint8
-    )
-    idx = np.asarray(
-        [[3, 1, 8, 9, 12, 0, 5, 7], [2, 4, 6, 10, 11, 13, 14, 15]], np.int32
-    )
-    base = jax.random.key(42)
-
-    runs = {}
-    for residency in ("replicated", "sharded"):
-        epoch_fn = make_pretrain_epoch_fn_tp(model, tx, mesh, residency=residency)
-        if residency == "replicated":
-            images_dev = jnp.asarray(images)
-        else:
-            images_dev = put_row_sharded(images, mesh)
-            assert images_dev.sharding.spec == P(DATA_AXIS)
-            assert images_dev.addressable_shards[0].data.shape[0] == n // 4
-        state, hist = epoch_fn(fresh_state(), images_dev, jnp.asarray(idx), base, 0)
-        runs[residency] = (np.asarray(hist["loss"]), jax.device_get(state.params))
-
-    np.testing.assert_allclose(
-        runs["sharded"][0], runs["replicated"][0], rtol=1e-4
-    )
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=2e-5
-        ),
-        runs["sharded"][1], runs["replicated"][1],
-    )
-
-
 def test_tp_rejects_unsupported_combinations():
-    """loss.negatives / loss.fused are now first-class under mesh.model>1
-    (they dispatch inside the tp step body like the dp path); the one
-    remaining gap in the support matrix is the concat forward."""
+    """loss.negatives / loss.fused are first-class under mesh.model>1 (they
+    dispatch inside the tp step like the dp step); the one remaining gap in
+    the support matrix is the concat forward, refused by the builder before
+    the entry point has compiled anything."""
     from simclr_tpu.main import run_pretrain
     from simclr_tpu.config import load_config
 
@@ -496,25 +354,66 @@ def test_tp_rejects_unsupported_combinations():
         run_pretrain(cfg)
 
 
-@pytest.mark.slow
-def test_tp_builders_validate_loss_variants_eagerly():
-    """The tp builders accept every dp loss variant and reject the same
-    invalid combinations as parallel/steps.py, at construction time (before
-    any trace) so a bad config fails fast, not mid-compile."""
+def _tiny_tp():
     mesh = create_mesh(MeshSpec(data=4, model=2))
     model = ContrastiveModel(
         base_cnn="resnet18", d=128, dtype=jnp.float32,
         bn_cross_replica_axis=DATA_AXIS,
     )
-    tx = lars(0.1)
+    return mesh, model, lars(0.1)
+
+
+def test_tp_builder_refuses_the_concat_forward():
+    """The refusal lives in the builder (it took ``main.py``'s place), with
+    the dp builder's keyword, and names the mesh and the option."""
+    mesh, model, tx = _tiny_tp()
+    with pytest.raises(ValueError, match=r"mesh\.model=2.*forward_mode='concat'"):
+        make_pretrain_step_tp(model, tx, mesh, forward_mode="concat")
+    make_pretrain_step_tp(model, tx, mesh, forward_mode="two_pass")
+
+
+def test_tp_builder_watches_its_own_jit_as_pretrain_step(tmp_path):
+    """``sentry=`` is the dp builder's keyword: the tp step's one compilation
+    lands as one ``compile`` event named ``pretrain_step``, the name the
+    benchmark and ``step_scopes.json`` go by, and a second call at the same
+    signature compiles nothing."""
+    from simclr_tpu.obs.compile import CompileSentry
+    from simclr_tpu.obs.events import EventLog, events_path, read_events
+
+    mesh, model, tx = _tiny_tp()
+    events = EventLog(str(tmp_path))
+    sentry = CompileSentry(events=events)
+    step = make_pretrain_step_tp(model, tx, mesh, sentry=sentry)
+    state = create_train_state(
+        model, tx, jax.random.key(7), jnp.zeros((2, 32, 32, 3), jnp.float32),
+        shardings=lambda abstract: tp_state_shardings(mesh, abstract),
+    )
+    batch = jax.device_put(
+        np.random.default_rng(3).integers(
+            0, 256, size=(8, 32, 32, 3), dtype=np.uint8
+        ),
+        batch_sharding(mesh),
+    )
+    for i in range(2):
+        state, metrics = step(state, batch, jax.random.key(i))
+    assert np.isfinite(float(metrics["loss"]))
+    compiles = [
+        e for e in read_events(events_path(str(tmp_path))) if e["event"] == "compile"
+    ]
+    assert [e["name"] for e in compiles] == ["pretrain_step"]
+    assert sentry.compiles == 1 and sentry.recompile_alarms == 0
+
+
+def test_tp_builders_validate_loss_variants_eagerly():
+    """The tp builder accepts every dp loss variant and rejects the same
+    invalid combinations as parallel/steps.py, at construction time (before
+    any trace) so a bad config fails fast, not mid-compile."""
+    mesh, model, tx = _tiny_tp()
     for negatives, fused in [
         ("global", False), ("local", False), ("ring", False),
         ("global", True), ("local", True),
     ]:
         make_pretrain_step_tp(
-            model, tx, mesh, negatives=negatives, fused=fused
-        )
-        make_pretrain_epoch_fn_tp(
             model, tx, mesh, negatives=negatives, fused=fused
         )
     with pytest.raises(ValueError, match="global|local|ring"):
@@ -523,7 +422,6 @@ def test_tp_builders_validate_loss_variants_eagerly():
         make_pretrain_step_tp(model, tx, mesh, negatives="ring", fused=True)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("negatives,fused", [
     ("local", False), ("ring", False), ("global", True),
 ])

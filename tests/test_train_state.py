@@ -17,7 +17,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from simclr_tpu.models.contrastive import ContrastiveModel, SupervisedModel
 from simclr_tpu.models.heads import LinearClassifier, NonLinearClassifier
 from simclr_tpu.ops.lars import lars, simclr_weight_decay_mask
-from simclr_tpu.parallel import mesh as mesh_lib
 from simclr_tpu.parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -137,15 +136,7 @@ def test_default_call_returns_what_callers_expect(built):
         _assert_same_bits(create_train_state(model, tx, rng, sample), state)
 
 
-@pytest.fixture
-def no_put_tree(monkeypatch):
-    def put_tree(tree, shardings):
-        raise AssertionError("the fresh state is placed by its own program")
-
-    monkeypatch.setattr(mesh_lib, "put_tree", put_tree)
-
-
-def test_every_leaf_is_born_replicated_over_the_mesh(built, no_put_tree):
+def test_every_leaf_is_born_replicated_over_the_mesh(built):
     model, tx, rng, plain, _ = built("contrastive", "resnet18")
     mesh = create_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
     replicated = replicated_sharding(mesh)
@@ -156,7 +147,7 @@ def test_every_leaf_is_born_replicated_over_the_mesh(built, no_put_tree):
     _assert_same_bits(state, plain)
 
 
-def test_tensor_parallel_shardings_come_from_the_abstract_state(built, no_put_tree):
+def test_tensor_parallel_shardings_come_from_the_abstract_state(built):
     model, tx, rng, plain, _ = built("contrastive", "resnet18")
     mesh = create_mesh(MeshSpec(data=2, model=2), devices=jax.devices()[:4])
     asked = []

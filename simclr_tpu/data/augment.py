@@ -13,8 +13,10 @@ that consumes them, so there is no per-worker CPU bottleneck and no H2D
 traffic beyond the raw images. All shapes are static: the data-dependent
 crop/resize is expressed as two (out, in) bilinear sampling matrices applied
 as matmuls, with traced crop-box coordinates and coordinates clamped inside
-the box (see :func:`random_resized_crop`), and the random-order color jitter
-uses ``lax.switch`` over op indices.
+the box (see :func:`random_resized_crop`); the matrices are built elementwise
+by comparing a source-index iota with each row's two tap indices, never by a
+scatter; and the random-order color jitter uses ``lax.switch`` over op
+indices.
 
 Distribution parity with torchvision (the likeliest silent-accuracy-gap
 source, SURVEY §7 hard part c):
@@ -136,6 +138,13 @@ def _axis_resize_weights(
     crop box, so border pixels replicate the box edge exactly as a
     crop-then-resize does — never bleeding into source pixels outside the
     sampled box.
+
+    Each row holds its two taps, ``1 - frac`` at ``i0`` and ``frac`` at
+    ``i1``, selected elementwise by comparing a source-index iota with the
+    tap indices and added in that order, so a clipped border where
+    ``i0 == i1`` sums both into one column. No scatter: under ``vmap`` a
+    scatter-add of the taps is one serial update per tap on the TPU (PERF.md,
+    Findings, PR 32); the comparison is one elementwise fusion.
     """
     centers = origin + (jnp.arange(out_size, dtype=jnp.float32) + 0.5) * (
         size / out_size
@@ -145,11 +154,10 @@ def _axis_resize_weights(
     frac = centers - i0
     i0 = jnp.clip(i0.astype(jnp.int32), 0, in_size - 1)
     i1 = jnp.clip(i0 + 1, 0, in_size - 1)
-    rows = jnp.arange(out_size)
-    weights = jnp.zeros((out_size, in_size), jnp.float32)
-    weights = weights.at[rows, i0].add(1.0 - frac)
-    weights = weights.at[rows, i1].add(frac)
-    return weights
+    src = jnp.arange(in_size)
+    return jnp.where(src == i0[:, None], (1.0 - frac)[:, None], 0.0) + jnp.where(
+        src == i1[:, None], frac[:, None], 0.0
+    )
 
 
 def random_resized_crop(

@@ -31,9 +31,10 @@ Layout, chosen for what Mosaic compiles (v5e, jax 0.9 / libtpu 0.0.34):
     slot instead of computing all four and selecting);
   * uint8 widens through int32 (Mosaic has no uint8 -> float32 cast);
   * the bilinear weights are rebuilt from the 4 crop-box scalars via iota
-    comparisons (equal to ``_axis_resize_weights``' scatter-add form,
-    including the clipped ``i0 == i1`` edge where both taps land on one
-    column and sum to 1). The column resize is one ``(W*3, out*3)`` matmul
+    comparisons, the form ``_axis_resize_weights`` has too (here on float
+    index planes, there on int32 indices; equal values, including the
+    clipped ``i0 == i1`` edge where both taps land on one column and sum
+    to 1). The column resize is one ``(W*3, out*3)`` matmul
     per channel whose result is that channel broadcast over each pixel's
     three lanes, so the color ops see r, g and b as same-shaped dense planes
     and no lane is ever sliced or shuffled; the horizontal flip is folded
@@ -160,10 +161,11 @@ def _bilinear(dst, src, origin, size, out_size: int, in_size: int):
     """``_axis_resize_weights(origin, size)[dst, src]``, elementwise over
     same-shaped float index arrays.
 
-    Comparison form of the scatter-add: both taps are written via equality
-    with the source index, so the clipped ``i0 == i1`` border case sums the
-    two taps into one column exactly like ``.at[].add`` does. Index values
-    are small exact integers in float32, so ``==`` is exact.
+    The same comparison form as the XLA path's: both taps are selected by
+    equality with the source index and added, so the clipped ``i0 == i1``
+    border case sums the two taps into one column. Indices stay float32
+    here (a Mosaic constraint); they are small exact integers, so ``==`` is
+    exact.
     """
     centers = origin + (dst + 0.5) * (size / out_size) - 0.5
     centers = jnp.clip(centers, origin, origin + size - 1.0)

@@ -173,6 +173,172 @@ class TestRandomOps:
         np.testing.assert_allclose(out, img, atol=1e-5)
 
 
+def _scatter_add_weights(origin, size, out_size, in_size):
+    """The scatter-add form ``_axis_resize_weights`` had before PR 32, kept
+    here as its plain reference: two taps per row added into a zero matrix."""
+    centers = origin + (jnp.arange(out_size, dtype=jnp.float32) + 0.5) * (
+        size / out_size
+    ) - 0.5
+    centers = jnp.clip(centers, origin, origin + size - 1.0)
+    i0 = jnp.floor(centers)
+    frac = centers - i0
+    i0 = jnp.clip(i0.astype(jnp.int32), 0, in_size - 1)
+    i1 = jnp.clip(i0 + 1, 0, in_size - 1)
+    rows = jnp.arange(out_size)
+    weights = jnp.zeros((out_size, in_size), jnp.float32)
+    weights = weights.at[rows, i0].add(1.0 - frac)
+    weights = weights.at[rows, i1].add(frac)
+    return weights
+
+
+def _boxes(in_size, seed, n_random=256):
+    """(origin, size) boxes on an axis of ``in_size`` pixels: every 1-pixel
+    box, the whole axis, boxes touching the first, the last and both
+    borders, and a seeded random sweep of in-bounds boxes."""
+    boxes = [(o, 1) for o in range(in_size)]
+    boxes += [(0, s) for s in range(1, in_size + 1)]
+    boxes += [(in_size - s, s) for s in range(1, in_size + 1)]
+    rng = np.random.default_rng(seed)
+    sizes = rng.integers(1, in_size + 1, n_random)
+    origins = np.floor(rng.random(n_random) * (in_size - sizes + 1))
+    boxes += list(zip(origins, sizes))
+    boxes = np.asarray(boxes, dtype=np.float32)
+    return jnp.asarray(boxes[:, 0]), jnp.asarray(boxes[:, 1])
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in ``jaxpr``, sub-jaxprs (pjit, the switch's
+    branches) included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def _scatters(fn, *args):
+    return {
+        name
+        for name in _primitives(jax.make_jaxpr(fn)(*args).jaxpr)
+        if "scatter" in name
+    }
+
+
+def _uint8_batch(n, seed=0):
+    return jnp.asarray(
+        np.random.default_rng(seed).integers(0, 256, (n, 32, 32, 3)), dtype=jnp.uint8
+    )
+
+
+class TestAxisResizeWeights:
+    """``_axis_resize_weights`` builds its matrix by comparison (PR 32); the
+    scatter-add form it replaced is the reference it must equal to the bit."""
+
+    @pytest.mark.parametrize(
+        "out_size,in_size",
+        [(32, 32), (32, 13), (16, 32), (24, 40), (32, 1), (1, 32)],
+    )
+    def test_equals_scatter_add_reference_bitwise(self, out_size, in_size):
+        from simclr_tpu.data.augment import _axis_resize_weights
+
+        origin, size = _boxes(in_size, seed=out_size * 100 + in_size)
+
+        def batched(weights_fn):
+            fn = jax.vmap(lambda o, s: weights_fn(o, s, out_size, in_size))
+            return np.asarray(jax.jit(fn)(origin, size))
+
+        got, want = batched(_axis_resize_weights), batched(_scatter_add_weights)
+        assert got.shape == want.shape == (origin.shape[0], out_size, in_size)
+        assert got.dtype == want.dtype == np.float32
+        assert got.tobytes() == want.tobytes()
+        np.testing.assert_allclose(got.sum(-1), 1.0, atol=1e-6)
+        # at most two taps a row, and none outside the box
+        assert (np.count_nonzero(got, axis=-1) <= 2).all()
+        src = np.arange(in_size)
+        lo = np.asarray(origin)[:, None, None]
+        hi = lo + np.asarray(size)[:, None, None]
+        assert (got[np.broadcast_to((src < lo) | (src >= hi), got.shape)] == 0).all()
+
+    def test_equals_scatter_add_reference_unbatched(self):
+        # one box at a time, not under vmap: size 1, the whole axis, both
+        # borders, an interior box, and an up- and a down-sampling resize
+        from simclr_tpu.data.augment import _axis_resize_weights
+
+        for origin, size, out_size, in_size in [
+            (0.0, 1.0, 32, 32), (31.0, 1.0, 32, 32), (0.0, 32.0, 32, 32),
+            (0.0, 7.0, 32, 32), (25.0, 7.0, 32, 32), (5.0, 13.0, 32, 32),
+            (3.0, 20.0, 8, 32), (0.0, 5.0, 32, 5),
+        ]:
+            got = _axis_resize_weights(
+                jnp.float32(origin), jnp.float32(size), out_size, in_size
+            )
+            want = _scatter_add_weights(
+                jnp.float32(origin), jnp.float32(size), out_size, in_size
+            )
+            assert np.asarray(got).tobytes() == np.asarray(want).tobytes(), (
+                origin, size, out_size, in_size,
+            )
+
+    def test_clipped_border_sums_both_taps_into_one_column(self):
+        # a 1-pixel box: every row's two taps fall on the one column
+        from simclr_tpu.data.augment import _axis_resize_weights
+
+        w = np.asarray(
+            _axis_resize_weights(jnp.float32(31.0), jnp.float32(1.0), 32, 32)
+        )
+        assert (w[:, 31] == 1.0).all() and (w[:, :31] == 0.0).all()
+
+    @pytest.mark.parametrize("chain", ["single", "two_views", "step"])
+    def test_augmentation_traces_no_scatter(self, chain):
+        """The serial op cannot come back unseen: neither the vmapped
+        per-example chain nor the step's two-view wrapper traces a scatter."""
+        from simclr_tpu.parallel.steps import _augment_two_views
+
+        images, key = _uint8_batch(8), jax.random.key(0)
+        if chain == "single":
+            found = _scatters(
+                jax.vmap(simclr_augment_single), jax.random.split(key, 8), to_float(images)
+            )
+        elif chain == "two_views":
+            found = _scatters(simclr_two_views, key, images)
+        else:
+            found = _scatters(
+                lambda k, x: _augment_two_views(k, x, 0.5, 32), key, images
+            )
+        assert found == set()
+
+    def test_scatter_detector_sees_the_reference_form(self, monkeypatch):
+        # the check above is not vacuous: with the scatter-add form patched
+        # back in, the same walk finds it, inside a jitted call's sub-jaxpr
+        from simclr_tpu.data import augment
+
+        monkeypatch.setattr(augment, "_axis_resize_weights", _scatter_add_weights)
+        found = _scatters(
+            jax.jit(jax.vmap(simclr_augment_single)),
+            jax.random.split(jax.random.key(0), 8), to_float(_uint8_batch(8)),
+        )
+        assert "scatter-add" in found
+
+    def test_whole_view_equals_scatter_add_reference_bytes(self, monkeypatch):
+        # the whole view, not only the matrix: 64 seeded keys through
+        # simclr_augment_single with and without the reference patched in
+        from simclr_tpu.data import augment
+
+        images = to_float(_uint8_batch(64, seed=3))
+        keys = jax.random.split(jax.random.key(32), 64)
+
+        def views():
+            return np.asarray(jax.jit(jax.vmap(simclr_augment_single))(keys, images))
+
+        got = views()
+        monkeypatch.setattr(augment, "_axis_resize_weights", _scatter_add_weights)
+        want = views()
+        assert got.shape == (64, 32, 32, 3)
+        assert got.tobytes() == want.tobytes()
+        assert not np.array_equal(got[0], got[1])
+
+
 class TestTwoViews:
     def test_views_are_independent_and_deterministic(self):
         imgs = jnp.asarray(

@@ -419,20 +419,57 @@ def check_pretrain_conf(cfg: Config) -> None:
     _require(e.lr > 0, "experiment.lr must be positive")
     _require(e.decay >= 0, "experiment.decay must be >= 0")
     _require(0.0 <= e.strength <= 1.0, "experiment.strength must be in [0, 1]")
+    from simclr_tpu.models.arch import ENCODERS, SEQUENCE_ENCODERS
+
     _require(
-        e.base_cnn in _supported_cnns(),
-        f"experiment.base_cnn must be {'|'.join(_supported_cnns())}, "
-        f"got {e.base_cnn!r}",
+        e.base_cnn in ENCODERS,
+        f"experiment.base_cnn must be {'|'.join(ENCODERS)}, got {e.base_cnn!r}",
     )
-    _require(
-        e.name in ("cifar10", "cifar100"),
-        f"experiment.name must be cifar10|cifar100, got {e.name!r}",
-    )
+    if e.base_cnn in SEQUENCE_ENCODERS:
+        _check_documents_conf(cfg)
+    else:
+        _require(
+            e.name in ("cifar10", "cifar100"),
+            f"experiment.name must be cifar10|cifar100 for the image encoder "
+            f"{e.base_cnn!r}, got {e.name!r}",
+        )
     _require(
         cfg.select("loss.negatives", "global") in ("global", "local", "ring"),
         "loss.negatives must be 'global', 'local', or 'ring'",
     )
     _check_runtime_conf(cfg)
+
+
+def _check_documents_conf(cfg: Config) -> None:
+    """A sequence encoder trains on token documents (``data/documents.py``),
+    whose views are span crops packed into rows (``experiment=smallthinker-docs``
+    has the keys)."""
+    e = cfg.experiment
+    _require(
+        e.name == "synthetic-docs",
+        f"the sequence encoder {e.base_cnn!r} takes token documents: "
+        f"experiment.name must be synthetic-docs, got {e.name!r} "
+        "(experiment=smallthinker-docs sets the group)",
+    )
+    crops = cfg.select("experiment.crop_lengths")
+    _require(
+        isinstance(crops, list) and crops
+        and all(isinstance(n, int) and n > 0 for n in crops),
+        f"experiment.crop_lengths must list a row's crop lengths, got {crops!r}",
+    )
+    _require(
+        e.batches % len(crops) == 0,
+        f"experiment.batches ({e.batches}) must fill rows of {len(crops)} crops",
+    )
+    _require(
+        0 < max(crops) <= int(e.document_tokens) and int(e.documents) > 0,
+        "experiment.document_tokens must hold the longest crop, and "
+        "experiment.documents be positive",
+    )
+    _require(
+        int(cfg.select("experiment.eval_every", 0) or 0) == 0,
+        "experiment.eval_every probes image classes; a sequence encoder has none",
+    )
 
 
 def _check_runtime_conf(cfg: Config) -> None:

@@ -56,6 +56,7 @@ from simclr_tpu.parallel.mesh import (
     validate_per_device_batch,
 )
 from simclr_tpu.parallel.steps import make_encode_step
+from simclr_tpu.models.arch import refuse_sequence_encoder
 from simclr_tpu.parallel.train_state import init_variables
 from simclr_tpu.utils.checkpoint import list_checkpoints_or_raise, restore_checkpoint
 from simclr_tpu.utils.fetch import fetch
@@ -76,6 +77,7 @@ def build_eval_model(cfg: Config) -> ContrastiveModel:
     (``/root/reference/eval.py:31-58``) — probes see full-precision
     features.
     """
+    refuse_sequence_encoder(str(cfg.experiment.base_cnn), "frozen-feature extraction")
     return ContrastiveModel(
         base_cnn=cfg.experiment.base_cnn, d=int(cfg.parameter.d),
         cifar_stem=True, dtype=jnp.float32,
@@ -90,6 +92,7 @@ def load_model_variables(ckpt_path: str) -> dict:
     whole train state; eval consumes only the model variables.
     """
     raw = restore_checkpoint(ckpt_path, None)
+    refuse_sequence_encoder(raw["params"], f"the checkpoint {ckpt_path} was asked for image features, which")
     # materialize to host numpy: orbax restores arrays WITH their saved
     # shardings, and a checkpoint written on a different mesh layout (e.g. a
     # tensor-parallel (data, model) run) would otherwise be rejected by this

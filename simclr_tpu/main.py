@@ -35,9 +35,12 @@ from simclr_tpu.config import (
     resolve_save_dir,
 )
 from simclr_tpu.data.cifar import load_dataset
+from simclr_tpu.data.documents import DATASET_NAME as DOCUMENTS, synthetic_documents
 from simclr_tpu.data.pipeline import EpochIterator
 from simclr_tpu.data.prefetch import prefetch
+from simclr_tpu.models.arch import SEQUENCE_ENCODERS
 from simclr_tpu.models.contrastive import ContrastiveModel
+from simclr_tpu.models.sequence import PackedView
 from simclr_tpu.obs.anomaly import maybe_detector
 from simclr_tpu.obs.compile import maybe_sentry
 from simclr_tpu.obs.device import maybe_dump_oom_profile, maybe_monitor
@@ -105,6 +108,53 @@ def build_model(cfg: Config) -> ContrastiveModel:
     )
 
 
+def load_train_rows(cfg: Config):
+    """The training split by ``experiment.name``: CIFAR images, or the
+    seeded token documents a sequence encoder trains on
+    (``data/documents.py``), over the encoder's held vocabulary slice."""
+    if cfg.experiment.name != DOCUMENTS:
+        return load_dataset(
+            cfg.experiment.name,
+            "train",
+            data_dir=cfg.select("experiment.data_dir"),
+            synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
+            synthetic_size=cfg.select("experiment.synthetic_size"),
+            synthetic_noise=cfg.select("experiment.synthetic_noise"),
+        )
+    return synthetic_documents(
+        int(cfg.experiment.documents), int(cfg.experiment.document_tokens),
+        SEQUENCE_ENCODERS[cfg.experiment.base_cnn].vocab_held,
+    )
+
+
+def sample_input(crop_lengths: tuple[int, ...]):
+    """Shapes of one model input, for the state's initialisation: two
+    images, or one packed row of token documents (``crop_lengths`` given)."""
+    if not crop_lengths:
+        return jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
+    row = jax.ShapeDtypeStruct((1, sum(crop_lengths)), jnp.int32)
+    return PackedView(
+        row, row, row, jax.ShapeDtypeStruct((1, len(crop_lengths)), jnp.int32)
+    )
+
+
+def expert_counters(metrics: dict) -> dict:
+    """A step's expert-layer counters as the ``epoch`` event carries them
+    (fetched with the loss; absent for an encoder without experts):
+    ``held_pairs``, the (token, expert) pairs the held experts computed in
+    the step; ``expert_load_max_over_mean``, the fullest held expert's pairs
+    over the mean held expert's, in the layer where that is largest."""
+    if "expert_load" not in metrics:
+        return {}
+    load = jax.device_get(metrics["expert_load"]).astype(float)  # (layers, held)
+    return {
+        "held_pairs": int(load.sum()),
+        "expert_load_max_over_mean": float(
+            (load.max(axis=1) / load.mean(axis=1).clip(min=1.0)).max()
+        ),
+    }
+
+
 def leads_over_boundary(
     epoch: int, *, epochs: int, save_model_epoch: int, eval_every: int
 ) -> bool:
@@ -145,14 +195,7 @@ def run_pretrain(cfg: Config) -> dict:
     global_batch = validate_per_device_batch(int(cfg.experiment.batches), mesh)
 
     with spans.span("boot/data"):
-        dataset = load_dataset(
-            cfg.experiment.name,
-            "train",
-            data_dir=cfg.select("experiment.data_dir"),
-            synthetic_ok=bool(cfg.select("experiment.synthetic_data", False)),
-            synthetic_size=cfg.select("experiment.synthetic_size"),
-            synthetic_noise=cfg.select("experiment.synthetic_noise"),
-        )
+        dataset = load_train_rows(cfg)
     boot_span = spans.begin("boot/state")  # optimizer, model, state: to run_start
 
     # Reference step accounting (drop_last truncation, main.py:76-80)
@@ -194,6 +237,9 @@ def run_pretrain(cfg: Config) -> dict:
 
     model = build_model(cfg)
     n_model = mesh.shape[MODEL_AXIS]
+    crop_lengths = tuple(
+        int(n) for n in cfg.select("experiment.crop_lengths", None) or ()
+    )
     if n_model > 1:
         # tensor-parallel layout from the start: head leaves sharded over the
         # model axis, everything else replicated (parallel/tp.py); also the
@@ -210,8 +256,7 @@ def run_pretrain(cfg: Config) -> dict:
     with spans.span("boot/state/init") as init_span:
         compiled_before = backend_compilations()
         state = create_train_state(
-            model, tx, init_key,
-            jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32),
+            model, tx, init_key, sample_input(crop_lengths),
             shardings=state_shardings,
         )
         init_span.n = backend_compilations() - compiled_before
@@ -358,6 +403,9 @@ def run_pretrain(cfg: Config) -> dict:
         # step through an instrumented AOT lower/compile path when set
         sentry=sentry,
     )
+    if crop_lengths:
+        # token documents: the views are span crops packed into rows
+        step_kwargs["crop_lengths"] = crop_lengths
     if n_model > 1:
         # tensor-parallel projection head over the model axis; what it
         # combines with: docs/PERF.md §"Tensor-parallel support matrix"
@@ -664,6 +712,7 @@ def run_pretrain(cfg: Config) -> dict:
                                 "epoch", epoch=epoch, step=cur_step,
                                 loss=epoch_loss,
                                 seconds=round(time.perf_counter() - epoch_t0, 6),
+                                **expert_counters(metrics),
                             )
                             write_results(
                                 {
@@ -683,7 +732,7 @@ def run_pretrain(cfg: Config) -> dict:
 
             # the epoch's end, as the boundary below reports it; cur_step and
             # metrics move on by one where the boundary is led
-            end_step, last_loss = cur_step, metrics["loss"]
+            end_step, last_loss, last_metrics = cur_step, metrics["loss"], metrics
             # the learning rate the boundary reports is a handful of tiny
             # device programs (the eager optax schedule). Queued here, behind
             # the epoch's last step, it is in hand with the loss; evaluated
@@ -781,6 +830,7 @@ def run_pretrain(cfg: Config) -> dict:
                 events.emit(
                     "epoch", epoch=epoch, step=end_step, loss=epoch_loss,
                     seconds=round(time.perf_counter() - epoch_t0, 6),
+                    **expert_counters(last_metrics),
                 )
             if eval_every > 0 and (epoch % eval_every == 0 or epoch == epochs):
                 with spans.span("boundary/probe"):

@@ -20,15 +20,39 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from simclr_tpu.models.arch import SEQUENCE_ENCODERS, STAGE_SIZES
 from simclr_tpu.models.heads import ProjectionHead
 from simclr_tpu.models.resnet import ResNetEncoder
+from simclr_tpu.models.sequence import SequenceEncoder
 from simclr_tpu.obs.trace import SCOPE_HEAD
 
 Dtype = Any
 
 
+def build_encoder(
+    name: str, *, dtype: Dtype, cifar_stem: bool = True,
+    bn_cross_replica_axis: str | None = None,
+) -> nn.Module:
+    """The encoder ``name`` of the one table (``models/arch.py``): a ResNet
+    over images, or a sequence encoder over packed token rows
+    (``models/sequence.PackedView``). Either gives one float32 feature row
+    per example, ``FEATURE_DIMS[name]`` wide."""
+    if name in SEQUENCE_ENCODERS:
+        return SequenceEncoder(spec=SEQUENCE_ENCODERS[name], dtype=dtype)
+    if name in STAGE_SIZES:
+        return ResNetEncoder(
+            base_cnn=name, cifar_stem=cifar_stem, dtype=dtype,
+            bn_cross_replica_axis=bn_cross_replica_axis,
+        )
+    raise ValueError(
+        f"no encoder {name!r}; have {sorted((*STAGE_SIZES, *SEQUENCE_ENCODERS))}"
+    )
+
+
 class ContrastiveModel(nn.Module):
-    """SimCLR model: z = g(f(x)). ``encode`` gives h = f(x)."""
+    """SimCLR model: z = g(f(x)). ``encode`` gives h = f(x). ``base_cnn``
+    names the encoder, a ResNet or a sequence encoder (:func:`build_encoder`;
+    the field keeps the name every recipe and checkpoint knows)."""
 
     base_cnn: str = "resnet18"
     d: int = 128
@@ -42,8 +66,8 @@ class ContrastiveModel(nn.Module):
     head_tp_axis: str | None = None
 
     def setup(self):
-        self.f = ResNetEncoder(
-            base_cnn=self.base_cnn,
+        self.f = build_encoder(
+            self.base_cnn,
             cifar_stem=self.cifar_stem,
             dtype=self.dtype,
             bn_cross_replica_axis=self.bn_cross_replica_axis,

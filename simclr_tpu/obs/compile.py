@@ -77,14 +77,27 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
     its instructions carry. An instruction the compiler added without
     metadata (a prefetch copy and its ``-start``/``-done``, a slice, a
     reshape) moves what its first operand made, and takes that one's
-    ``op_name``. What still has none (constants, parameters' copies) is left
+    ``op_name``. An instruction printed over several lines (a Pallas custom
+    call) is read to its last line. What still has none (constants, parameters' copies) is left
     out: a reader counts it as unscoped."""
     computations: dict[str, list[tuple]] = {}
     current = None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
             m = _COMPUTATION.match(line)
-            current = computations.setdefault(m.group(1), []) if m else None
+            if m:
+                current = computations.setdefault(m.group(1), [])
+            elif line.strip() == "}" or current is None:
+                current = None
+            elif current:
+                # the text of one instruction going on: a Pallas kernel's
+                # custom call prints its kernel metadata over several lines
+                # that begin in the first column, and its ``metadata={op_name``
+                # on the last of them. It neither ends the computation nor
+                # loses its name
+                op_name = _OP_NAME.search(line)
+                if op_name and current[-1][1] is None:
+                    current[-1] = (current[-1][0], op_name.group(1), *current[-1][2:4], None)
             continue
         m = _INSTRUCTION.match(line) if current is not None else None
         if m is None:

@@ -210,6 +210,13 @@ DEVICE_SCOPES = ("augment", "encoder", "head", "ntxent", "grad_reduce", "lars")
     SCOPE_AUGMENT, SCOPE_ENCODER, SCOPE_HEAD, SCOPE_NTXENT, SCOPE_GRAD_REDUCE,
     SCOPE_LARS,
 ) = DEVICE_SCOPES
+# inside ``encoder``, a sequence encoder's layers (models/sequence.py):
+# ``attention`` (norm, projections, rotary embedding, the attention itself)
+# and ``moe``, which holds ``router`` (scores, softmax, top-k; it runs before
+# the attention, on the layer's input) and ``experts`` (norm, sort, the
+# grouped products, the weighted sum)
+SEQUENCE_SCOPES = ("attention", "moe", "router", "experts")
+SCOPE_ATTENTION, SCOPE_MOE, SCOPE_ROUTER, SCOPE_EXPERTS = SEQUENCE_SCOPES
 
 SPANS_NAME = "spans.jsonl"
 

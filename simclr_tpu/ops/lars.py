@@ -16,8 +16,8 @@ automatically).
 
 Documented deviation: the reference's name-substring skip list ("bias", "bn")
 misses torchvision's ``downsample.1`` batch-norms, so those *do* get weight
-decay there; our structural mask (leaf name ``bias``/``scale``) excludes all
-norm parameters uniformly, which is the documented intent.
+decay there; our structural mask (every leaf of one axis: biases and norm
+scales) excludes all norm parameters uniformly, which is the documented intent.
 """
 
 from __future__ import annotations
@@ -163,6 +163,11 @@ def get_weight_decay_mask(kind: str, base_cnn: str = "resnet18") -> Callable[[An
     if kind == "structural":
         return simclr_weight_decay_mask
     if kind == "reference":
+        if base_cnn not in CONVS_PER_BLOCK:
+            raise ValueError(
+                f"optimizer.weight_decay_mask=reference transcribes the torch "
+                f"ResNets' parameter names; {base_cnn!r} has none"
+            )
         return lambda params: reference_weight_decay_mask(params, base_cnn)
     raise ValueError(
         f"optimizer.weight_decay_mask must be structural|reference, got {kind!r}"
@@ -170,20 +175,11 @@ def get_weight_decay_mask(kind: str, base_cnn: str = "resnet18") -> Callable[[An
 
 
 def simclr_weight_decay_mask(params) -> Any:
-    """True where weight decay applies: everything except biases and norm
-    scales — the reference's ("bias", "bn") skip list by structure rather
-    than name substring (see module docstring for the deviation).
+    """True where weight decay applies: on every matrix, convolution kernel
+    and embedding, and not on biases and norm scales, which are told by what
+    a leaf is (one axis or none) and not by what a model's blocks call it:
+    the ResNets' ``bias`` and ``scale`` leaves, a sequence encoder's RMS-norm
+    scales alike (see module docstring for the deviation from the
+    reference's name-substring list).
     """
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-
-    def decide(path) -> bool:
-        leaf_name = None
-        for part in reversed(path):
-            if isinstance(part, jax.tree_util.DictKey):
-                leaf_name = str(part.key)
-                break
-        return leaf_name not in ("bias", "scale")
-
-    decisions = [decide(path) for path, _ in flat]
-    treedef = jax.tree_util.tree_structure(params)
-    return jax.tree_util.tree_unflatten(treedef, decisions)
+    return jax.tree.map(lambda p: jnp.ndim(p) > 1, params)

@@ -45,6 +45,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from simclr_tpu.data.augment import simclr_augment_single, to_float
+from simclr_tpu.data.documents import span_crop_views
 from simclr_tpu.obs.trace import (
     SCOPE_AUGMENT,
     SCOPE_ENCODER,
@@ -112,9 +113,13 @@ def _global_sample_keys(rng, n_local: int, views: int = 2):
 
 
 def _augment_two_views(
-    rng, images, strength, out_size, augment_impl="xla", keys=None
+    rng, images, strength, out_size, augment_impl="xla", keys=None,
+    crop_lengths=(),
 ):
-    """Two on-device SimCLR views of the local uint8 shard.
+    """Two on-device SimCLR views of the local shard, by what the data is:
+    image augmentation for uint8 images, and for rows of token ids
+    (``crop_lengths`` given; ``data/documents.py``) one span crop per
+    document and view, packed into rows.
 
     ``augment_impl="xla"`` is the vmapped per-example chain, converting
     uint8→f32 once per IMAGE (hoisted out of ``simclr_augment_single``, not
@@ -127,6 +132,8 @@ def _augment_two_views(
     :func:`_global_sample_keys` (same (2n,) layout) so the draw is
     layout-invariant; ``rng`` is ignored then.
     """
+    if crop_lengths:
+        return span_crop_views(keys, images, crop_lengths)
     if augment_impl == "fused":
         return fused_two_views(rng, images, strength, out_size, keys=keys)
     images = to_float(images)
@@ -147,9 +154,11 @@ def _forward_fn(model, remat: bool):
     """
 
     def fwd(params, batch_stats, v):
+        # ``counters``: what a sequence encoder's expert layers sow
+        # (models/sequence.py); a ResNet sows nothing and the key is absent
         return model.apply(
             {"params": params, "batch_stats": batch_stats}, v, train=True,
-            mutable=["batch_stats"],
+            mutable=["batch_stats", "counters"],
         )
 
     return jax.checkpoint(fwd) if remat else fwd
@@ -162,9 +171,12 @@ def _apply_two_pass(fwd, params, batch_stats, v0, v1):
     view's batch forms its own BN batch statistics and the running stats get
     two momentum updates per step — NOT one concatenated 2B forward.
     """
-    z0, mut = fwd(params, batch_stats, v0)
-    z1, mut = fwd(params, mut["batch_stats"], v1)
-    return z0, z1, mut["batch_stats"]
+    z0, mut0 = fwd(params, batch_stats, v0)
+    z1, mut1 = fwd(params, mut0["batch_stats"], v1)
+    counters = jax.tree.map(
+        jnp.add, mut0.get("counters", {}), mut1.get("counters", {})
+    )
+    return z0, z1, mut1["batch_stats"], counters
 
 
 def _apply_concat(fwd, params, batch_stats, v0, v1):
@@ -175,9 +187,21 @@ def _apply_concat(fwd, params, batch_stats, v0, v1):
     google-research SimCLR formulation) instead of per-view — a documented
     semantic deviation behind ``model.forward_mode=concat``.
     """
-    n = v0.shape[0]
-    z, mut = fwd(params, batch_stats, jnp.concatenate([v0, v1], axis=0))
-    return z[:n], z[n:], mut["batch_stats"]
+    both = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), v0, v1)
+    z, mut = fwd(params, batch_stats, both)
+    n = z.shape[0] // 2
+    return z[:n], z[n:], mut["batch_stats"], mut.get("counters", {})
+
+
+def _counter_metrics(counters) -> Metrics:
+    """What the model sowed into ``counters`` in one step, summed over the
+    whole batch, each leaf under its own name, as the host reads them with
+    the loss (a sequence encoder's ``expert_load``, ``models/sequence.py``).
+    Empty for an encoder that counts nothing."""
+    return {
+        path[-1].key: jax.lax.psum(leaf, DATA_AXIS)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(counters)
+    }
 
 
 def _ntxent(z0, z1, negatives: str, fused: bool, temperature: float):
@@ -210,6 +234,7 @@ def make_pretrain_step(
     comm_overlap: str = "off",
     comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
     augment_impl: str = "xla",
+    crop_lengths: tuple[int, ...] = (),
     sentry=None,
 ) -> Callable[[TrainState, jax.Array, jax.Array], tuple[TrainState, Metrics]]:
     """Build the jitted contrastive train step.
@@ -217,6 +242,11 @@ def make_pretrain_step(
     Returned callable: ``(state, images_u8, rng) -> (state, metrics)`` with
     ``images`` the raw uint8 global batch sharded over the data axis. The
     model must be constructed with ``bn_cross_replica_axis=DATA_AXIS``.
+
+    ``crop_lengths`` says that the rows are token documents and how a view
+    packs their span crops (``data/documents.span_crop_views``); the model's
+    encoder is then a sequence encoder, and ``metrics`` carries its expert
+    layers' counters beside the loss (:func:`_counter_metrics`).
 
     ``fused=True`` routes the loss through the Pallas blockwise kernels
     (``ops/ntxent_pallas.py``), which never materialize the similarity
@@ -265,17 +295,18 @@ def make_pretrain_step(
             keys = _global_sample_keys(rng, images.shape[0], views=2)
             rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
             v0, v1 = _augment_two_views(
-                rng, images, strength, out_size, augment_impl, keys=keys
+                rng, images, strength, out_size, augment_impl, keys=keys,
+                crop_lengths=crop_lengths,
             )
 
         def loss_fn(params):
             with jax.named_scope(SCOPE_ENCODER):
-                z0, z1, new_stats = apply_views(
+                z0, z1, new_stats, counters = apply_views(
                     forward, params, state.batch_stats, v0, v1
                 )
             with jax.named_scope(SCOPE_NTXENT):
                 loss = _ntxent(z0, z1, negatives, fused, temperature)
-            return loss, new_stats
+            return loss, (new_stats, counters)
 
         if comm_overlap == "async":
             # staged backward: explicit VJP makes the cotangent pytree a
@@ -283,10 +314,14 @@ def make_pretrain_step(
             # paired with grad_allreduce's per-bucket assembly (no global
             # concatenate) each ring depends only on the leaves it spans, so
             # its hops can issue while earlier layers' backward matmuls run
-            loss, vjp_fn, new_stats = jax.vjp(loss_fn, state.params, has_aux=True)
+            loss, vjp_fn, (new_stats, counters) = jax.vjp(
+                loss_fn, state.params, has_aux=True
+            )
             grads, = vjp_fn(jnp.ones_like(loss))
         else:
-            (loss, new_stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+            (loss, (new_stats, counters)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params)
         # the quantization stream forks off the same per-step, per-data-shard
         # rng the augmentations use (fold_in is the jax stream-split idiom)
         with jax.named_scope(SCOPE_GRAD_REDUCE):
@@ -301,7 +336,7 @@ def make_pretrain_step(
         new_state = state.replace(
             step=state.step + 1, params=params, batch_stats=new_stats, opt_state=new_opt
         )
-        metrics = {"loss": loss}
+        metrics = {"loss": loss, **_counter_metrics(counters)}
         return new_state, metrics
 
     sharded = jax.shard_map(

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from simclr_tpu.models.resnet import feature_dim
+from simclr_tpu.models.arch import FEATURE_DIMS
 from simclr_tpu.obs.trace import (
     SCOPE_AUGMENT,
     SCOPE_ENCODER,
@@ -120,7 +120,7 @@ def tp_state_shardings(mesh, state: TrainState) -> TrainState:
 def _local_view(model, tp: int):
     """The per-shard model the shard_map body applies (clone keeps every
     other field in lockstep with the global-view model)."""
-    hidden = feature_dim(model.base_cnn)
+    hidden = FEATURE_DIMS[model.base_cnn]  # the encoder's output width
     if hidden % tp:
         raise ValueError(
             f"projection hidden width {hidden} not divisible by model axis {tp}"
@@ -144,6 +144,7 @@ def make_pretrain_step_tp(
     comm_overlap: str = "off",
     comm_chunks: int = compress.DEFAULT_COMM_CHUNKS,
     augment_impl: str = "xla",
+    crop_lengths: tuple[int, ...] = (),
     sentry=None,
 ) -> Callable[[TrainState, jax.Array, jax.Array], tuple[TrainState, dict]]:
     """Contrastive train step with the projection head tensor-parallel over
@@ -172,13 +173,21 @@ def make_pretrain_step_tp(
     ``negatives``/``fused`` select the NT-Xent variant with the dp step's
     dispatch (``steps._ntxent``) — the loss operates on the per-data-shard
     embeddings the TP head psum-completes, so every data-axis variant
-    composes with head sharding unchanged."""
+    composes with head sharding unchanged. ``crop_lengths`` (the dp step's
+    keyword for rows of token documents) is refused: a sequence encoder's
+    expert layer has no exchange over the model axis."""
     tp = mesh.shape[MODEL_AXIS]
     if forward_mode != "two_pass":
         raise ValueError(
             f"mesh.model={tp} (tensor parallelism) does not combine with "
             f"model.forward_mode={forward_mode!r}: only two_pass "
             "(see docs/PERF.md, tensor-parallel support matrix)"
+        )
+    if crop_lengths:
+        raise ValueError(
+            f"mesh.model={tp} with a sequence encoder ({model.base_cnn!r}, rows "
+            "of token documents): its expert layer holds its share on one chip "
+            "and has no exchange over a model axis; run it with mesh.model=1"
         )
     compress.validate_mode(grad_allreduce)
     compress.validate_overlap(comm_overlap, comm_chunks)

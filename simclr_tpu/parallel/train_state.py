@@ -57,8 +57,9 @@ def create_train_state(model, tx, rng: jax.Array, sample_batch, shardings=None) 
     leaf's largest weight): their scalars (``sqrt(variance) / c``,
     ``erf(-2 / sqrt 2)``) are folded on the host here and were computed on
     the device there, one ulp apart. ``sample_batch`` gives a batch's shape
-    and dtype (an array or a ``jax.ShapeDtypeStruct``; its values are never
-    read).
+    and dtype (an array or a ``jax.ShapeDtypeStruct``, or a pytree of them
+    where the model takes one, as a sequence encoder's packed view; the
+    values are never read).
 
     ``shardings`` are the program's ``out_shardings``, so the state is born
     where it lives and nothing is moved afterwards: one sharding for every
@@ -69,7 +70,8 @@ def create_train_state(model, tx, rng: jax.Array, sample_batch, shardings=None) 
 
     def init(key):
         variables = model.init(
-            key, jnp.zeros(sample_batch.shape, sample_batch.dtype), train=True
+            key, jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), sample_batch),
+            train=True,
         )
         params = variables["params"]
         return TrainState(

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from simclr_tpu.data.augment import to_float
+from simclr_tpu.models.arch import refuse_sequence_encoder
 from simclr_tpu.obs.compile import CompileSentry
 from simclr_tpu.parallel.compress import (
     dequantize_weight_buckets,
@@ -127,6 +128,9 @@ class EmbedEngine:
         replica_id: int | None = None,
         weights: str = "exact",
     ):
+        # requests are pixel arrays of `input_shape`
+        refuse_sequence_encoder(str(getattr(model, "base_cnn", "")), "the embed engine")
+        refuse_sequence_encoder(variables.get("params", {}), "the embed engine")
         self.model = model
         self.max_batch = int(max_batch)
         self.use_full_encoder = bool(use_full_encoder)

@@ -417,6 +417,11 @@ class EmbedHandler(BaseHTTPRequestHandler):
             payload = json.loads(self.rfile.read(length))
         except (ValueError, UnicodeDecodeError) as e:
             raise _BadRequest(f"body is not valid JSON: {e}") from None
+        if isinstance(payload, dict) and ("input_ids" in payload or "tokens" in payload):
+            raise _BadRequest(
+                "this server embeds images (\"instances\" of pixels); token "
+                "inputs for a sequence encoder are not built (ROADMAP.md, Reach)"
+            )
         if not isinstance(payload, dict) or "instances" not in payload:
             raise _BadRequest('body must be a JSON object with "instances"')
         engine = self.server.engine

@@ -151,6 +151,37 @@ def test_instruction_scopes_reads_a_compiled_programs_text():
     assert instruction_scopes("") == {}
 
 
+# a Pallas kernel's custom call prints its kernel metadata over several lines
+# that begin in the first column (libtpu 0.0.34), its ``metadata=`` on the last
+KERNEL_HLO = """HloModule jit_local_step
+
+ENTRY %main.9 (x.1: bf16[8,128]) -> bf16[8,128] {
+  %x.1 = bf16[8,128]{1,0} parameter(0)
+  %fusion.1 = bf16[8,128]{1,0} fusion(%x.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/attention/mul"}
+  %splash_mqa_fwd.2 = bf16[8,128]{1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512}"
+}}, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/attention/pallas_call" stack_frame_id=7}, backend_config={"custom_call_config": {}}
+  %copy.3 = bf16[8,128]{1,0} copy(%splash_mqa_fwd.2)
+  ROOT %fusion.4 = bf16[8,128]{1,0} fusion(%copy.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/mul"}
+}
+
+%fused_computation.1 (p: bf16[8,128]) -> bf16[8,128] {
+  %p = bf16[8,128]{1,0} parameter(0)
+  ROOT %mul.5 = bf16[8,128]{1,0} multiply(%p, %p), metadata={op_name="inside"}
+}
+"""
+
+
+def test_an_instruction_printed_over_several_lines_neither_ends_its_computation_nor_loses_its_name():
+    assert instruction_scopes(KERNEL_HLO) == {
+        "fusion.1": "jit(local_step)/jvp(encoder)/f/layer_0/attention/mul",
+        "splash_mqa_fwd.2": "jit(local_step)/jvp(encoder)/f/layer_0/attention/pallas_call",
+        "copy.3": "jit(local_step)/jvp(encoder)/f/layer_0/attention/pallas_call",
+        # before PR 34 everything from here on was lost with the kernel's lines
+        "fusion.4": "jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/mul",
+    }
+
+
 def test_a_watched_step_writes_its_scope_map_beside_the_compile_event(tmp_path):
     """Once per compile, inside the sentry's own seconds; nothing on a later
     call. On the CPU too, where instruction names differ and the path is the

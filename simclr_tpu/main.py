@@ -143,15 +143,23 @@ def expert_counters(metrics: dict) -> dict:
     (fetched with the loss; absent for an encoder without experts):
     ``held_pairs``, the (token, expert) pairs the held experts computed in
     the step; ``expert_load_max_over_mean``, the fullest held expert's pairs
-    over the mean held expert's, in the layer where that is largest."""
+    over the mean held expert's, in the layer where that is largest;
+    ``expert_chunks_per_call``, the chunks of sorted pairs the step's expert
+    layers ran over their calls (``models/experts.py``: 1.0 where every
+    call's held pairs fit one chunk)."""
     if "expert_load" not in metrics:
         return {}
-    load = jax.device_get(metrics["expert_load"]).astype(float)  # (layers, held)
+    load, chunks, calls = (
+        leaf.astype(float) for leaf in jax.device_get(
+            [metrics[name] for name in ("expert_load", "expert_chunks", "expert_calls")]
+        )
+    )  # (layers, held), (layers,), (layers,)
     return {
         "held_pairs": int(load.sum()),
         "expert_load_max_over_mean": float(
             (load.max(axis=1) / load.mean(axis=1).clip(min=1.0)).max()
         ),
+        "expert_chunks_per_call": float(chunks.sum() / calls.sum()),
     }
 
 

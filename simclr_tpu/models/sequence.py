@@ -25,9 +25,14 @@ at 16,384 tokens a view the layers' insides would not fit beside a
 half-billion parameters' gradients and momentum, so this is part of the
 encoder and not an option of the run.
 
-Counter: the encoder sows ``expert_load`` (layers, held experts), the pairs
-each held expert took in this call (``counters`` collection), which the
-step hands to the host with the loss.
+Counters: the encoder sows into the ``counters`` collection, for this call,
+``expert_load`` (layers, held experts), the pairs each held expert took;
+``expert_chunks`` (layers,), the chunks of sorted pairs each layer's expert
+loop ran (``models/experts.py``: a chunk is a fixed number of rows of the
+pairs sorted by expert, and as many run as hold a held expert's pair); and
+``expert_calls`` (layers,), a one for the call, so that whatever sums the
+leaves over views and devices also counts the calls it summed. The step
+hands them to the host with the loss.
 """
 
 from __future__ import annotations
@@ -125,14 +130,14 @@ class SequenceLayer(nn.Module):
 
         with jax.named_scope(SCOPE_MOE), jax.named_scope(SCOPE_EXPERTS):
             m = rms_norm(h, scale("experts_norm"), s.rms_eps)
-            y, load = held_experts(
+            y, load, chunks = held_experts(
                 m.reshape(-1, width), weights, chosen,
                 matrix("gate", s.experts_held, width, s.expert_width),
                 matrix("up", s.experts_held, width, s.expert_width),
                 matrix("down", s.experts_held, s.expert_width, width),
-                s.first_expert, dt,
+                s.first_expert, s.experts, dt,
             )
-        return h + y.reshape(rows, length, width), load
+        return h + y.reshape(rows, length, width), (load, chunks)
 
 
 class SequenceEncoder(nn.Module):
@@ -146,15 +151,19 @@ class SequenceEncoder(nn.Module):
         del train  # no layer here behaves differently in training
         s = self.spec
         embedding = self.param("embedding", _init, (s.vocab_held, s.hidden), jnp.float32)
-        h, loads = embedding[view.tokens], []
+        h, counted = embedding[view.tokens], []
         for i, kind in enumerate(s.layout):
-            h, load = nn.remat(SequenceLayer)(s, bool(kind), self.dtype, name=f"layer_{i}")(
+            h, counts = nn.remat(SequenceLayer)(s, bool(kind), self.dtype, name=f"layer_{i}")(
                 h, view.segments, view.positions
             )
-            loads.append(load)
-        self.sow(  # this call's numbers, not a history
-            "counters", "expert_load", jnp.stack(loads),
-            reduce_fn=lambda _, new: new, init_fn=lambda: None,
-        )
+            counted.append(counts)
+        loads, chunks = (jnp.stack(c) for c in zip(*counted))
+        for name, leaf in (
+            ("expert_load", loads), ("expert_chunks", chunks),
+            ("expert_calls", jnp.ones_like(chunks)),
+        ):
+            self.sow(  # this call's numbers, not a history
+                "counters", name, leaf, reduce_fn=lambda _, new: new, init_fn=lambda: None
+            )
         scale = self.param("final_norm", nn.initializers.ones, (s.hidden,), jnp.float32)
         return pool_segments(rms_norm(h, scale, s.rms_eps), view.segments, view.lengths)

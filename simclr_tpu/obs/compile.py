@@ -65,6 +65,9 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([^\s=]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _FUSION_CALLS = re.compile(r" fusion\(.*?calls=%?([^\s,}]+)")
+# an instruction that is its called computations' instructions one after
+# another: a loop, a branch, a call
+_RUNS_OTHERS = re.compile(r" (?:while|conditional|call)\(")
 _OPERAND = re.compile(r"%([^\s,()]+)")
 
 
@@ -78,9 +81,15 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
     metadata (a prefetch copy and its ``-start``/``-done``, a slice, a
     reshape) moves what its first operand made, and takes that one's
     ``op_name``. An instruction printed over several lines (a Pallas custom
-    call) is read to its last line. What still has none (constants, parameters' copies) is left
-    out: a reader counts it as unscoped."""
+    call) is read to its last line. A loop, a branch or a call is left out:
+    a device trace shows it as one event over all the events of what it
+    runs, and those are in the map one by one, so a reader that sums time by
+    scope would count the loop's inside twice (the chunk loops of
+    ``models/experts.py``: 236 ms of a 910 ms step; chip run, PR 35). What
+    still has none (constants, parameters' copies) is left out too: a reader
+    counts it as unscoped."""
     computations: dict[str, list[tuple]] = {}
+    runs_others = set()
     current = None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
@@ -105,6 +114,8 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
         op_name = _OP_NAME.search(line)
         calls = _FUSION_CALLS.search(line)
         operand = None if op_name else _OPERAND.search(line, m.end())
+        if _RUNS_OTHERS.search(line, m.end()):
+            runs_others.add(m.group(2))
         current.append((
             m.group(2), op_name.group(1) if op_name else None,
             bool(m.group(1)), calls.group(1) if calls else None,
@@ -145,7 +156,8 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
                 break
         if source in scopes:
             scopes[name] = scopes[source]
-    return scopes
+    # only now: what moves a loop's result still takes the loop's name
+    return {name: op for name, op in scopes.items() if name not in runs_others}
 
 
 def executable_cost(compiled) -> tuple[float, float]:

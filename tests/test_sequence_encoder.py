@@ -146,8 +146,8 @@ def test_the_holders_shares_add_up_to_the_uncut_layer(holders):
     total, pairs = 0.0, 0
     for first in range(0, TINY.experts, held):
         part = slice(first, first + held)
-        y, load = experts.held_experts(
-            m, weights, chosen, gate[part], up[part], down[part], first, jnp.float32)
+        y, load, _ = experts.held_experts(
+            m, weights, chosen, gate[part], up[part], down[part], first, TINY.experts, jnp.float32)
         share = ref.held_share(m, weights, chosen, gate[part], up[part], down[part], first, None)
         np.testing.assert_allclose(y, share, rtol=1e-4, atol=1e-5)
         total, pairs = total + y, pairs + int(load.sum())
@@ -157,19 +157,22 @@ def test_the_holders_shares_add_up_to_the_uncut_layer(holders):
 
 def test_no_token_is_dropped_under_a_skewed_router():
     m, weights, chosen, gate, up, down = expert_inputs(skew=True)
-    y, load = experts.held_experts(m, weights, chosen, gate[:2], up[:2], down[:2], 0, jnp.float32)
+    y, load, _ = experts.held_experts(
+        m, weights, chosen, gate[:2], up[:2], down[:2], 0, TINY.experts, jnp.float32)
     assert load.tolist() == [m.shape[0], m.shape[0]]  # all 192 pairs on two experts
     np.testing.assert_allclose(y, uncut(m, weights, chosen, gate, up, down), rtol=1e-4, atol=1e-5)
     # and the other holders get nothing to do, without a shape changing
-    y, load = experts.held_experts(m, weights, chosen, gate[2:4], up[2:4], down[2:4], 2, jnp.float32)
-    assert load.tolist() == [0, 0] and float(jnp.abs(y).max()) == 0.0
+    y, load, chunks = experts.held_experts(
+        m, weights, chosen, gate[2:4], up[2:4], down[2:4], 2, TINY.experts, jnp.float32)
+    assert load.tolist() == [0, 0] and float(jnp.abs(y).max()) == 0.0 and int(chunks) == 0
 
 
 def test_the_experts_gradients_match_the_dense_sum():
     m, weights, chosen, gate, up, down = expert_inputs(tokens=32)
 
     def ours(m, weights, gate, up, down):
-        return jnp.sum(jnp.sin(experts.held_experts(m, weights, chosen, gate[2:6], up[2:6], down[2:6], 2, jnp.float32)[0]))
+        return jnp.sum(jnp.sin(experts.held_experts(
+            m, weights, chosen, gate[2:6], up[2:6], down[2:6], 2, TINY.experts, jnp.float32)[0]))
 
     def theirs(m, weights, gate, up, down):
         return jnp.sum(jnp.sin(ref.held_share(m, weights, chosen, gate[2:6], up[2:6], down[2:6], 2, None)))
@@ -178,6 +181,81 @@ def test_the_experts_gradients_match_the_dense_sum():
         a = jax.grad(ours, argnums=(0, 1, 2, 3, 4))(m, weights, gate, up, down)
         b = jax.grad(theirs, argnums=(0, 1, 2, 3, 4))(m, weights, gate, up, down)
     assert worst_gap(a, b) < 1e-4
+
+
+def planted_routing(tokens, first_choice, second_choice):
+    """Two choices a token, the held experts' pairs counted out: the first
+    choice of ``first_choice`` tokens is expert 0 and the second choice of
+    ``second_choice`` tokens expert 1 (which tokens: a fixed shuffle each);
+    every other choice falls on experts 2 to 7, held elsewhere."""
+    k = jax.random.split(jax.random.key(9), 3)
+    t = jnp.arange(tokens)
+    chosen = jnp.stack([
+        jnp.where(jax.random.permutation(k[0], tokens) < first_choice, 0, 2 + t % 3),
+        jnp.where(jax.random.permutation(k[1], tokens) < second_choice, 1, 5 + t % 3),
+    ], axis=1)
+    weights = jax.random.uniform(k[2], (tokens, 2), minval=0.1)
+    return weights / weights.sum(axis=1, keepdims=True), chosen
+
+
+# 1,024 tokens, two choices each: 2,048 pairs. Two held of eight make chunks
+# of 1,024 rows (two), one held of eight chunks of 512 (four)
+CHUNKED = {
+    "every held pair in one chunk": (2, 500, 300),
+    "a few rows spill into the second chunk": (2, 1024, 6),
+    "an expert's group lies across a chunk's edge": (2, 600, 900),
+    "every chunk full": (2, 1024, 1024),
+    "no held pair": (2, 0, 0),
+    "one held expert's group over two chunks of four": (1, 900, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNKED))
+def test_as_many_chunks_run_as_hold_a_held_pair_and_nothing_changes(case):
+    """Result, gradients and the counter where the held pairs fill one chunk,
+    lie across an edge, fill every chunk or do not exist: against the dense
+    sum, which knows no chunk. With no held pair the loop makes no turn and
+    the count is 0."""
+    held, first_choice, second_choice = CHUNKED[case]
+    tokens = 1024
+    m, _, _, gate, up, down = expert_inputs(tokens)
+    weights, chosen = planted_routing(tokens, first_choice, second_choice)
+    gate, up, down = gate[:held], up[:held], down[:held]
+    chunk = experts.chunk_rows(2 * tokens, held, TINY.experts)
+    assert chunk == {2: 1024, 1: 512}[held]
+
+    def ours(m, weights, gate, up, down):
+        y, load, chunks = experts.held_experts(
+            m, weights, chosen, gate, up, down, 0, TINY.experts, jnp.float32)
+        return jnp.sum(jnp.sin(y)), (y, load, chunks)
+
+    def theirs(m, weights, gate, up, down):
+        y = ref.held_share(m, weights, chosen, gate, up, down, 0, None)
+        return jnp.sum(jnp.sin(y)), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, load, chunks)), a = jax.value_and_grad(
+            ours, argnums=(0, 1, 2, 3, 4), has_aux=True)(m, weights, gate, up, down)
+        (_, dense), b = jax.value_and_grad(
+            theirs, argnums=(0, 1, 2, 3, 4), has_aux=True)(m, weights, gate, up, down)
+    total = first_choice + (second_choice if held == 2 else 0)
+    assert int(load.sum()) == total
+    assert int(chunks) == -(-total // chunk)
+    np.testing.assert_allclose(y, dense, rtol=1e-4, atol=1e-5)
+    assert worst_gap(a, b) < 1e-4
+
+
+@pytest.mark.parametrize("rows,held,of,chunk", [
+    (98304, 16, 64, 32768),  # the cell: an even router's 24,576 and a third more
+    (98304, 64, 64, 98304),  # every expert held: one chunk, every pair in it
+    (98304, 8, 64, 16384),
+    (2048, 2, 8, 1024),
+    (192, 2, 8, 192),  # nothing smaller is a multiple of the row tile
+    (256, 4, 8, 256),
+])
+def test_a_chunk_follows_from_shapes(rows, held, of, chunk):
+    assert experts.chunk_rows(rows, held, of) == chunk
+    assert rows % chunk == 0 and (chunk == rows or chunk % experts.ROW_TILE == 0)
 
 
 class TestPacking:
@@ -237,3 +315,6 @@ def test_the_encoder_counts_what_its_held_experts_took(views):
     assert load.shape == (len(spec.layout), spec.experts_held)
     # all held: every pair of every layer
     assert load.sum(axis=1).tolist() == [2 * 64 * spec.experts_per_token] * len(spec.layout)
+    # and with them one chunk of all rows, which every layer's one call ran
+    assert mut["counters"]["expert_chunks"].tolist() == [1] * len(spec.layout)
+    assert mut["counters"]["expert_calls"].tolist() == [1] * len(spec.layout)

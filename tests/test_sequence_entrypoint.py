@@ -55,6 +55,8 @@ class TestPretrainOnDocuments:
             # 4 held, 4 layers: 4,096 pairs if the router were even
             assert 2500 < event["held_pairs"] < 6000
             assert event["expert_load_max_over_mean"] >= 1.0
+            # 256 pairs a call at these sizes: one chunk of all rows
+            assert event["expert_chunks_per_call"] == 1.0
         assert events_of(run, "run_start")[0]["global_batch"] == 64
 
     def test_resume_continues_from_checkpoint(self, run, tmp_path):

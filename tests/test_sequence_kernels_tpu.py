@@ -2,7 +2,8 @@
 real widths: splash attention (full causal, and the 4,096 window) over rows
 of 8,192 tokens with 28 query on 4 key-value heads of 128, and the grouped
 expert products (megablox) for 16 held experts of 2560-768-2560 over a
-view's 98,304 (token, expert) pairs, each forward and backward. Compiled
+chunk of 32,768 of a view's 98,304 sorted (token, expert) pairs, inside the
+loop over chunks, each forward and backward. Compiled
 ahead of time by the TPU compiler installed here, for a chip that is
 described and not attached: what Mosaic refuses (a tile off the tiling, too
 much fast memory) shows here and costs no chip time. That proves
@@ -75,12 +76,21 @@ def test_attention_forward_and_backward_compile_as_kernels(one_chip, kernels, wi
 
 def test_the_held_experts_products_compile_as_kernels(one_chip, kernels):
     tokens = 2 * ROW  # one view
+    pairs = tokens * SPEC.experts_per_token
+    chunk = experts.chunk_rows(pairs, SPEC.experts_held, SPEC.experts)
+    assert (pairs, chunk) == (98304, 32768)
 
     def sds(*shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def loss(m, weights, chosen, gate, up, down):
-        return jnp.sum(experts.held_experts(m, weights, chosen, gate, up, down, 0, jnp.bfloat16)[0])
+    @jax.checkpoint  # as the encoder runs a layer (models/sequence.py)
+    def layer(m, weights, chosen, gate, up, down):
+        with jax.named_scope("experts"):
+            return experts.held_experts(
+                m, weights, chosen, gate, up, down, 0, SPEC.experts, jnp.bfloat16)[0]
+
+    def loss(*args):
+        return jnp.sum(jnp.sin(layer(*args)))
 
     text = compiled_text(
         jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
@@ -90,5 +100,18 @@ def test_the_held_experts_products_compile_as_kernels(one_chip, kernels):
         sds(SPEC.experts_held, SPEC.hidden, SPEC.expert_width),
         sds(SPEC.experts_held, SPEC.expert_width, SPEC.hidden),
     )
-    # three products forward; each gives two backward (its rows, its weights)
-    assert text.count("tpu_custom_call") >= 9
+    # three products forward; each gives two backward (its rows, its weights),
+    # and the backward pass runs the three again in place of the rematerialised
+    # forward, whose own are dead code: twelve, as with a row for every pair
+    # (3 forward + 3 rematerialised + 6 backward), whatever the chunks' number
+    assert 9 <= text.count("tpu_custom_call") <= 12
+    # nothing the chip writes has a row for every pair: the widest results are
+    # a chunk's rows and the tokens' own. Neither token-side array of the
+    # parent's is admitted: the return to the tokens is gathers of
+    # ``tokens`` rows from a chunk, added in float32
+    assert f"[{chunk},{SPEC.hidden}]" in text and f"[{tokens},{SPEC.hidden}]" in text
+    for shape in (
+        f"[{pairs},{SPEC.hidden}]", f"[{SPEC.hidden},{pairs}]", f"[{pairs},{SPEC.expert_width}]",
+        f"[{tokens},{SPEC.experts_per_token},{SPEC.hidden}]",
+    ):
+        assert shape not in text, shape

@@ -182,6 +182,51 @@ def test_an_instruction_printed_over_several_lines_neither_ends_its_computation_
     }
 
 
+# a loop: the trace shows the ``while`` as one event over its body's events
+LOOP_HLO = """HloModule jit_local_step
+
+%body.1 (carry: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %carry = (s32[], f32[8]{0}) parameter(0)
+  %i.2 = s32[] get-tuple-element(%carry), index=0
+  %x.3 = f32[8]{0} get-tuple-element(%carry), index=1
+  %fusion.4 = f32[8]{0} fusion(%x.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/while/body/gather"}
+  ROOT %tuple.5 = (s32[], f32[8]{0}) tuple(%i.2, %fusion.4)
+}
+
+%cond.1 (carry.1: (s32[], f32[8])) -> pred[] {
+  %carry.1 = (s32[], f32[8]{0}) parameter(0)
+  %i.6 = s32[] get-tuple-element(%carry.1), index=0
+  %n.7 = s32[] constant(3)
+  ROOT %lt.8 = pred[] compare(%i.6, %n.7), direction=LT, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/while/cond/lt"}
+}
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %mul.9 = f32[8]{0} multiply(%p, %p), metadata={op_name="inside"}
+}
+
+ENTRY %main.9 (x.1: f32[8]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %zero.2 = s32[] constant(0)
+  %tuple.3 = (s32[], f32[8]{0}) tuple(%zero.2, %x.1)
+  %while.4 = (s32[], f32[8]{0}) while(%tuple.3), condition=%cond.1, body=%body.1, metadata={op_name="jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/while"}
+  %get-tuple-element.5 = f32[8]{0} get-tuple-element(%while.4), index=1
+  ROOT %copy.6 = f32[8]{0} copy(%get-tuple-element.5)
+}
+"""
+
+
+def test_a_loop_is_left_out_and_its_bodys_instructions_are_not():
+    experts = "jit(local_step)/jvp(encoder)/f/layer_0/moe/experts/while"
+    assert instruction_scopes(LOOP_HLO) == {
+        "fusion.4": experts + "/body/gather",
+        "lt.8": experts + "/cond/lt",
+        # what moves the loop's result is still the loop's
+        "get-tuple-element.5": experts,
+        "copy.6": experts,
+    }
+
+
 def test_a_watched_step_writes_its_scope_map_beside_the_compile_event(tmp_path):
     """Once per compile, inside the sentry's own seconds; nothing on a later
     call. On the CPU too, where instruction names differ and the path is the
